@@ -7,9 +7,10 @@ family, config_hash, mesh) — the compile accounting layer already
 fingerprints (docs/OBSERVABILITY.md §compile). This package erases that
 debt twice over:
 
-1. :func:`enable_from_env` points JAX's **persistent compilation
-   cache** (``jax_compilation_cache_dir``) at ``DCT_COMPILE_CACHE_DIR``
-   so any re-trace of an identical program is a disk hit instead of an
+1. :func:`enable_from_env` arms JAX's **persistent compilation
+   cache** at the directory the one resolver names
+   (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``) so
+   any re-trace of an identical program is a disk hit instead of an
    XLA compile — wired into trainer startup, the supervised
    relauncher, and the serving entry point.
 2. :class:`ExecutableStore` **AOT-serializes the hot executables**
@@ -28,6 +29,7 @@ executable the miss path would have built on this machine.
 """
 
 from dct_tpu.compilecache.cache import (
+    CACHE_DIR_ENV,
     DEFAULT_CACHE_DIR,
     aot_enabled,
     cache_mode,
@@ -47,6 +49,7 @@ from dct_tpu.compilecache.aot import (
 )
 
 __all__ = [
+    "CACHE_DIR_ENV",
     "DEFAULT_CACHE_DIR",
     "CachedProgram",
     "ExecutableStore",

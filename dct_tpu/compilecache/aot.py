@@ -36,6 +36,7 @@ process by construction.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -86,6 +87,41 @@ def signature_of(args) -> str:
     ]
     blob = f"n{len(leaves)}|" + "|".join(parts)
     return hashlib.sha1(blob.encode()).hexdigest()[:12]
+
+
+def _execution_device_ids(compiled) -> list[int]:
+    """Ids of the devices ``compiled`` runs on, in device-assignment
+    order (read off the same unloaded executable ``serialize`` pickles)."""
+    unloaded = compiled._executable._unloaded_executable
+    return [int(d.id) for d in unloaded.device_list]
+
+
+_cache_hits = threading.local()
+
+
+@functools.cache
+def _count_persistent_cache_hits() -> None:
+    """Install (once) the ``jax.monitoring`` listener that counts, per
+    thread, the compiles JAX's persistent compilation cache served."""
+    import jax.monitoring
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            _cache_hits.n = getattr(_cache_hits, "n", 0) + 1
+
+    jax.monitoring.register_event_listener(on_event)
+
+
+def _persistent_cache_hits() -> int:
+    """How many compiles of THIS thread the persistent compilation
+    cache has served so far. An executable that came out of that cache
+    must not be published as an AOT artifact: XLA:CPU re-serializes
+    such an executable without its object code, and the artifact then
+    dies at its first call in the next process (``NOT_FOUND: Function
+    ... not found``). The persistent cache already holds it — nothing
+    is lost."""
+    _count_persistent_cache_hits()
+    return getattr(_cache_hits, "n", 0)
 
 
 def _safe_name(s: str) -> str:
@@ -214,6 +250,7 @@ class ExecutableStore:
                 "program": program,
                 "signature": signature,
                 "payload_sha256": hashlib.sha256(payload).hexdigest(),
+                "device_ids": _execution_device_ids(compiled),
             }
             if cost:
                 header["roofline"] = cost
@@ -306,7 +343,18 @@ class ExecutableStore:
             out_tree = jax.tree_util.tree_structure(
                 jax.eval_shape(fn, *args)
             )
-            loaded = _se.deserialize_and_load(payload, in_tree, out_tree)
+            # The devices the program was compiled for, in assignment
+            # order: deserialize_and_load defaults to EVERY device of
+            # the backend, so a single-device scorer or a sub-mesh stage
+            # would otherwise be loaded as an N-device program and die
+            # at its first call on a many-device host.
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[
+                    by_id[i] for i in header["device_ids"]
+                ],
+            )
             # Roofline provenance stamped at compile time reads back on
             # the warm path — a hit run reports the same analytic
             # FLOPs/HBM as the run that compiled the artifact. (If the
@@ -460,6 +508,7 @@ class CachedProgram:
                     self._entries[(program, sig)] = loaded
                 return out
         store._note(program, "miss")
+        hits_before = _persistent_cache_hits()
         try:
             compiled = self._fn.lower(*args).compile()
         except Exception:
@@ -476,7 +525,8 @@ class CachedProgram:
             if _roofline.roofline_enabled() else None
         )
         store.note_cost(program, cost)
-        store.save(program, sig, compiled, cost=cost)
+        if _persistent_cache_hits() == hits_before:
+            store.save(program, sig, compiled, cost=cost)
         with self._lock:
             self._entries[(program, sig)] = compiled
         return compiled(*args)

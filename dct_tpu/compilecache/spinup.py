@@ -72,7 +72,6 @@ def _measure_env(
         DCT_HEARTBEAT_DIR=os.path.join(workdir, f"hb_{tag}"),
         DCT_TRACKING_DIR=os.path.join(workdir, f"mlruns_{tag}"),
         DCT_COMPILE_CACHE="on" if cache_on else "off",
-        DCT_COMPILE_CACHE_DIR=os.path.join(workdir, "xla_cache"),
         DCT_COMPILE_CACHE_AOT_DIR=os.path.join(workdir, "aot"),
         DCT_EPOCHS="1",
         DCT_BATCH_SIZE="32",
@@ -82,6 +81,11 @@ def _measure_env(
         # measurement, and the crash path must not owe them a flush.
         DCT_TELEMETRY_FLUSH_S="0",
     )
+    # JAX reads this variable itself, whatever DCT_COMPILE_CACHE says: the
+    # cold control must not see one at all.
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_on:
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(workdir, "xla_cache")
     env.update(model_env or {})
     return env
 
@@ -213,9 +217,16 @@ def measure_first_score(
         JAX_PLATFORMS=env.get("JAX_PLATFORMS", "cpu"),
         DCT_COMPILE_CACHE="on" if cache_on else "off",
     )
-    # The XLA persistent cache would hide the compile on the "cold"
-    # control; the measurement isolates the package's own aot/ dir.
-    env.pop("DCT_COMPILE_CACHE_DIR", None)
+    # A persistent XLA cache would hide the compile on the "cold"
+    # control (JAX reads the variable whatever DCT_COMPILE_CACHE says);
+    # the warm side gets an empty one of its own beside the package, so
+    # the measurement isolates the package's aot/ dir.
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_on:
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+            os.path.dirname(os.path.abspath(package_dir)),
+            "xla_cache_first_score",
+        )
     proc = subprocess.run(
         [
             sys.executable, "-m", "dct_tpu.compilecache.spinup",

@@ -220,10 +220,9 @@ class TrainConfig:
     # EarlyStopping with the ModelCheckpoint the reference configures).
     early_stop_patience: int = 0
     early_stop_min_delta: float = 0.0
-    # Epochs fused into ONE XLA dispatch (scan path only; 1 = parity).
-    # On a slow control plane each epoch costs a host round trip that can
-    # dwarf the compute at parity batch sizes; chunking K epochs amortizes
-    # it to 1/K. Trade-offs, all chunk-granular: deploy checkpoints and
+    # Epochs fused into ONE XLA dispatch (scan path only; 1 = parity):
+    # K epochs pay one host round trip instead of K.
+    # Trade-offs, all chunk-granular: deploy checkpoints and
     # resume snapshots land at chunk boundaries (per-epoch metrics are
     # still returned and logged), early stopping is evaluated per epoch
     # but can only take effect between chunks, and up to 2K epochs of
@@ -1185,9 +1184,9 @@ class RunConfig:
 # dct-lint rule (docs/ANALYSIS.md) holds the three surfaces equal:
 # an undeclared read, an undocumented entry, and a dead entry are all
 # findings. Entries that are dataclass knobs above carry no extra
-# authority — the dict exists so the ~160-knob surface (bench,
-# campaign scripts, DAG plumbing, launcher-exported IDs included) has
-# ONE greppable index that cannot silently drift from the code.
+# authority — the dict exists so the knob surface (bench, DAG plumbing,
+# launcher-exported IDs included) has ONE greppable index that cannot
+# silently drift from the code.
 # ======================================================================
 
 ENV_REGISTRY: dict[str, str] = {
@@ -1448,25 +1447,22 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_SERVE_SCALE_COOLDOWN_S": "min seconds between scale events",
     "DCT_SERVE_MAX_RESTARTS": "pool respawn budget before circuit-break",
     "DCT_SERVE_PROC_INDEX": "pool-exported child index (set by ServerPool)",
-    # --- platform probing / caches / native ------------------------
-    "DCT_REQUIRE_TPU": "fail fast when no TPU backend is available",
-    "DCT_BACKEND_PROBE_TIMEOUT": "backend liveness probe timeout (s)",
-    "DCT_BACKEND_PROBE_RETRIES": "backend probe retry count",
-    "DCT_BACKEND_PROBE_BUDGET": "total probe wall-clock budget (s)",
+    # --- peaks / caches / native -----------------------------------
     "DCT_PEAK_TFLOPS": "per-chip peak TFLOPs override for MFU math",
-    "DCT_JAX_CACHE": "enable the persistent XLA compilation cache",
-    "DCT_JAX_CACHE_DIR": "compilation cache directory",
     # Compile cache + AOT executables (dct_tpu.compilecache;
-    # docs/OBSERVABILITY.md §compile): sub-second relaunch/spin-up.
-    "DCT_COMPILE_CACHE": "compile cache mode: off | auto (dir arms) | on",
-    "DCT_COMPILE_CACHE_DIR": "persistent XLA compile-cache dir (per-machine)",
+    # docs/OBSERVABILITY.md §compile): sub-second relaunch/spin-up. The
+    # directory is JAX's own JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache — there is no DCT_ knob for it.
+    "DCT_COMPILE_CACHE": (
+        "compile cache mode: off | auto (JAX_COMPILATION_CACHE_DIR arms) | on"
+    ),
     "DCT_COMPILE_CACHE_AOT": "AOT executable store on/off (default on)",
     "DCT_COMPILE_CACHE_AOT_DIR": "AOT store root override (default <models>/aot)",
     "DCT_COMPILE_CACHE_MIN_COMPILE_S": "min compile seconds worth caching (0 = all)",
     "DCT_COMPILE_CACHE_WARM_SIZES": "packaging scorer pre-compile batch sizes",
     "DCT_NATIVE": "enable the native (C++) extension build",
     "DCT_CXX": "C++ compiler for the native build",
-    # --- bench / campaign scripts ----------------------------------
+    # --- bench -----------------------------------------------------
     "DCT_BENCH_ROWS": "bench dataset size (rows)",
     "DCT_BENCH_EPOCHS": "bench trainer-loop epochs",
     "DCT_BENCH_TORCH_EPOCHS": "bench torch-reference epochs",
@@ -1493,11 +1489,4 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_SCALED_BATCH": "scaled bench leg: per-device batch",
     "DCT_SCALED_WINDOW": "scaled bench leg: attention window",
     "DCT_SCALED_SCAN": "scaled bench leg: scan path on/off",
-    "DCT_ONCHIP_MOE": "on-chip campaign: include the MoE section",
-    "DCT_CAMPAIGN_SECTIONS": "campaign: comma-separated section filter",
-    "DCT_CAMPAIGN_OUT": "campaign: output JSON path",
-    "DCT_CAMPAIGN_MFU": "campaign: MFU gate threshold",
-    "DCT_CAMPAIGN_ALLOW_CPU": "campaign: permit CPU (evidence-only) runs",
-    "DCT_CAMPAIGN_INTERPRET": "campaign: Pallas interpret mode",
-    "DCT_CAMPAIGN_FLASH_SHAPES": "campaign: flash shape sweep spec",
 }

@@ -357,6 +357,21 @@ class AlwaysOnLoop:
         from dct_tpu.resilience.preempt import PreemptedError
 
         lc = self.loop_cfg
+        if lc.train_mode == "supervised":
+            from dct_tpu import compilecache as _compilecache
+
+            if _compilecache.enabled() and _compilecache.warm_sizes():
+                # Packaging-time scorer warm-up compiles in THIS process
+                # (score_gen -> warm_package_scorer) while the supervised
+                # child trainer holds the chip.
+                from dct_tpu.utils.chip import refuse_shared_chip
+
+                refuse_shared_chip(
+                    "DCT_LOOP_TRAIN_MODE=supervised with "
+                    "DCT_COMPILE_CACHE_WARM_SIZES set (the loop parent "
+                    "would compile the scorer beside its child trainer)",
+                    {**os.environ, **self._extra_round_env},
+                )
         t0 = self._clock()
         self.events.emit(
             "loop", "loop.start",

@@ -170,6 +170,11 @@ def build_healthcheck_script(
     command's arbitrary status): the supervisor's classifier must see
     "a host is unhealthy" as infra, never as a training crash to burn
     restart budget on.
+
+    Each check takes its host's chips for a moment (``jax.devices()``),
+    and a chip belongs to one process at a time: every check runs in the
+    FOREGROUND, one after another, so all of them have exited before
+    this script returns and the launch block starts rank 0.
     """
     lines = ["set -e"]
     for host in hosts:

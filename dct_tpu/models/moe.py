@@ -45,9 +45,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from dct_tpu.parallel.shard_map_compat import shard_map
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 from flax import linen as nn
 

@@ -266,26 +266,11 @@ class WeatherTransformerPP(nn.Module):
         m = self.n_microbatches or max(pipe, 1)
         dp = mesh.shape.get("data", 1) if mesh is not None else 1
         if pipe > 1 and b % m == 0 and (b // m) % dp == 0:
-            from dct_tpu.parallel.shard_map_compat import (
-                PARTIAL_AUTO_SHARD_MAP,
+            h = pipeline_apply(
+                lambda p, a: stage_mod.apply({"params": p}, a),
+                stacked, h, mesh=mesh, n_microbatches=m,
+                data_axis="data" if dp > 1 else None,
             )
-
-            if PARTIAL_AUTO_SHARD_MAP:
-                h = pipeline_apply(
-                    lambda p, a: stage_mod.apply({"params": p}, a),
-                    stacked, h, mesh=mesh, n_microbatches=m,
-                    data_axis="data" if dp > 1 else None,
-                )
-            else:
-                # jax 0.4.x: partial-manual shard_map cannot lower — run
-                # the SAME tick schedule as a vmapped GSPMD scan (the
-                # stage dim stays a real array axis sharded P('pipe')).
-                from dct_tpu.parallel.pipeline import gpipe_tick_apply
-
-                h = gpipe_tick_apply(
-                    lambda p, a: stage_mod.apply({"params": p}, a),
-                    stacked, h, n_microbatches=m,
-                )
         elif pipe > 1 and b >= m * dp:
             # A real batch that cannot tile the configured pipeline is a
             # sizing bug: running the sequential path with P('pipe')

@@ -34,7 +34,7 @@ plane built on four pillars:
   burn-rate monitoring (``slo.alert`` events, ``dct_slo_*`` gauges)
   over the aggregated view.
 - :mod:`report` — the bench-trajectory regression sentinel
-  (``python -m dct_tpu.observability.report BENCH_r0*.json``).
+  (``python -m dct_tpu.observability.report <records...>``).
 
 Everything here is dependency-free, failure-isolated (a full disk or an
 unwritable dir degrades telemetry to a no-op, never fails training), and

@@ -101,9 +101,8 @@ def _fmt_num(v) -> str:
 
 def load_bench_record(run_dir: str) -> tuple[str, dict] | None:
     """Newest ``BENCH*.json`` under ``run_dir`` (rounds sort by name),
-    or None. The cycle report surfaces its MFU — including the
-    ``scaled_mfu_stale_reason`` a dead relay stamps — instead of
-    silently omitting the number an operator will otherwise chase."""
+    or None. The cycle report surfaces its MFU instead of silently
+    omitting the number an operator will otherwise chase."""
     paths = _find_files(
         run_dir,
         lambda fn, d: fn.startswith("BENCH") and fn.endswith(".json"),
@@ -133,28 +132,12 @@ def _bench_mfu_lines(bench: tuple[str, dict] | None) -> list[str]:
         )
         return lines
     mfu = parsed.get("mfu")
-    stale = parsed.get("scaled_mfu_stale")
-    reason = parsed.get("scaled_mfu_stale_reason")
     if mfu is not None:
         line = f"  {name}: mfu={_fmt_num(mfu)}"
         source = parsed.get("mfu_source")
         if source:
             line += f" ({source})"
-        if stale:
-            # Post-roofline records: the headline is local, so staleness
-            # only taints the scaled stanza's on-chip number.
-            which = (
-                "scaled on-chip MFU STALE"
-                if source == "cost_model_local" else "STALE"
-            )
-            line += f" [{which}: {reason or 'reason unrecorded'}]"
         lines.append(line)
-    elif stale or reason:
-        why = reason or "no reason recorded"
-        lines.append(
-            f"  {name}: scaled MFU stale — {why} "
-            "(prior rounds' numbers do not transfer)"
-        )
     else:
         lines.append(
             f"  {name}: no MFU in the record "

@@ -38,7 +38,6 @@ programs and catch regressions, which is what this plane is for.
 from __future__ import annotations
 
 import os
-import threading
 
 #: Best-effort HBM bandwidth per chip, bytes/sec, by device-kind
 #: substring (same table style as profiling.chip_peak_flops). Public
@@ -188,58 +187,20 @@ def analyze_compiled(compiled) -> dict | None:
     return out
 
 
-# ----------------------------------------------------------------------
-# Host peak measurement: the bench's "never null" fallback. On rigs
-# whose device kind has no peak-FLOPs table entry (the CPU fallback
-# rig), MFU would stay null forever — exactly the staleness this plane
-# retires. A dense f32 GEMM through the platform BLAS is the honest
-# local peak: the best the hardware demonstrably sustains on the
-# roofline's compute axis.
-
-_PEAK_LOCK = threading.Lock()
-_PEAK_CACHE: float | None = None
-
-
-def measure_host_peak_flops(n: int = 512, reps: int = 5) -> float:
-    """Measured dense-GEMM FLOPs/sec on THIS host (numpy/BLAS, float32),
-    cached per process. ~tens of ms once."""
-    global _PEAK_CACHE
-    with _PEAK_LOCK:
-        if _PEAK_CACHE is not None:
-            return _PEAK_CACHE
-        import time
-
-        import numpy as np
-
-        a = np.ones((n, n), np.float32)
-        b = np.ones((n, n), np.float32)
-        a @ b  # warm the BLAS thread pool
-        best = float("inf")
-        for _ in range(max(1, reps)):
-            t0 = time.perf_counter()
-            a @ b
-            best = min(best, time.perf_counter() - t0)
-        _PEAK_CACHE = 2.0 * n * n * n / max(best, 1e-9)
-        return _PEAK_CACHE
-
-
 def resolve_peak_flops() -> tuple[float | None, str]:
-    """(peak FLOPs/sec per chip, source): the device table /
-    ``DCT_PEAK_TFLOPS`` override when known, else the measured host GEMM
-    peak — so a locally-computed MFU always has a denominator."""
+    """(peak FLOPs/sec per chip, source): the ``DCT_PEAK_TFLOPS``
+    override or the device table. Off the TPU there is no denominator
+    — ``(None, "not_measured")`` — and no MFU is reported; nothing is
+    substituted for it."""
     from dct_tpu.utils.profiling import chip_peak_flops
 
     peak = chip_peak_flops()
-    if peak:
-        source = (
-            "DCT_PEAK_TFLOPS" if os.environ.get("DCT_PEAK_TFLOPS")
-            else "device_table"
-        )
-        return peak, source
-    try:
-        return measure_host_peak_flops(), "measured_gemm"
-    except Exception:  # noqa: BLE001 — no numpy = no denominator
-        return None, "unknown"
+    if not peak:
+        return None, "not_measured"
+    return peak, (
+        "DCT_PEAK_TFLOPS" if os.environ.get("DCT_PEAK_TFLOPS")
+        else "device_table"
+    )
 
 
 # ----------------------------------------------------------------------

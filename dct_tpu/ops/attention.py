@@ -38,10 +38,8 @@ import os
 
 import jax
 import jax.numpy as jnp
-
-from dct_tpu.parallel.shard_map_compat import pcast_varying, shard_map
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG = -1e30  # finite "minus infinity": keeps the online max/exp NaN-free
@@ -540,9 +538,11 @@ def _ring_body(q, k, v, *, axis_name: str, ring_size: int, causal: bool,
     # from the first iteration on; typing them that way up front keeps
     # every step's accumulator type fixed.
     axes = tuple(vary_axes) or (axis_name,)
-    m = pcast_varying(jnp.full(q.shape[:-1], _NEG, jnp.float32), axes)
-    l = pcast_varying(jnp.zeros(q.shape[:-1], jnp.float32), axes)
-    o = pcast_varying(jnp.zeros(q.shape, jnp.float32), axes)
+    m = lax.pcast(
+        jnp.full(q.shape[:-1], _NEG, jnp.float32), axes, to="varying"
+    )
+    l = lax.pcast(jnp.zeros(q.shape[:-1], jnp.float32), axes, to="varying")
+    o = lax.pcast(jnp.zeros(q.shape, jnp.float32), axes, to="varying")
     k_cur, v_cur = k, v
     for step in range(n_steps):  # static unroll: ring_size is mesh shape
         src = (my - step) % ring_size
@@ -859,6 +859,38 @@ def a2a_attention(
     )(q, k, v)
 
 
+def _flash_per_shard(kernel, mesh: Mesh | None, q, k, v, *,
+                     data_axis: str = "data", model_axis: str = "model"):
+    """Run the single-shard flash ``kernel`` under ``mesh``.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), and inside a shard_map it needs EVERY
+    mesh axis manual. So on a multi-device mesh the kernel runs per
+    shard inside a full-manual shard_map — batch over ``data``, heads
+    over ``model``, the layout the surrounding program already has, so
+    no data moves. A shape that does not tile the mesh is a sizing bug
+    and raises (the batch-1 init trace never gets here — the caller
+    sends it down a JAX-level path)."""
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v)
+    b, h = q.shape[0], q.shape[1]
+    h_kv = k.shape[1]
+    n_data, n_model = mesh.shape[data_axis], mesh.shape[model_axis]
+    if b % n_data or h % n_model or h_kv % n_model:
+        raise ValueError(
+            f"flash attention shapes B={b}, H={h} (kv heads {h_kv}) do "
+            f"not tile mesh axes data={n_data}, model={n_model}; adjust "
+            "batch/heads or the mesh"
+        )
+    spec = P(data_axis, model_axis, None, None)
+    # check_vma=False for the same reason as the flash ring: interpret-
+    # mode pallas internals trip the varying-axes checker spuriously.
+    return shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
 def make_attention_fn(mesh: Mesh | None = None, *, causal: bool = False,
                       block_size: int = 512, window: int | None = None):
     """Pick the attention path per :func:`select_attention_path`: ring (or
@@ -884,21 +916,30 @@ def make_attention_fn(mesh: Mesh | None = None, *, causal: bool = False,
     def attn(q, k, v):
         t = q.shape[-2]
         # Tunable kernel tiles; the selection check uses the SAME values,
-        # so a non-dividing override degrades to blockwise instead of
-        # crashing inside the kernel.
+        # so a non-dividing override degrades to blockwise. The degrade
+        # is by SHAPE only: a kernel that fails to compile raises.
         bq = int(os.environ.get("DCT_FLASH_BLOCK_Q", "128"))
         bk = int(os.environ.get("DCT_FLASH_BLOCK_K", "128"))
         path = select_attention_path(
             t, block_size=block_size, flash_block=max(bq, bk)
         )
-        if path == "flash" and t % bq == 0 and t % bk == 0:
+        init_trace = mesh is not None and _is_init_trace_escape(
+            q, q.shape[0], mesh.shape["data"]
+        )
+        if (
+            path == "flash" and t % bq == 0 and t % bk == 0
+            and not init_trace
+        ):
             from dct_tpu.ops.pallas_attention import flash_attention
 
             # Windowed calls stay kernel-resident: the band mask lives in
             # the kernel and out-of-band tiles skip compute + DMA.
-            return flash_attention(
-                q, k, v, block_q=bq, block_k=bk, causal=causal,
-                interpret=bool(flash_interpret_mode()), window=window,
+            return _flash_per_shard(
+                functools.partial(
+                    flash_attention, block_q=bq, block_k=bk, causal=causal,
+                    interpret=bool(flash_interpret_mode()), window=window,
+                ),
+                mesh, q, k, v,
             )
         # 'flash' whose override blocks do not divide t degrades here too.
         if t > block_size and t % block_size == 0:

@@ -70,12 +70,9 @@ def _compiler_params():
     init at its inner sweep's first step, finalize at its last), only
     the innermost accumulation sweep is order-dependent. One helper so
     forward and backward cannot drift."""
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        return None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")
+    )
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
@@ -235,12 +232,9 @@ def _flash_fwd(q, k, v, *, block_q: int, block_k: int, causal: bool,
             return (kv_bh(bh), j, 0)
     compiler_params = _compiler_params()
     # Under a vma-checked shard_map the outputs must declare the inputs'
-    # device-varying axes explicitly; outside shard_map (and on jax
-    # versions without vma typing) this resolves to no kwarg at all.
-    try:
-        vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
-    except AttributeError:  # pragma: no cover - older jax
-        vma = frozenset()
+    # device-varying axes explicitly; outside shard_map this resolves to
+    # no kwarg at all.
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
     vma_kw = {"vma": vma} if vma else {}
     out_specs = [
         pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -454,10 +448,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
     lsef = jnp.broadcast_to(
         lse.reshape(b * h, t, 1), (b * h, t, _STATS_LANES)
     )
-    try:
-        vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
-    except AttributeError:  # pragma: no cover - older jax
-        vma = frozenset()
+    vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
     vma_kw = {"vma": vma} if vma else {}
 
     # Same DMA-elision trick as the forward: clamp skipped blocks'

@@ -50,14 +50,7 @@ def initialize_from_env(cfg: DistributedConfig | None = None) -> DistributedConf
         or ""
     )
     if platforms.split(",")[0].strip().lower() == "cpu":
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo"
-            )
-        except (AttributeError, ValueError):
-            # jax without the flag (or without gloo built in): keep the
-            # historical behavior rather than failing the launch.
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         jax.distributed.initialize(
             coordinator_address=cfg.coordinator_address,

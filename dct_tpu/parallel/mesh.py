@@ -99,8 +99,11 @@ def _device_grid(shape: list, devices: list):
     a contiguous aligned block (the input-pipeline contract
     :func:`process_data_block` enforces) — a torus mapping that
     interleaves a host's rows falls back to enumeration order instead of
-    aborting training at startup. CPU rigs and explicit subsets always
-    use enumeration order, which tests rely on.
+    aborting training at startup. A shape ``create_device_mesh`` cannot
+    place raises: asking for enumeration order is ``DCT_ICI_MESH=0``,
+    said out loud. CPU rigs and explicit subsets always use enumeration
+    order, which tests rely on. :func:`layout_of` reads back which one a
+    mesh got.
     """
     import sys
 
@@ -113,22 +116,24 @@ def _device_grid(shape: list, devices: list):
         and getattr(devices[0], "platform", "") == "tpu"
         and need == len(devices)
     ):
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            grid = mesh_utils.create_device_mesh(shape, devices=devices)
-            if _grid_blocks_contiguous(grid):
-                return grid
-            sys.stderr.write(
-                "[dct_tpu] ICI-aware layout interleaves a process's "
-                "data-axis rows; falling back to enumeration order\n"
-            )
-        except Exception as e:  # noqa: BLE001 — odd shapes/topologies:
-            sys.stderr.write(
-                f"[dct_tpu] create_device_mesh failed ({e}); falling back "
-                "to enumeration-order layout\n"
-            )
+        grid = mesh_utils.create_device_mesh(shape, devices=devices)
+        if _grid_blocks_contiguous(grid):
+            return grid
+        sys.stderr.write(
+            "[dct_tpu] ICI-aware layout interleaves a process's "
+            "data-axis rows; falling back to enumeration order\n"
+        )
     return np.array(devices[:need]).reshape(shape)
+
+
+def layout_of(mesh: Mesh) -> str:
+    """``"enumeration"`` when the mesh holds its devices in ascending-id
+    (``jax.devices()``) order, ``"ici"`` when ``create_device_mesh``
+    re-ordered them onto the torus."""
+    ids = [d.id for d in mesh.devices.flat]
+    return "enumeration" if ids == sorted(ids) else "ici"
 
 
 def process_data_block(mesh: Mesh) -> tuple[int, int]:

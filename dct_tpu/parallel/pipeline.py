@@ -30,8 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from dct_tpu.parallel.shard_map_compat import pcast_varying, shard_map
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -63,8 +62,8 @@ def _pipeline_body(params, xs, *, stage_fn, axis: str, n_stages: int):
     # The carry becomes device-varying over the pipe axis from the first
     # tick (stage-dependent compute); type the initial carry that way so
     # the scan carry type is fixed (same recipe as ring attention).
-    act0 = pcast_varying(jnp.zeros_like(xs[0]), (axis,))
-    ys0 = pcast_varying(jnp.zeros_like(xs), (axis,))
+    act0 = lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+    ys0 = lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
 
     def tick(carry, t):
         act, ys = carry
@@ -109,11 +108,9 @@ def gpipe_tick_apply(
     the stacked params/activations are sharded ``P('pipe', ...)`` the
     partitioner turns the vmap into per-shard stage compute and the roll
     into the neighbor collective-permute, with no shard_map involved.
-    This is the pipeline path on jax 0.4.x rigs where partial-manual
-    shard_map cannot lower (shard_map_compat.PARTIAL_AUTO_SHARD_MAP is
-    False), and the SPMD-GPipe comparator for the MPMD bubble bench
-    (bench.py ``mpmd_pipeline``); the tick structure — and therefore the
-    measured bubble — is the same either way.
+    The SPMD-GPipe comparator for the MPMD runner (tests/test_mpmd.py,
+    bench.py ``mpmd_pipeline``); the tick structure — and therefore the
+    bubble — is the same either way.
 
     Differentiable: ``jax.grad`` through the scan+roll yields the
     reverse tick schedule, exactly as with ppermute.

@@ -246,8 +246,13 @@ class WorkloadScheduler:
             return {}
         root = os.path.abspath(self.sched_cfg.root)
         env = {"DCT_COMPILE_CACHE": os.environ.get("DCT_COMPILE_CACHE") or "on"}
-        if not os.environ.get("DCT_COMPILE_CACHE_DIR"):
-            env["DCT_COMPILE_CACHE_DIR"] = os.path.join(root, "xla-cache-shared")
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            # Read by the supervised tenants' child processes (JAX has
+            # already read it in THIS process — inline tenants share the
+            # AOT store only).
+            env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+                root, "xla-cache-shared"
+            )
         if not os.environ.get("DCT_COMPILE_CACHE_AOT_DIR"):
             env["DCT_COMPILE_CACHE_AOT_DIR"] = os.path.join(root, "aot-shared")
         return env
@@ -544,6 +549,21 @@ class WorkloadScheduler:
             self._init_metrics()
             for i, spec in enumerate(self.tenants):
                 self._runtimes[spec.name] = self._build_runtime(spec, i)
+            supervised = [
+                rt for rt in self._runtimes.values()
+                if rt.cfg.loop.train_mode == "supervised"
+            ]
+            if self.sched_cfg.concurrent > 1 and len(supervised) > 1:
+                # Concurrent leases of supervised tenants are several
+                # trainer processes at once on this host.
+                from dct_tpu.utils.chip import refuse_shared_chip
+
+                for rt in supervised:
+                    refuse_shared_chip(
+                        f"DCT_SCHED_CONCURRENT={self.sched_cfg.concurrent} "
+                        f"with supervised tenant {rt.name!r}",
+                        {**os.environ, **rt.env},
+                    )
         except Exception:
             # A rejected roster must not leak the session's cache pins
             # into the process env.

@@ -13,7 +13,7 @@ Records are CRC-framed: an 8-byte little-endian header (payload length
 indices; a segment file's name carries the offset of its first record,
 so the partition's end offset is derivable by scanning ONE file.
 
-Durability contract, per the atomic-publish lint's taxonomy:
+Durability contract, per the atomic-publish lint's classes:
 
 - the ACTIVE segment is append-mode writes to a tmp-flavored name —
   in-progress state that readers must tolerate mid-write (the CRC

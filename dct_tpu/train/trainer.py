@@ -193,6 +193,19 @@ class TrainResult:
     # Training-health summary (observability.health.HealthMonitor):
     # nan/spike event counts and the last loss/grad-norm observed.
     health: dict = field(default_factory=dict)
+    # Where the run actually lived: sorted ids of the devices that held
+    # shards of the final train state and of the first dispatched train
+    # batch. A multi-chip run confined to device 0 shows up here.
+    placement: dict = field(default_factory=dict)
+
+
+def _device_ids(tree) -> list:
+    """Sorted ids of every device holding a shard of any leaf of ``tree``."""
+    ids: set = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        if isinstance(leaf, jax.Array):
+            ids.update(d.id for d in leaf.sharding.device_set)
+    return sorted(ids)
 
 
 class Trainer:
@@ -218,10 +231,9 @@ class Trainer:
     # ------------------------------------------------------------------
     def fit(self, data: WeatherArrays | None = None) -> TrainResult:
         cfg = self.cfg
-        # Persistent compile cache (ROADMAP item 5): point jax at the
-        # DCT_COMPILE_CACHE_DIR before this process's FIRST compile
-        # (model init below is one) — a supervised relaunch then disk-
-        # hits every program its dead predecessor already compiled.
+        # Persistent compile cache: arm it before this process's FIRST
+        # compile (model init below is one) — a supervised relaunch then
+        # disk-hits every program its dead predecessor already compiled.
         # No-op unless the env arms it (compilecache.cache docstring).
         from dct_tpu import compilecache as _compilecache
 
@@ -775,15 +787,15 @@ class Trainer:
 
         es_best: float | None = None
         es_stale = 0
+        batch_devices: list = []
         # For the epoch_chunk > 1 shadowing diagnostic: only span-END
         # params ever exist on device, so only span-end epochs can become
-        # the deploy "best" checkpoint (ADVICE r4).
+        # the deploy "best" checkpoint.
         span_end_vl_min = float("inf")
 
-        # Epoch chunking (scan path): fuse K epochs into one dispatch.
-        # On a slow control plane every epoch pays a host round trip that
-        # can dwarf the compute at parity batch sizes; chunking amortizes
-        # it to 1/K. Per-epoch metrics are preserved (the fused program
+        # Epoch chunking (scan path): fuse K epochs into one dispatch —
+        # one host round trip instead of K.
+        # Per-epoch metrics are preserved (the fused program
         # returns losses[K, S] and a 6-tuple of [K] eval sums); checkpoints, resume
         # snapshots, and early-stop effects move to chunk boundaries
         # (config.TrainConfig.epoch_chunk documents the trade).
@@ -1271,9 +1283,7 @@ class Trainer:
                             n_steps, globs = prefetched.result()
                         else:
                             n_steps, globs = _assemble_span(epoch, k)
-                    # Train span + full eval in ONE dispatch (the saved
-                    # host round trips are most of an epoch's wall time
-                    # on a slow control plane at the parity batch size).
+                    # Train span + full eval in ONE dispatch.
                     # Beat BEFORE the span's dispatch: the fused program
                     # can legitimately block for minutes (first-span
                     # compile, k fused epochs), and the monitor must see
@@ -1305,6 +1315,8 @@ class Trainer:
                     # `key=` threads the goodput dispatch key into the
                     # AOT store so cache hit/miss states line up 1:1
                     # with the compile.window accounting below.
+                    if not batch_devices:
+                        batch_devices = _device_ids(globs)
                     if multi_fused is not None:
                         state, losses, val_sums, gnorms = multi_fused(
                             state, *globs, *val_global, key=f"scan_k{k}"
@@ -1410,6 +1422,8 @@ class Trainer:
                                 bx = _np.array(bx, copy=True)
                                 bx[0, ...] = _np.nan
                             x, y, w = make_global_batch(self.mesh, bx, by, bw)
+                            if not batch_devices:
+                                batch_devices = _device_ids(x)
                         group = []
                         # The device_get of the loss is the step's real
                         # sync point — include it in the dispatch window.
@@ -1775,6 +1789,9 @@ class Trainer:
             goodput=goodput_summary,
             run_correlation_id=events.run_id,
             health=health_summary,
+            placement={
+                "state": _device_ids(state), "batch": batch_devices,
+            },
         )
 
     # ------------------------------------------------------------------

@@ -123,21 +123,29 @@ class Profiler:
 
 
 def chip_peak_flops() -> float | None:
-    """Best-effort bf16 peak FLOPs/sec per chip from the device kind
-    (None when unknown). Override with DCT_PEAK_TFLOPS."""
+    """bf16 peak FLOPs/sec per chip from the device kind. Override with
+    DCT_PEAK_TFLOPS. Off the TPU there is no chip to have a peak: None,
+    and whatever divides by it is "not measured". On the TPU a device
+    kind the table does not know is an error, not a default."""
     import jax
 
     env = os.environ.get("DCT_PEAK_TFLOPS")
     if env:
         return float(env) * 1e12
-    kind = jax.devices()[0].device_kind.lower()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    kind = dev.device_kind.lower()
     for pat, peak_t in (
         ("v6", 918.0), ("v5p", 459.0), ("v5 lite", 197.0), ("v5e", 197.0),
         ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
     ):
         if pat in kind:
             return peak_t * 1e12
-    return None
+    raise ValueError(
+        f"no peak FLOP/s on record for TPU device kind "
+        f"{dev.device_kind!r}; add it to the table or set DCT_PEAK_TFLOPS"
+    )
 
 
 def transformer_train_flops(

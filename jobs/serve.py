@@ -53,7 +53,14 @@ def _serve_pool(build_server, what: str, serving, host: str,
 
     from dct_tpu.resilience.supervisor import RestartPolicy
     from dct_tpu.serving.server import ServerPool
+    from dct_tpu.utils.chip import refuse_shared_chip
 
+    if serving.engine == "jax":
+        # Every forked worker builds its own jitted scorer and would
+        # claim the accelerator: refuse before the first fork.
+        refuse_shared_chip(
+            f"DCT_SERVE_PROCS={serving.processes} with DCT_SERVE_ENGINE=jax"
+        )
     pool = ServerPool(
         build_server, processes=serving.processes, host=host, port=port,
         restart_policy=RestartPolicy(max_restarts=serving.max_restarts),

@@ -99,7 +99,7 @@ def main() -> int:
         DCT_MPMD_PORT_BASE=os.environ.get("DCT_MPMD_PORT_BASE", "29650"),
         DCT_MPMD_TRANSFER_TIMEOUT_S="90",
         DCT_COMPILE_CACHE="auto",
-        DCT_COMPILE_CACHE_DIR=os.path.join(tmp, "xla_cache"),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "xla_cache"),
         DCT_WORLD_SIZE="2",
         DCT_RUN_ID="mpmd-smoke",
     )

@@ -98,7 +98,7 @@ def main() -> int:
         DCT_LOOP_MAX_WALL_S=str(int(WAIT_S)),
         # Warm relaunches: the steady-state loop configuration (PR 9).
         DCT_COMPILE_CACHE="on",
-        DCT_COMPILE_CACHE_DIR=os.path.join(work, "xla_cache"),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(work, "xla_cache"),
         DCT_EPOCH_CHUNK="1",
         DCT_BENCH_SPINUP="0",
     )

@@ -630,7 +630,7 @@ class TestTracePurity:
     def test_shard_map_body_flagged(self, tmp_path):
         src = (
             "import time\n"
-            "from dct_tpu.parallel.shard_map_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def make(mesh):\n"
             "    def body(x):\n"
             "        time.sleep(0.1)\n"

@@ -1,10 +1,8 @@
 """End-to-end smoke of ``bench.main()`` — the exact artifact the driver
 runs at end of round. The unit tests in test_val_parity.py /
 test_bench_record.py pin the pieces; this pins the WIRING: the one JSON
-line must land with the prior-onchip carry-forward, the val-parity
-numbers, and the probe stanza all present on a CPU-fallback run (the
-round-4 failure mode was precisely good pieces that never reached the
-driver's record)."""
+line must land with every section's digest and the val-parity numbers
+present on a CPU run."""
 
 import importlib
 import json
@@ -49,17 +47,6 @@ def test_bench_main_cpu_record_carries_everything(
     import bench
 
     bench = importlib.reload(bench)
-    monkeypatch.setattr(bench, "_REPO_ROOT", str(tmp_path))
-    # Plant prior on-chip evidence the CPU run must carry forward.
-    onchip = {"platform": "tpu", "value": 8342288.0, "mfu": 0.21,
-              "generated_utc": "2026-07-31T04:00:00Z"}
-    (tmp_path / "BENCH_ONCHIP_LATEST.json").write_text(json.dumps(onchip))
-    (tmp_path / "ONCHIP_CAMPAIGN.jsonl").write_text(
-        json.dumps({"section": "campaign", "item": "start",
-                    "result": {"platform": "tpu"}}) + "\n"
-        + json.dumps({"section": "mfu", "item": "base", "t": 1753934400.0,
-                      "result": {"mfu": 0.21}}) + "\n"
-    )
     try:
         bench.main()
     finally:
@@ -69,14 +56,14 @@ def test_bench_main_cpu_record_carries_everything(
 
     record = json.loads(out.strip().splitlines()[-1])
     # The driver's contract: ONE JSON line, headline fields present, and
-    # short enough to survive the driver's 2,000-byte stdout tail
-    # (r05's 2,578 B line parsed null — VERDICT r5 item 1).
+    # short enough to survive the driver's 2,000-byte stdout tail.
     line = out.strip().splitlines()[-1]
     assert len(line.encode()) <= 1800, len(line.encode())
     assert record["metric"] == "weather_parity_train_samples_per_sec_per_chip"
     assert record["platform"] == "cpu"
     assert record["value"] > 0
-    assert record["probe"]["platform"] == "cpu"
+    # No chip, no headline MFU: nothing computed on the CPU stands in.
+    assert record["mfu"] is None and "mfu_source" not in record
     assert "generated_utc" in record
     # Dispatch-gap tracker: the ratio rides every record. fused/fit
     # duplicate the top-level value / trainer_loop keys byte for byte,
@@ -106,23 +93,13 @@ def test_bench_main_cpu_record_carries_everything(
     # the per-variant p50 pair stays in the partial.
     assert isinstance(sl["publish_overhead_ms"], float)
     assert "snapshot_publish" not in sl
-    # Carry-forward ON STDOUT is a compact digest (headline numbers +
-    # provenance); the verbatim record lives in the partial on disk.
-    po = record["prior_onchip"]
-    assert po["source"] == "BENCH_ONCHIP_LATEST.json"
-    assert po["captured_utc"] == "2026-07-31T04:00:00Z"
-    assert po["value"] == onchip["value"]
-    assert po["mfu"] == onchip["mfu"]
-    assert po["platform"] == "tpu"
-    assert "record" not in po  # digest, not the verbatim embed
-    assert po["campaign_items"] == 1
     # North-star val parity: both numbers in the driver record; the
     # protocol prose is trimmed to its BASELINE.md pointer on stdout.
     vp = record["val_parity"]
     assert vp["torch_val_loss"] > 0 and vp["jax_val_loss"] > 0
     assert vp["protocol"] == "BASELINE.md row 1"
-    # The partial on disk is the VERBATIM record (crash hedge + the
-    # carry-forward's full provenance), matching stdout's digest.
+    # The partial on disk is the VERBATIM record (the crash hedge),
+    # matching stdout's digest.
     # Skipped-not-absent: the gated restart_spinup / cycle_freshness
     # legs leave their null markers (DCT_BENCH_SPINUP=0 /
     # DCT_BENCH_FRESHNESS=0 above), like every skippable section.
@@ -139,8 +116,6 @@ def test_bench_main_cpu_record_carries_everything(
     assert partial["serving_load"]["baseline_qps"] > 0
     assert partial["serving_load"]["snapshot_publish"]["plain_p50_ms"] > 0
     assert partial["serving_load"]["snapshot_publish"]["publish_p50_ms"] > 0
-    assert partial["prior_onchip"]["record"] == onchip
-    assert partial["prior_onchip"]["campaign"]["tpu_item_count"] == 1
     assert "train_lightning_ddp" in partial["val_parity"]["protocol"]
     import bench as bench_now
 

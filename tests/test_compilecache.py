@@ -94,6 +94,32 @@ def test_miss_publishes_artifact_then_fresh_store_hits(tmp_path):
     np.testing.assert_array_equal(np.asarray(prog2(*ARGS)), out_hit)
 
 
+def test_submesh_program_warm_loads_on_its_own_devices(tmp_path):
+    """A program compiled for a sub-mesh of a many-device backend loads
+    back onto exactly those devices (deserialize_and_load defaults to
+    EVERY device of the backend, which makes a 2-device program an
+    8-device one that dies at its first call)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devs = jax.devices()[2:4]
+    mesh = Mesh(np.array(devs), ("data",))
+    x = jax.device_put(ARGS[0], NamedSharding(mesh, P("data")))
+    y = jax.device_put(ARGS[1], NamedSharding(mesh, P()))
+    ref = np.asarray(_mk_store(tmp_path).wrap(_jit_fn(), program="p")(x, y))
+    (art,) = [f for f in os.listdir(tmp_path) if f.endswith(".aotx")]
+    with open(tmp_path / art, "rb") as f:
+        header = json.loads(f.read().split(b"\n", 2)[1])
+    assert header["device_ids"] == [d.id for d in devs]
+
+    events: list = []
+    store = _mk_store(tmp_path, events)
+    out = store.wrap(_jit_fn(), program="p")(x, y)
+    assert store.states == {"p": "hit"}
+    assert not [e for e in events if e["event"] == "compile.cache_miss"]
+    assert {d.id for d in out.sharding.device_set} == {d.id for d in devs}
+    np.testing.assert_array_equal(np.asarray(out), ref)
+
+
 def test_corrupt_artifact_is_loud_miss_with_identical_results(tmp_path):
     store = _mk_store(tmp_path)
     ref = np.asarray(store.wrap(_jit_fn(), program="p")(*ARGS))
@@ -182,17 +208,17 @@ def test_non_jit_callable_degrades_to_plain_call(tmp_path):
 
 def test_cache_mode_resolution(monkeypatch):
     monkeypatch.delenv("DCT_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("DCT_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert cc_cache.cache_mode() == "auto"
     assert cc_cache.resolve_cache_dir() is None
     assert not cc_cache.enabled() and not cc_cache.aot_enabled()
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", "/tmp/cc")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/cc")
     assert cc_cache.resolve_cache_dir() == "/tmp/cc"
     assert cc_cache.enabled() and cc_cache.aot_enabled()
     monkeypatch.setenv("DCT_COMPILE_CACHE", "off")
     assert not cc_cache.enabled()
     monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
-    monkeypatch.delenv("DCT_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert cc_cache.resolve_cache_dir() == cc_cache.DEFAULT_CACHE_DIR
     monkeypatch.setenv("DCT_COMPILE_CACHE_AOT", "0")
     assert cc_cache.enabled() and not cc_cache.aot_enabled()
@@ -210,26 +236,26 @@ def test_store_from_env_gating(tmp_path, monkeypatch):
 
 def test_export_env_pins_resolved_dir(monkeypatch):
     monkeypatch.delenv("DCT_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("DCT_COMPILE_CACHE_DIR", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     child: dict = {}
     cc_cache.export_env(child)
-    assert "DCT_COMPILE_CACHE_DIR" not in child  # cache off -> no-op
+    assert "JAX_COMPILATION_CACHE_DIR" not in child  # cache off -> no-op
     child = {"DCT_COMPILE_CACHE": "on"}
     cc_cache.export_env(child)
-    assert child["DCT_COMPILE_CACHE_DIR"] == os.path.abspath(
+    assert child["JAX_COMPILATION_CACHE_DIR"] == os.path.abspath(
         cc_cache.DEFAULT_CACHE_DIR
     )
     # An explicit parent-env dir is pinned verbatim (absolute), so
     # every relaunch attempt resolves the SAME directory even if the
     # supervisor and ranks run from different cwds.
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", "/tmp/mine")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/mine")
     child = {"DCT_COMPILE_CACHE": "on"}
     cc_cache.export_env(child)
-    assert child["DCT_COMPILE_CACHE_DIR"] == os.path.abspath("/tmp/mine")
+    assert child["JAX_COMPILATION_CACHE_DIR"] == os.path.abspath("/tmp/mine")
     monkeypatch.setenv("DCT_COMPILE_CACHE", "off")
     child = {"DCT_COMPILE_CACHE": "off"}
     cc_cache.export_env(child)
-    assert "DCT_COMPILE_CACHE_DIR" not in child
+    assert "JAX_COMPILATION_CACHE_DIR" not in child
 
 
 def test_warm_sizes_parse(monkeypatch):
@@ -361,7 +387,7 @@ def test_trainer_warm_rerun_is_bitwise_identical_and_labelled(
     and byte-identical deploy checkpoints."""
     processed = _processed_dir(tmp_path)
     monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     monkeypatch.setenv("DCT_COMPILE_CACHE_AOT_DIR", str(tmp_path / "aot"))
     res_a, win_a = _fit_once(tmp_path, "a", monkeypatch, processed)
     res_b, win_b = _fit_once(tmp_path, "b", monkeypatch, processed)
@@ -380,7 +406,7 @@ def test_trainer_corrupt_artifact_degrades_to_identical_compile(
     path and still reproduces run A bit for bit."""
     processed = _processed_dir(tmp_path)
     monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     monkeypatch.setenv("DCT_COMPILE_CACHE_AOT_DIR", str(tmp_path / "aot"))
     res_a, _ = _fit_once(tmp_path, "a", monkeypatch, processed)
     for name in os.listdir(tmp_path / "aot"):
@@ -403,7 +429,7 @@ def test_trainer_cache_off_matches_cache_on_bitwise(tmp_path, monkeypatch):
     monkeypatch.setenv("DCT_COMPILE_CACHE", "off")
     res_off, win_off = _fit_once(tmp_path, "off", monkeypatch, processed)
     monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     monkeypatch.setenv("DCT_COMPILE_CACHE_AOT_DIR", str(tmp_path / "aot"))
     _fit_once(tmp_path, "warmup", monkeypatch, processed)
     res_hit, win_hit = _fit_once(tmp_path, "hit", monkeypatch, processed)
@@ -481,7 +507,7 @@ def test_scorer_identity_includes_weights_digest(tmp_path, monkeypatch):
 
     x = rng.normal(size=(2, 4)).astype(np.float32)
     monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     w_a, w_b = mk_weights(1), mk_weights(2)
     probs_a = _build_jax_scorer(w_a, dict(meta))(x)
     probs_b = _build_jax_scorer(w_b, dict(meta))(x)

@@ -161,3 +161,39 @@ def test_nondividing_flash_block_override_degrades(monkeypatch, rng):
     out = attn(q, k, v)
     ref = dense_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_flash_under_a_mesh_runs_per_shard(monkeypatch, rng):
+    """GSPMD cannot partition a Mosaic kernel (the four-chip smoke died
+    on exactly that), so under a multi-device mesh the single-shard
+    flash path runs inside a full-manual shard_map: batch over data,
+    heads over model — same numbers, forward and backward, and the
+    traced program holds the shard_map."""
+    monkeypatch.setenv("DCT_FLASH", "interpret")
+    mesh = make_mesh(MeshConfig(data=4, model=2))
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((4, 2, 256, 32)), jnp.float32)
+        for _ in range(3)
+    )
+    meshed = make_attention_fn(mesh, causal=True)
+    single = make_attention_fn(None, causal=True)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    jaxpr = str(jax.make_jaxpr(meshed)(q, k, v))
+    assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+    got = jax.jit(jax.value_and_grad(loss(meshed), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.value_and_grad(loss(single), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+    # A batch that does not tile the data axis is a sizing bug, said so.
+    with pytest.raises(ValueError, match="do not tile mesh axes"):
+        meshed(q[:3], k[:3], v[:3])
+    # The batch-1 init trace takes a JAX-level path instead.
+    one = jax.jit(meshed)(q[:1], k[:1], v[:1])
+    np.testing.assert_allclose(
+        one, dense_attention(q[:1], k[:1], v[:1], causal=True),
+        rtol=2e-5, atol=2e-5,
+    )

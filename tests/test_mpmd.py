@@ -570,7 +570,7 @@ def test_per_stage_aot_identity_and_warm_hit(tmp_path, monkeypatch):
     topology in the identity: a cold build misses (publishing per-stage
     artifacts with DISTINCT names), a warm rebuild hits every stage."""
     monkeypatch.setenv("DCT_COMPILE_CACHE", "auto")
-    monkeypatch.setenv("DCT_COMPILE_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     from dct_tpu import compilecache as _cc
 
     cfg = _small_cfg(tmp_path)

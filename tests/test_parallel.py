@@ -159,13 +159,14 @@ def test_device_grid_uses_ici_layout_on_tpu(monkeypatch):
     assert calls == [(2, 2, 2, 1)]
     assert list(grid_cpu.reshape(-1)) == list(cpu)
 
-    # A failing create_device_mesh degrades to enumeration order.
+    # A failing create_device_mesh raises: no silent enumeration order
+    # (DCT_ICI_MESH=0 below is how to ask for it).
     def boom(shape, devices=None):
         raise ValueError("unsupported topology")
 
     monkeypatch.setattr(mesh_utils, "create_device_mesh", boom)
-    grid_fb = mesh_mod._device_grid([8, 1, 1, 1], fakes)
-    assert list(grid_fb.reshape(-1)) == fakes
+    with pytest.raises(ValueError, match="unsupported topology"):
+        mesh_mod._device_grid([8, 1, 1, 1], fakes)
 
     # DCT_ICI_MESH=0 opts out entirely.
     monkeypatch.setattr(mesh_utils, "create_device_mesh", fake_create)
@@ -173,6 +174,15 @@ def test_device_grid_uses_ici_layout_on_tpu(monkeypatch):
     grid_off = mesh_mod._device_grid([2, 2, 2, 1], fakes)
     assert calls == [(2, 2, 2, 1)]  # not called again
     assert list(grid_off.reshape(-1)) == fakes
+
+    # layout_of reads the order back off a real mesh.
+    from jax.sharding import Mesh
+
+    cpu4 = jax.devices()[:4]
+    assert mesh_mod.layout_of(Mesh(_np.array(cpu4), ("data",))) == "enumeration"
+    assert mesh_mod.layout_of(
+        Mesh(_np.array([cpu4[i] for i in (0, 1, 3, 2)]), ("data",))
+    ) == "ici"
 
 
 def test_device_grid_rejects_interleaved_process_rows(monkeypatch):

@@ -14,22 +14,6 @@ from dct_tpu.parallel.pipeline import (
     stage_params_sharding,
 )
 
-from dct_tpu.parallel.shard_map_compat import PARTIAL_AUTO_SHARD_MAP
-
-# jax 0.4.x's experimental shard_map translates the partial-manual
-# axis_names spelling to auto=, but its lowering rejects the pipeline's
-# programs (NotImplementedError on several collectives under
-# partial-auto, or downstream xla_extension errors). These cases need
-# the stable jax.shard_map (jax >= 0.5); on older rigs they are a known
-# API limit, not a regression.
-requires_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason=(
-        "partial-auto shard_map (pipe manual, data auto) is impossible "
-        "on jax 0.4.x's experimental API; needs jax >= 0.5 stable "
-        "jax.shard_map"
-    ),
-)
 
 D = 16
 N_STAGES = 4
@@ -61,7 +45,6 @@ def mesh():
 
 
 @pytest.mark.parametrize("n_microbatches", [4, 8])
-@requires_partial_auto
 def test_pipeline_matches_sequential(rng, mesh, n_microbatches):
     stages = _stages(rng)
     stacked = stack_stage_params(stages)
@@ -77,7 +60,6 @@ def test_pipeline_matches_sequential(rng, mesh, n_microbatches):
     )
 
 
-@requires_partial_auto
 def test_pipeline_grad_matches_sequential(rng, mesh):
     """jax.grad through the pipeline == grad of the sequential stack: the
     reverse (backward) pipeline schedule comes from AD, not hand code."""
@@ -105,7 +87,6 @@ def test_pipeline_grad_matches_sequential(rng, mesh):
     )
 
 
-@requires_partial_auto
 def test_pipeline_under_jit(rng, mesh):
     stages = _stages(rng)
     stacked = stack_stage_params(stages)

@@ -22,20 +22,6 @@ from dct_tpu.parallel.sharding_rules import (
 from dct_tpu.train.state import create_train_state
 from dct_tpu.train.steps import make_train_step
 
-from dct_tpu.parallel.shard_map_compat import PARTIAL_AUTO_SHARD_MAP
-
-# Same gate as tests/test_pipeline.py: these cases drive the pipeline's
-# partial-manual shard_map, which jax 0.4.x's experimental API cannot
-# lower (NotImplementedError / xla_extension errors) — a known API
-# limit on old rigs, not a regression.
-requires_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason=(
-        "partial-auto shard_map (pipe manual, data auto) is impossible "
-        "on jax 0.4.x's experimental API; needs jax >= 0.5 stable "
-        "jax.shard_map"
-    ),
-)
 
 CFG = dict(
     name="weather_transformer_pp", seq_len=8, d_model=16, n_heads=2,
@@ -48,7 +34,6 @@ def _model(mesh=None, **over):
     return get_model(cfg, input_dim=5, mesh=mesh)
 
 
-@requires_partial_auto
 def test_pp_matches_sequential(rng):
     """pipe=4 pipeline forward == the sequential stage stack (same params,
     mesh-less model instance) — the model-level pipeline oracle."""
@@ -154,7 +139,6 @@ def test_pp_untileable_real_batch_raises(rng):
         model.apply(params, x)
 
 
-@requires_partial_auto
 def test_pp_tp_composed_matches_sequential(rng):
     """PP x TP: stages streamed over `pipe` with their projection kernels
     sharded over `model` — output equals the meshless sequential stack
@@ -196,7 +180,6 @@ def test_pp_tp_composed_matches_sequential(rng):
     np.testing.assert_allclose(out, ref, atol=1e-4)
 
 
-@requires_partial_auto
 def test_pp_tp_train_step_runs(rng):
     """Full train step over the data x model x pipe mesh with composed
     PP x TP shardings: finite loss, params update."""
@@ -232,7 +215,6 @@ def test_pp_tp_train_step_runs(rng):
     assert np.abs(after - before).max() > 0  # grads flowed through PPxTP
 
 
-@requires_partial_auto
 def test_pp_tp_collective_in_hlo(rng):
     """The compiled PP x TP body contains a model-axis all-reduce INSIDE
     the pipeline (the row-parallel psum) — TP compute is real, not an
@@ -276,7 +258,6 @@ def test_pp_tp_collective_in_hlo(rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(h), atol=1e-4)
 
 
-@requires_partial_auto
 def test_pp_remat_is_layout_not_math(rng):
     """DCT_REMAT through the PP family: same param tree, same outputs and
     gradients as the non-remat pipeline (remat only reschedules the

@@ -16,8 +16,7 @@ Covers the acceptance surface end to end:
   loadable plugins/profile dir while the loss trajectory stays bitwise
   identical to an untriggered run;
 - MPMD transfer byte/latency histograms on the metrics plane;
-- the trajectory sentinel's program_mfu / transfer_wait_frac series and
-  the mfu_stale retirement rule.
+- the trajectory sentinel's program_mfu / transfer_wait_frac series.
 """
 
 from __future__ import annotations
@@ -609,26 +608,6 @@ def test_sentinel_program_mfu_and_transfer_series(tmp_path):
     series = {f["series"] for f in findings if f["kind"] == "regression"}
     assert "program_mfu" in series          # 25% drop > 10% threshold
     assert "transfer_wait_frac" in series   # 2x rise > 25% threshold
-
-
-def test_sentinel_retires_mfu_stale_with_local_mfu(tmp_path):
-    from dct_tpu.observability.report import compare_rounds, load_round
-
-    stale_no_local = load_round(_round(tmp_path, "BENCH_r01.json", {
-        "metric": "m", "value": 1.0,
-        "scaled_mfu_stale": True,
-        "scaled_mfu_stale_reason": "dead relay",
-    }))
-    stale_with_local = load_round(_round(tmp_path, "BENCH_r02.json", {
-        "metric": "m", "value": 1.0, "mfu": 0.21,
-        "roofline": {"mfu": 0.21},
-        "scaled_mfu_stale": True,
-        "scaled_mfu_stale_reason": "dead relay",
-    }))
-    kinds1 = [f["kind"] for f in compare_rounds([stale_no_local])]
-    assert "mfu_stale" in kinds1  # the pre-roofline record shape (r05)
-    kinds2 = [f["kind"] for f in compare_rounds([stale_with_local])]
-    assert "mfu_stale" not in kinds2  # local MFU retires the finding
 
 
 def test_inspector_roofline_section(tmp_path):

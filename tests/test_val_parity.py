@@ -1,5 +1,4 @@
-"""North-star val-loss parity (BASELINE.md protocol row 1) plus the
-prior-onchip evidence carry-forward (VERDICT r4 item 2).
+"""North-star val-loss parity (BASELINE.md protocol row 1).
 
 The parity band: the reference's exact end-to-end protocol — 10 epochs,
 batch 4, Adam lr 0.01, seeded 80/20 random split, MLP 5->64(ReLU,
@@ -51,128 +50,7 @@ def test_val_loss_parity_band(bench_mod, tmp_path):
     # split bug moves val_loss by >> 0.03 at loss ~0.3).
     assert out["abs_diff"] < 0.03, out
     # The leg must have streamed into the partial record the moment it
-    # was measured (the r4 lesson: unstreamed values die with the relay).
+    # was measured (unstreamed values die with a later failure).
     with open(bench_mod._PARTIAL_PATH) as f:
         on_disk = json.load(f)
     assert on_disk["scaled_legs"]["val_parity"]["abs_diff"] == out["abs_diff"]
-
-
-# --- prior_onchip carry-forward -----------------------------------------
-
-
-@pytest.fixture()
-def bench_iso(tmp_path, monkeypatch):
-    """bench with _REPO_ROOT pointed at an empty dir, so the real repo's
-    interim/campaign files cannot leak into these hermetic tests."""
-    monkeypatch.setenv(
-        "DCT_BENCH_PARTIAL", str(tmp_path / "BENCH_PARTIAL.json")
-    )
-    import bench
-
-    bench = importlib.reload(bench)
-    monkeypatch.setattr(bench, "_REPO_ROOT", str(tmp_path))
-    yield bench, tmp_path
-    monkeypatch.undo()
-    importlib.reload(bench)
-
-
-def test_no_evidence_returns_none(bench_iso):
-    bench, root = bench_iso
-    assert bench._prior_onchip_evidence(None) is None
-    # A CPU stash is not on-chip evidence.
-    assert (
-        bench._prior_onchip_evidence(({"platform": "cpu", "v": 1}, 1.0))
-        is None
-    )
-
-
-def test_onchip_latest_is_carried_verbatim(bench_iso):
-    bench, root = bench_iso
-    rec = {"platform": "tpu", "value": 8342288.0, "mfu": 0.21}
-    (root / "BENCH_ONCHIP_LATEST.json").write_text(json.dumps(rec))
-    out = bench._prior_onchip_evidence(None)
-    assert out["source"] == "BENCH_ONCHIP_LATEST.json"
-    assert out["record"] == rec  # verbatim, never merged
-    assert "captured_utc" in out
-
-
-def test_newest_tpu_candidate_wins_and_cpu_files_ignored(bench_iso):
-    bench, root = bench_iso
-    old = {"platform": "tpu", "value": 1.0}
-    cpu = {"platform": "cpu", "value": 99.0}
-    (root / "BENCH_INTERIM_r04.json").write_text(json.dumps(old))
-    os.utime(root / "BENCH_INTERIM_r04.json", (1000, 1000))
-    (root / "BENCH_ONCHIP_LATEST.json").write_text(json.dumps(cpu))
-    out = bench._prior_onchip_evidence(None)
-    assert out["record"] == old  # the CPU file must not shadow it
-    # A NEWER tpu stash beats the old interim file...
-    stash = {"platform": "tpu", "value": 2.0}
-    out2 = bench._prior_onchip_evidence((stash, 2000.0))
-    assert out2["record"] == stash
-    assert "stash" in out2["source"]
-    # ...but a STALE stash (captured before the interim landed) must
-    # not — the stash mtime is the one main() captured pre-overwrite,
-    # not the partial file's current (this-run) mtime.
-    out3 = bench._prior_onchip_evidence((stash, 500.0))
-    assert out3["record"] == old
-
-
-def test_complete_latest_outranks_newer_partial_evidence(bench_iso):
-    """BENCH_ONCHIP_LATEST.json is written only after a COMPLETE
-    successful on-chip bench — when present it wins outright over interim
-    records and the stash, whatever their mtimes (in the driver's fresh
-    checkout all mtimes are checkout time anyway)."""
-    bench, root = bench_iso
-    latest = {"platform": "tpu", "value": 7.0}
-    (root / "BENCH_ONCHIP_LATEST.json").write_text(json.dumps(latest))
-    (root / "BENCH_INTERIM_r05.json").write_text(
-        json.dumps({"platform": "tpu", "value": 1.0})
-    )
-    out = bench._prior_onchip_evidence(
-        ({"platform": "tpu", "value": 2.0}, 9e12)
-    )
-    assert out["record"] == latest
-
-
-def test_internal_timestamp_outranks_checkout_mtime(bench_iso):
-    """Records stamp generated_utc so evidence captured in different
-    sessions ranks by real capture time, not by (identical) checkout
-    mtimes."""
-    bench, root = bench_iso
-    older = {"platform": "tpu", "value": 1.0,
-             "generated_utc": "2026-07-29T01:00:00Z"}
-    newer = {"platform": "tpu", "value": 2.0,
-             "generated_utc": "2026-07-31T01:00:00Z"}
-    # Write the NEWER-stamped record first so its file mtime is older.
-    (root / "BENCH_INTERIM_a.json").write_text(json.dumps(newer))
-    (root / "BENCH_INTERIM_b.json").write_text(json.dumps(older))
-    out = bench._prior_onchip_evidence(None)
-    assert out["record"] == newer
-    assert out["captured_utc"] == "2026-07-31T01:00:00Z"
-
-
-def test_campaign_digest_tracks_platform_per_run(bench_iso):
-    bench, root = bench_iso
-    lines = [
-        # CPU smoke run: its items must NOT count as on-chip evidence.
-        {"section": "campaign", "item": "start",
-         "result": {"platform": "cpu"}},
-        {"section": "mfu", "item": "base", "t": 1.0,
-         "result": {"mfu": 0.001}},
-        # Real on-chip run.
-        {"section": "campaign", "item": "start",
-         "result": {"platform": "tpu"}},
-        {"section": "mfu", "item": "base", "t": 2.0,
-         "result": {"mfu": 0.21}},
-        {"section": "flash", "item": "8x8x2048x64_flash_256x256",
-         "t": 3.0, "result": {"fwd_speedup": 1.3}},
-        {"section": "campaign", "item": "end", "result": {}},
-    ]
-    (root / "ONCHIP_CAMPAIGN.jsonl").write_text(
-        "\n".join(json.dumps(l) for l in lines) + "\n"
-    )
-    out = bench._prior_onchip_evidence(None)
-    camp = out["campaign"]
-    assert camp["tpu_item_count"] == 2
-    assert [i["section"] for i in camp["tpu_items"]] == ["mfu", "flash"]
-    assert camp["tpu_items"][0]["result"]["mfu"] == 0.21
