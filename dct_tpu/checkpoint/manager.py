@@ -73,31 +73,44 @@ def save_checkpoint(path: str, params: Any, meta: dict) -> str:  # dct: noqa[ran
     remains and the previous publish stays intact. The temp name is
     pid-suffixed so concurrent writers (another rank, a stale zombie)
     cannot tear each other's in-flight temp.
+
+    Three spans, one per thing the call's seconds can go to: serialise,
+    disk, hash (``bytes`` = the file's size on each).
     """
-    payload = {"meta": dict(meta), "params": to_host(params)}
-    data = serialization.msgpack_serialize(payload)
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    tmp = f"{path}.tmp.{os.getpid()}"
-    with open(tmp, "wb") as f:
-        f.write(data)
-    # Fault hook INSIDE the vulnerable window: tmp written, final not
-    # yet renamed — the exact instant a preemption would tear a
-    # non-atomic write.
-    _faults.get_default().maybe_fire("save", save_kind="deploy", path=path)
-    os.replace(tmp, path)  # atomic: no torn ckpt if a rank dies mid-write
+    tracer = _spans.get_default()
+    base = os.path.basename(path)
+    with tracer.span("checkpoint.serialize", path=base) as sp:
+        payload = {"meta": dict(meta), "params": to_host(params)}
+        data = serialization.msgpack_serialize(payload)
+        sp.set(bytes=len(data))
+    with tracer.span("checkpoint.file_write", path=base, bytes=len(data)):
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        tmp = f"{path}.tmp.{os.getpid()}"
+        with open(tmp, "wb") as f:
+            f.write(data)
+        # Fault hook INSIDE the vulnerable window: tmp written, final
+        # not yet renamed — the exact instant a preemption would tear a
+        # non-atomic write.
+        _faults.get_default().maybe_fire(
+            "save", save_kind="deploy", path=path
+        )
+        os.replace(tmp, path)  # atomic: no torn ckpt if a rank dies mid-write
     lin = _lineage.get_default()
     if lin.enabled:
         # Content address from the serialized bytes already in hand (no
         # file re-read); edges to whatever training inputs the trainer
         # declared (dataset snapshot, a restored trajectory) make every
         # published checkpoint a walkable graph hop.
-        nid = lin.node(
-            "checkpoint", path=path,
-            sha256=hashlib.sha256(data).hexdigest(),
-            attrs={"epoch": dict(meta).get("epoch")},
-        )
-        for src in _lineage.run_inputs():
-            lin.edge("consumed", nid, src)
+        with tracer.span(
+            "checkpoint.lineage_hash", path=base, bytes=len(data)
+        ):
+            nid = lin.node(
+                "checkpoint", path=path,
+                sha256=hashlib.sha256(data).hexdigest(),
+                attrs={"epoch": dict(meta).get("epoch")},
+            )
+            for src in _lineage.run_inputs():
+                lin.edge("consumed", nid, src)
     return path
 
 
@@ -337,21 +350,16 @@ class TrainStateCheckpointer:  # dct: noqa[rank0-io] — per-process BY DESIGN: 
     ) -> str:
         """Write ``entries`` (+ meta + layout) into state.next, then
         rotate."""
-        # Span from whichever thread publishes (save_async's worker
-        # included): the resume-save I/O window on the trace timeline.
-        # try/finally so a FAILED write (ENOSPC — exactly the window an
-        # operator opens the trace to diagnose) is still recorded.
-        span = _spans.get_default().start(
-            "checkpoint.resume_save", component="checkpoint",
+        # A stack span on whichever thread publishes (save_async's
+        # worker included): the resume-save I/O window on the trace
+        # timeline. A FAILED write (ENOSPC — exactly the window an
+        # operator opens the trace to diagnose) is still recorded, with
+        # its error.
+        with _spans.get_default().span(
+            "checkpoint.resume_save",
             epochs_completed=(meta or {}).get("epochs_completed"),
-        )
-        try:
+        ):
             return self._publish_inner(entries, meta, layout)
-        except BaseException as e:
-            span.attrs["error"] = type(e).__name__
-            raise
-        finally:
-            span.end()
 
     def _publish_inner(
         self, entries: dict, meta: dict | None = None,
@@ -433,9 +441,14 @@ class TrainStateCheckpointer:  # dct: noqa[rank0-io] — per-process BY DESIGN: 
         ``save``/``restore``) before reading the checkpoint back."""
         import threading
 
-        self.wait()
-        entries = self._entries(state)
-        layout = self._layout(state)
+        tracer = _spans.get_default()
+        # The two things the caller's thread waits for here: the previous
+        # write's worker, then the device-to-host copy of the state.
+        with tracer.span("checkpoint.resume_wait_prev"):
+            self.wait()
+        with tracer.span("checkpoint.resume_snapshot"):
+            entries = self._entries(state)
+            layout = self._layout(state)
 
         def work():
             try:

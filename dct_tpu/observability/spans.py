@@ -39,12 +39,27 @@ Record schema::
 Telemetry must never fail the run: recording degrades to a no-op on OS
 errors, and a disabled recorder still mints span IDs so propagation
 (and tests over it) keep working with zero files written.
+
+The profiler's timeline: a span that lives on its thread's stack
+(:meth:`SpanRecorder.open`, :meth:`SpanRecorder.span`) also enters a
+``jax.profiler.TraceAnnotation`` of its name, with its scalar attrs and
+its ``span_id`` as the event's stats, and leaves it when it ends — so a
+``jax.profiler`` capture shows the recorder's spans on the thread that
+ran them, on the clock of the device trace, whether or not the JSONL
+sink is on. The ``span_id`` stat is what tells a program span from the
+runtime's own host events, and joins the two timelines. A
+:meth:`SpanRecorder.start` span may end on another thread or overlap
+its successor, which the profiler's per-thread nesting cannot show: it
+stays JSONL-only. A process that never imported jax (DAG tasks, the
+launcher) emits nothing and imports nothing. Outside a profiler session
+an annotation costs one atomic load.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -70,12 +85,20 @@ def env_parent_span_id(env=None) -> str | None:
     return (env if env is not None else os.environ).get(SPAN_ENV) or None
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attrs a profiler event can carry as stats."""
+    return {
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float, str))
+    }
+
+
 class Span:
     """One in-flight timed operation; call :meth:`end` exactly once."""
 
     __slots__ = (
         "recorder", "name", "component", "span_id", "parent_id",
-        "t0", "attrs", "_tid", "_ended",
+        "t0", "attrs", "_tid", "_ended", "_ann",
     )
 
     def __init__(self, recorder, name, component, span_id, parent_id,
@@ -89,9 +112,25 @@ class Span:
         self.attrs = attrs
         self._tid = tid
         self._ended = False
+        self._ann = None
+
+    def _enter_timeline(self) -> None:
+        """Enter this span's event on the profiler's timeline. Stack
+        spans only: the caller has just pushed it on this thread."""
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return
+        self._ann = profiler.TraceAnnotation(
+            self.name, **{**_scalars(self.attrs), "span_id": self.span_id}
+        )
+        self._ann.__enter__()
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        scalars = _scalars(attrs)
+        if self._ann is not None and scalars:
+            self._ann.set_metadata(**scalars)
         return self
 
     def end(self, **attrs) -> None:
@@ -99,7 +138,10 @@ class Span:
             return
         self._ended = True
         if attrs:
-            self.attrs.update(attrs)
+            self.set(**attrs)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         # A span opened with SpanRecorder.open sits on its thread's
         # stack; ending it pops it (identity-checked: ending from
         # another thread, or out of order, never corrupts the stack).
@@ -220,6 +262,7 @@ class SpanRecorder:
             name, component=component, parent_id=parent_id, **attrs
         )
         self._stack().append(sp)
+        sp._enter_timeline()
         return sp
 
     class _Ctx:
@@ -231,14 +274,15 @@ class SpanRecorder:
 
         def __enter__(self):
             self.recorder._stack().append(self.span)
+            self.span._enter_timeline()
             return self.span
 
         def __exit__(self, exc_type, exc, tb):
             st = self.recorder._stack()
             if st and st[-1] is self.span:
                 st.pop()
-            if exc_type is not None:
-                self.span.attrs.setdefault("error", exc_type.__name__)
+            if exc_type is not None and "error" not in self.span.attrs:
+                self.span.set(error=exc_type.__name__)
             self.span.end()
             return False
 

@@ -65,7 +65,7 @@ from dct_tpu.resilience import faults as _faults
 from dct_tpu.resilience.preempt import PreemptedError, PreemptionGuard
 from dct_tpu.tracking.client import get_tracker
 from dct_tpu.train.state import create_train_state
-from dct_tpu.utils.profiling import EpochTimer, Profiler, annotate
+from dct_tpu.utils.profiling import EpochTimer, Profiler
 from dct_tpu.train.steps import (
     make_epoch_train_eval_step,
     make_eval_step,
@@ -147,6 +147,44 @@ def optimizer_identity(train_cfg) -> dict:
         "momentum": float(train_cfg.momentum),
         "weight_decay": wd,
     }
+
+
+class _Timed:
+    """One interval of ``Trainer.fit``, read once and written twice: the
+    goodput ledger's clock is read on entry and on exit, and the seconds
+    between go to the ledger under ``category`` and onto a stack span
+    (JSONL and the profiler's timeline) as ``seconds`` — one bracket, so
+    the two timelines cannot drift. ``category=None`` bills nothing: the
+    dispatch and join windows go through ``add_dispatch``'s arithmetic,
+    which reads ``t0`` / ``t1`` / ``seconds`` here. A ``with`` block, or
+    ``begin()`` / ``end()`` where the interval cannot be one; ``end`` is
+    idempotent, for the crash sweep."""
+
+    def __init__(self, ledger, tracer, category, name, **attrs):
+        self._ledger, self._tracer = ledger, tracer
+        self._category, self._name, self._attrs = category, name, attrs
+        self.span = None
+        self.t0 = self.t1 = self.seconds = None
+
+    def begin(self) -> "_Timed":
+        self.span = self._tracer.open(self._name, **self._attrs)
+        self.t0 = self._ledger.clock()
+        return self
+
+    def end(self, **attrs) -> None:
+        if self.t1 is not None:
+            return
+        self.t1 = self._ledger.clock()
+        self.seconds = self.t1 - self.t0
+        if self._category is not None:
+            self._ledger.add(self._category, self.seconds)
+        self.span.end(seconds=self.seconds, **attrs)
+
+    __enter__ = begin
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end(**({"error": exc_type.__name__} if exc_type else {}))
+        return False
 
 
 @dataclass
@@ -308,8 +346,11 @@ class Trainer:
             model=cfg.model.name, epochs=cfg.train.epochs,
             resume=cfg.train.resume, world_size=jax.process_count(),
         )
-        _t_startup = ledger.clock()
-        startup_span = tracer.start("trainer.startup", component="trainer")
+
+        def timed(category, name, **attrs):
+            return _Timed(ledger, tracer, category, name, **attrs)
+
+        startup = timed("startup_recovery", "trainer.startup").begin()
         # Data-generation provenance for the always-on loop's freshness
         # accounting (dct_tpu.continuous): the incremental ETL stamps a
         # generation + arrival_ts into etl_state.json, read here BEFORE
@@ -817,9 +858,9 @@ class Trainer:
         # also overlaps compute). One span deep: bounded host memory, and
         # the device queue never sees stale epochs after an early stop.
         def _assemble_span(e0: int, k: int):
-            # Annotated HERE so the profiler span follows the work onto
-            # the prefetch thread (the consumer side only joins a future).
-            with annotate("host_epoch_assembly"):
+            # Spanned HERE so it follows the work onto the prefetch
+            # thread (the consumer side only joins a future).
+            with tracer.span("data.assemble", epoch=e0, k=k):
                 per = []
                 for e in range(e0, e0 + k):
                     xs, ys, ws = self._stack_epoch(train_loader, e)
@@ -865,14 +906,16 @@ class Trainer:
         # creation/sharding, resume restore, validation staging — is the
         # run's startup/recovery cost in the goodput ledger (and the
         # trainer.startup span: the ledger's window, on the timeline).
-        ledger.add("startup_recovery", ledger.clock() - _t_startup)
-        startup_span.end(resumed=start_epoch > 0)
+        startup.end(resumed=start_epoch > 0)
         completed = False
         preempted = False
         # In-flight phase spans, tracked so a crash mid-epoch still
         # records them (Span.end is idempotent: the success path's own
         # end() wins and the crash-path sweep becomes a no-op).
-        epoch_span = dispatch_span = ckpt_span = None
+        epoch_span = dispatch_span = bookkeep = None
+        # Program keys already dispatched: a key's first dispatch_call is
+        # its trace + AOT load or compile (attr first=true).
+        dispatched_keys: set = set()
         # Pipelined mode: the one dispatched-but-unbookkept span. Its
         # results are consumed one iteration late, while the NEXT span
         # computes on device; the crash sweep also closes its spans.
@@ -889,8 +932,12 @@ class Trainer:
             span's device compute) and the eager path. Returns
             ``stop_early``."""
             nonlocal es_best, es_stale, span_end_vl_min
-            nonlocal consumed_through, ckpt_span, layout_checked
+            nonlocal consumed_through, bookkeep, layout_checked
             e0, k = sp.epoch0, sp.k
+            # The scan path's consume opened it right after its join;
+            # the eager path enters here.
+            if bookkeep is None:
+                bookkeep = timed(None, "trainer.bookkeep", epoch=e0).begin()
             # Declared-vs-actual layout reconciliation, once, on the
             # FIRST span the jitted step produced: its output shardings
             # can drift from the declared rule layout (ZeRO-1 keeps the
@@ -1003,71 +1050,71 @@ class Trainer:
             if not math.isnan(_span_end_vl):
                 span_end_vl_min = min(span_end_vl_min, _span_end_vl)
             profiler.maybe_stop_span(e0, k)
-            # Host-gather BEFORE the coordinator gate: with TP/SP
-            # spanning processes this is a collective every rank must
-            # join; in the common fully-addressable case only the
-            # coordinator pays the device-to-host copy. Pipelined: the
-            # gathered state is the NEXT span's live input — valid
-            # because the fused step does not donate it in that mode.
-            _t_ckpt = ledger.clock()
-            # open (stack-pushed), not start: the checkpoint manager's
-            # own spans (checkpoint.deploy_write) parent implicitly to
-            # this thread's stack top, and they belong under the
-            # trainer.checkpoint window. Safe under pipelining — the
-            # whole push/end window is synchronous inside this consume,
-            # nothing else touches the stack in between.
-            ckpt_span = tracer.open(
-                "trainer.checkpoint", component="trainer",
-                epoch=e0 + k - 1, parent_id=sp.epoch_span.span_id,
-            )
-            if params_cross_process or self.coordinator:
-                host_params = to_host(sp.state.params)
-            if self.coordinator:
-                # Deploy-checkpoint policy at span granularity: only
-                # the span-end params exist on device, so best/last
-                # selection sees the span-end epoch's metrics (k == 1
-                # reduces to the per-epoch policy exactly).
-                _, last_vl, last_va, _ = sub_epochs[-1]
-                ckpt_metrics = {"val_loss": last_vl, "val_acc": last_va}
-                if "val_f1" in last_rec:
-                    ckpt_metrics["val_f1"] = last_rec["val_f1"]
-                ckptr.update(
-                    epoch=e0 + k - 1,
-                    metrics=ckpt_metrics,
-                    params=host_params,
-                    meta=meta,
-                )
-
-            # Every process keeps its own resume state (host-local
-            # disk) plus the run facts the next run's continuation
-            # semantics are decided from. The write overlaps the next
-            # epoch's compute (device->host snapshot is synchronous;
-            # the npz/rotation runs on a worker thread). On an early
-            # stop the run is marked COMPLETE at the stop point
-            # (target_epochs = epochs_completed) so a resumed run
-            # EXTENDS (continuous semantics) instead of "finishing"
-            # the abandoned target.
-            # Re-pin to the declared layout before snapshotting (a
-            # no-op for leaves already there; a collective reshard —
-            # every rank calls it — for any the step's output layout
-            # drifted, e.g. ZeRO-1 output params).
-            state_ckptr.save_async(
-                jax.device_put(sp.state, declared_shardings),
-                meta={
-                    "epochs_completed": e0 + k,
-                    "target_epochs": (
-                        e0 + k if stop_early else target_epochs
-                    ),
-                    # Exact resume refusal across optimizer configs
-                    # whose state trees are isomorphic (ADVICE r4).
-                    "optimizer": opt_identity,
-                },
-            )
+            bookkeep.end()
+            bookkeep = None
             # Both checkpoint tiers' synchronous cost (host gather,
             # deploy-tier writes, the resume snapshot's device->host
             # copy; the npz write itself overlaps on a worker thread).
-            ledger.add("checkpoint", ledger.clock() - _t_ckpt)
-            ckpt_span.end()
+            # A stack span: the checkpoint manager's own spans parent
+            # implicitly to this thread's stack top, and they belong
+            # under the trainer.checkpoint window. Safe under pipelining
+            # — the whole window is synchronous inside this consume,
+            # nothing else touches the stack in between.
+            with timed(
+                "checkpoint", "trainer.checkpoint",
+                epoch=e0 + k - 1, parent_id=sp.epoch_span.span_id,
+            ):
+                # Host-gather BEFORE the coordinator gate: with TP/SP
+                # spanning processes this is a collective every rank
+                # must join; in the common fully-addressable case only
+                # the coordinator pays the device-to-host copy.
+                # Pipelined: the gathered state is the NEXT span's live
+                # input — valid because the fused step does not donate
+                # it in that mode.
+                if params_cross_process or self.coordinator:
+                    with tracer.span("trainer.gather_params"):
+                        host_params = to_host(sp.state.params)
+                if self.coordinator:
+                    # Deploy-checkpoint policy at span granularity: only
+                    # the span-end params exist on device, so best/last
+                    # selection sees the span-end epoch's metrics (k == 1
+                    # reduces to the per-epoch policy exactly).
+                    _, last_vl, last_va, _ = sub_epochs[-1]
+                    ckpt_metrics = {"val_loss": last_vl, "val_acc": last_va}
+                    if "val_f1" in last_rec:
+                        ckpt_metrics["val_f1"] = last_rec["val_f1"]
+                    ckptr.update(
+                        epoch=e0 + k - 1,
+                        metrics=ckpt_metrics,
+                        params=host_params,
+                        meta=meta,
+                    )
+
+                # Every process keeps its own resume state (host-local
+                # disk) plus the run facts the next run's continuation
+                # semantics are decided from. The write overlaps the next
+                # epoch's compute (device->host snapshot is synchronous;
+                # the npz/rotation runs on a worker thread). On an early
+                # stop the run is marked COMPLETE at the stop point
+                # (target_epochs = epochs_completed) so a resumed run
+                # EXTENDS (continuous semantics) instead of "finishing"
+                # the abandoned target.
+                # Re-pin to the declared layout before snapshotting (a
+                # no-op for leaves already there; a collective reshard —
+                # every rank calls it — for any the step's output layout
+                # drifted, e.g. ZeRO-1 output params).
+                state_ckptr.save_async(
+                    jax.device_put(sp.state, declared_shardings),
+                    meta={
+                        "epochs_completed": e0 + k,
+                        "target_epochs": (
+                            e0 + k if stop_early else target_epochs
+                        ),
+                        # Exact resume refusal across optimizer configs
+                        # whose state trees are isomorphic (ADVICE r4).
+                        "optimizer": opt_identity,
+                    },
+                )
             sp.epoch_span.end(val_loss=sub_epochs[-1][1])
             consumed_through = e0 + k
             return stop_early
@@ -1079,7 +1126,7 @@ class Trainer:
             on device (so early-stop/health decisions trail the device
             by at most one span — the documented trade). Returns
             ``stop_early``."""
-            nonlocal global_step, dispatch_span, epoch_span
+            nonlocal global_step, dispatch_span, epoch_span, bookkeep
             import numpy as _np
 
             e0, k = sp.epoch0, sp.k
@@ -1088,37 +1135,42 @@ class Trainer:
             # in flight (a pipelined successor's live in pending).
             dispatch_span = sp.dispatch_span
             epoch_span = sp.epoch_span
-            _t_join = ledger.clock()
             # The device_get joins the span's program; the D2H copies
             # were started right after its dispatch, so in steady
-            # pipelined state the bytes are already on the host.
-            if multi_fused is not None:
-                # [K, S] losses; val_sums is a 6-tuple of [K] arrays
-                # (dtype-preserving per leaf — see
-                # make_multi_epoch_train_eval_step). Stack host-side as
-                # float64 -> [K, 6]; the upcast only protects the
-                # stacking, precision is bounded by the on-device f32
-                # accumulation (exact for integral weights up to 2^24
-                # per epoch, steps.py).
-                losses_host = _np.asarray(jax.device_get(sp.losses))
-                gnorms_host = _np.asarray(jax.device_get(sp.gnorms))
-                val_host = _np.stack(
-                    [
-                        _np.asarray(v, dtype=_np.float64)
-                        for v in jax.device_get(sp.val_sums)
-                    ],
-                    axis=1,
-                )
-            else:  # [S] / 6-tuple — the k == 1 parity layout
-                losses_host = _np.asarray(
-                    jax.device_get(sp.losses)
-                )[None]
-                gnorms_host = _np.asarray(
-                    jax.device_get(sp.gnorms)
-                )[None]
-                val_host = _np.asarray(
-                    [float(v) for v in jax.device_get(sp.val_sums)]
-                )[None]
+            # pipelined state the bytes are already on the host: this
+            # is the thread waiting for the device.
+            with timed(None, "trainer.join", epoch=e0, k=k) as join:
+                if multi_fused is not None:
+                    # [K, S] losses; val_sums is a 6-tuple of [K] arrays
+                    # (dtype-preserving per leaf — see
+                    # make_multi_epoch_train_eval_step). Stack host-side as
+                    # float64 -> [K, 6]; the upcast only protects the
+                    # stacking, precision is bounded by the on-device f32
+                    # accumulation (exact for integral weights up to 2^24
+                    # per epoch, steps.py).
+                    losses_host = _np.asarray(jax.device_get(sp.losses))
+                    gnorms_host = _np.asarray(jax.device_get(sp.gnorms))
+                    val_host = _np.stack(
+                        [
+                            _np.asarray(v, dtype=_np.float64)
+                            for v in jax.device_get(sp.val_sums)
+                        ],
+                        axis=1,
+                    )
+                else:  # [S] / 6-tuple — the k == 1 parity layout
+                    losses_host = _np.asarray(
+                        jax.device_get(sp.losses)
+                    )[None]
+                    gnorms_host = _np.asarray(
+                        jax.device_get(sp.gnorms)
+                    )[None]
+                    val_host = _np.asarray(
+                        [float(v) for v in jax.device_get(sp.val_sums)]
+                    )[None]
+            # Everything between the join and the checkpoint section
+            # (tracker, events, health, heartbeat); the ledger leaves
+            # it unattributed. _bookkeep_span closes it.
+            bookkeep = timed(None, "trainer.bookkeep", epoch=e0).begin()
             # Fused dispatch (train + eval in one program) bills to
             # train_step; its first occurrence per program shape is the
             # compile. Serial: one window, dispatch -> results joined
@@ -1133,9 +1185,9 @@ class Trainer:
             # bookkeeping is exactly the overlap the mode buys; it
             # surfaces as the other categories' windows, never twice.
             _billed = (
-                (sp.dispatch_elapsed + (ledger.clock() - _t_join))
+                (sp.dispatch_elapsed + join.seconds)
                 if pipelined
-                else (ledger.clock() - sp.t_dispatch)
+                else (join.t1 - sp.t_dispatch)
             )
             _billed_cat = ledger.add_dispatch(
                 "train_step", f"scan_k{k}", _billed,
@@ -1275,8 +1327,8 @@ class Trainer:
                 if use_scan:
                     # Goodput: joining the prefetch future (or assembling
                     # inline) is time the DEVICE spends waiting on data.
-                    with ledger.span("data_wait"), tracer.span(
-                        "trainer.data_wait", component="trainer",
+                    with timed(
+                        "data_wait", "trainer.data_wait",
                         epoch=epoch, parent_id=epoch_span.span_id,
                     ):
                         if prefetched is not None:
@@ -1306,32 +1358,39 @@ class Trainer:
                     # overlap PR 5 bought collapses back to serial. The
                     # join belongs in _consume_span, one span later.
                     # Enforced by dct-lint rule `span-sync`.
-                    _t_dispatch = ledger.clock()
+                    _key = f"scan_k{k}"
+                    # Dispatch to join: overlaps its successor under
+                    # pipelining, so JSONL-only (spans.py).
                     dispatch_span = tracer.start(
                         "trainer.dispatch", component="trainer",
-                        epoch=epoch, k=k, key=f"scan_k{k}",
+                        epoch=epoch, k=k, key=_key,
                         parent_id=epoch_span.span_id,
                     )
-                    # `key=` threads the goodput dispatch key into the
-                    # AOT store so cache hit/miss states line up 1:1
-                    # with the compile.window accounting below.
-                    if not batch_devices:
-                        batch_devices = _device_ids(globs)
-                    if multi_fused is not None:
-                        state, losses, val_sums, gnorms = multi_fused(
-                            state, *globs, *val_global, key=f"scan_k{k}"
-                        )
-                    else:
-                        state, losses, val_sums, gnorms = epoch_fused(
-                            state, *globs, *val_global, key=f"scan_k{k}"
-                        )
                     # Host-blocking cost of the dispatch call itself
-                    # (jit trace + XLA compile on the first span of a
-                    # program shape; ~enqueue after) — the pipelined
-                    # ledger bills this window separately from the
-                    # consume-time join so category windows stay
-                    # main-thread sequential (never double-counted).
-                    _dispatch_elapsed = ledger.clock() - _t_dispatch
+                    # (jit trace + AOT load or XLA compile on the first
+                    # span of a program shape; ~enqueue after) — the
+                    # pipelined ledger bills this window separately
+                    # from the consume-time join so category windows
+                    # stay main-thread sequential (never double-counted).
+                    with timed(
+                        None, "trainer.dispatch_call", epoch=epoch,
+                        key=_key, first=_key not in dispatched_keys,
+                        parent_id=dispatch_span.span_id,
+                    ) as dispatch_call:
+                        dispatched_keys.add(_key)
+                        # `key=` threads the goodput dispatch key into
+                        # the AOT store so cache hit/miss states line up
+                        # 1:1 with the compile.window accounting below.
+                        if not batch_devices:
+                            batch_devices = _device_ids(globs)
+                        if multi_fused is not None:
+                            state, losses, val_sums, gnorms = multi_fused(
+                                state, *globs, *val_global, key=_key
+                            )
+                        else:
+                            state, losses, val_sums, gnorms = epoch_fused(
+                                state, *globs, *val_global, key=_key
+                            )
                     # Non-blocking bookkeeping: start the D2H copies of
                     # everything consume will read NOW, so by the time
                     # the span is bookkept the bytes are already on the
@@ -1369,8 +1428,8 @@ class Trainer:
                     cur = _SpanInFlight(
                         epoch0=epoch, k=k, n_steps=n_steps, state=state,
                         losses=losses, val_sums=val_sums, gnorms=gnorms,
-                        t_dispatch=_t_dispatch,
-                        dispatch_elapsed=_dispatch_elapsed,
+                        t_dispatch=dispatch_call.t0,
+                        dispatch_elapsed=dispatch_call.seconds,
                         dispatch_span=dispatch_span,
                         epoch_span=epoch_span,
                     )
@@ -1404,8 +1463,7 @@ class Trainer:
                         group.append(batch)
                         if len(group) < accum:
                             continue
-                        with annotate("host_batch_staging"), \
-                                ledger.span("data_wait"):
+                        with timed("data_wait", "data.stage"):
                             if accum > 1:
                                 bx = _np.concatenate([b.x for b in group])
                                 by = _np.concatenate([b.y for b in group])
@@ -1602,7 +1660,7 @@ class Trainer:
                             # inspect: record any span still in flight
                             # (pipelined, the un-bookkept successor's
                             # spans live in `pending`).
-                            in_flight = [dispatch_span, ckpt_span,
+                            in_flight = [bookkeep, dispatch_span,
                                          epoch_span]
                             if pending is not None:
                                 in_flight += [pending.dispatch_span,
@@ -1634,39 +1692,40 @@ class Trainer:
 
         # Rank-0 post-train artifact upload, mirroring
         # jobs/train_lightning_ddp.py:146-164 (best, else last.ckpt fallback).
-        _t_upload = ledger.clock()
-        best_path = ckptr.best_model_path
-        if self.coordinator:
-            if not os.path.exists(best_path):
-                best_path = ckptr.last_path
-            if os.path.exists(best_path):
-                self.tracker.log_artifact(
-                    best_path, artifact_path=self.cfg.tracking.artifact_path
-                )
-                # log_model parity (MLFlowLogger(log_model=True) logs the
-                # model object too, reference jobs/train_lightning_ddp.py:95):
-                # the checkpoint plus loader metadata under artifact path
-                # "model", so the registry carries a self-describing model
-                # artifact, not only the raw .ckpt.
-                import json as _json
-                import tempfile as _tempfile
+        with timed(
+            "checkpoint", "trainer.upload", parent_id=fit_span.span_id
+        ):
+            best_path = ckptr.best_model_path
+            if self.coordinator:
+                if not os.path.exists(best_path):
+                    best_path = ckptr.last_path
+                if os.path.exists(best_path):
+                    self.tracker.log_artifact(
+                        best_path, artifact_path=self.cfg.tracking.artifact_path
+                    )
+                    # log_model parity (MLFlowLogger(log_model=True) logs the
+                    # model object too, reference jobs/train_lightning_ddp.py:95):
+                    # the checkpoint plus loader metadata under artifact path
+                    # "model", so the registry carries a self-describing model
+                    # artifact, not only the raw .ckpt.
+                    import json as _json
+                    import tempfile as _tempfile
 
-                with _tempfile.TemporaryDirectory() as td:
-                    mlmodel = os.path.join(td, "MLmodel.json")
-                    with open(mlmodel, "w") as f:
-                        _json.dump(
-                            {
-                                "flavor": "dct_tpu",
-                                "checkpoint": os.path.basename(best_path),
-                                "serving": "dct_tpu.serving.runtime",
-                                **meta,
-                            },
-                            f,
-                            indent=2,
-                        )
-                    self.tracker.log_artifact(mlmodel, artifact_path="model")
-                    self.tracker.log_artifact(best_path, artifact_path="model")
-        ledger.add("checkpoint", ledger.clock() - _t_upload)
+                    with _tempfile.TemporaryDirectory() as td:
+                        mlmodel = os.path.join(td, "MLmodel.json")
+                        with open(mlmodel, "w") as f:
+                            _json.dump(
+                                {
+                                    "flavor": "dct_tpu",
+                                    "checkpoint": os.path.basename(best_path),
+                                    "serving": "dct_tpu.serving.runtime",
+                                    **meta,
+                                },
+                                f,
+                                indent=2,
+                            )
+                        self.tracker.log_artifact(mlmodel, artifact_path="model")
+                        self.tracker.log_artifact(best_path, artifact_path="model")
 
         # Run-end goodput accounting: logged to the tracker NEXT TO
         # val_loss (a goodput regression becomes queryable exactly like

@@ -14,9 +14,11 @@ This module fills that gap TPU-natively:
 - :class:`EpochTimer` — wall-clock + throughput accounting per epoch
   (samples/sec and samples/sec/chip, the BASELINE.md north-star metric),
   ready to be logged as tracking metrics next to val_loss.
-- :func:`annotate` — host-side named spans (``jax.profiler.TraceAnnotation``)
-  so batch assembly and H2D staging show up on the trace timeline alongside
-  device work.
+
+Host-side named spans on the trace timeline (batch assembly, H2D staging,
+the checkpoint section) come from the span recorder
+(:mod:`dct_tpu.observability.spans`), whose stack spans are
+``jax.profiler.TraceAnnotation``s.
 
 Profiling is a window, not a mode: tracing every step of a long run would
 produce gigabytes and perturb the steady state, so the profiler arms itself
@@ -28,13 +30,6 @@ from __future__ import annotations
 import os
 import time
 from dataclasses import dataclass, field
-
-
-def annotate(name: str):
-    """Named host span that appears on the profiler timeline."""
-    import jax.profiler
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 class Profiler:
