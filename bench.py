@@ -732,17 +732,15 @@ def bench_scaled_transformer() -> dict:
     t_flash = None
     state_fl = None
     causal = {}
-    block_q = int(os.environ.get("DCT_FLASH_BLOCK_Q", "128"))
-    block_k = int(os.environ.get("DCT_FLASH_BLOCK_K", "128"))
     t = scaled["seq_len"]
-    flash_fits = t % block_q == 0 and t % block_k == 0
+    # The kernels pick their tiles from the shape
+    # (pallas_attention.flash_tiles), as the product path does.
+    flash_fits = t % 128 == 0
     if flash_interpret_mode() is False and not flash_fits:
-        # Same degrade-instead-of-crash policy as make_attention_fn
-        # (ops/attention.py:583): a sweep value that does not divide the
-        # sequence must not kill the whole bench record.
+        # A sequence the kernel cannot tile must not kill the whole
+        # bench record.
         print(
-            f"[bench] SKIP flash legs: blocks {block_q}x{block_k} do not "
-            f"divide seq_len {t}",
+            f"[bench] SKIP flash legs: seq_len {t} is no multiple of 128",
             file=sys.stderr, flush=True,
         )
     run_flash = flash_interpret_mode() is False and flash_fits
@@ -753,7 +751,7 @@ def bench_scaled_transformer() -> dict:
         from dct_tpu.ops.pallas_attention import flash_attention
 
         def flash_fn(q, k, v):
-            return flash_attention(q, k, v, block_q, block_k)
+            return flash_attention(q, k, v)
 
         # A Mosaic compile/runtime failure in a flash leg must degrade
         # to the blockwise-only record, not kill the section — the
@@ -778,7 +776,7 @@ def bench_scaled_transformer() -> dict:
         # (and elides their KV DMA) — roughly half the attention work —
         # while the XLA blockwise path computes every block and masks.
         def flash_causal(q, k, v):
-            return flash_attention(q, k, v, block_q, block_k, True)
+            return flash_attention(q, k, v, causal=True)
 
         def blockwise_causal(q, k, v):
             return blockwise_attention(
@@ -794,9 +792,7 @@ def bench_scaled_transformer() -> dict:
         win = int(os.environ.get("DCT_SCALED_WINDOW", str(max(1, t // 4))))
 
         def flash_window(q, k, v):
-            return flash_attention(
-                q, k, v, block_q, block_k, True, None, False, win
-            )
+            return flash_attention(q, k, v, causal=True, window=win)
 
         def blockwise_window(q, k, v):
             return blockwise_attention(
@@ -884,7 +880,7 @@ def bench_scaled_transformer() -> dict:
 
                 fl = _jax.jit(
                     lambda q_, k_, v_: flash_attention(
-                        q_, k_, v_, block_q, block_k, True
+                        q_, k_, v_, causal=True
                     )
                 )
                 t_mha = _time_op(fl, qa, kf, vf)

@@ -18,7 +18,7 @@ tmp+``os.replace`` so a reader can never see a torn artifact)::
 The header is the **load-or-miss contract**: every fingerprint
 (jax/jaxlib version, backend, device kind/count, process count, CPU
 arch) and every identity field (program, family, config_hash, mesh,
-extra) must match the loading process exactly, and the payload must
+code, extra) must match the loading process exactly, and the payload must
 hash to the header's sha256 — anything else is a LOUD miss
 (``compile.cache_miss`` event naming the reason) that falls back to a
 normal jit compile. A stale, foreign, or corrupted artifact can cost a
@@ -68,6 +68,28 @@ def runtime_fingerprint() -> dict:
         "process_count": jax.process_count(),
         "machine": _platform.machine(),
     }
+
+
+@functools.lru_cache(maxsize=1)
+def source_digest() -> str:
+    """Content digest of the program's own source: every ``.py`` file of
+    the ``dct_tpu`` package, by relative path and bytes. The configuration
+    says WHICH program was asked for; the code says what that program
+    does — a changed kernel or step under an unchanged configuration
+    compiles a different executable, so the code joins the identity
+    (:func:`store_from_env`) and an upgrade misses instead of running the
+    previous version's machine code. Milliseconds, once per process."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    h = hashlib.sha256()
+    for dirpath, dirnames, files in os.walk(root):
+        dirnames.sort()
+        for name in sorted(files):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()[:16]
 
 
 def signature_of(args) -> str:
@@ -552,11 +574,19 @@ def store_from_env(
     world's rank N deserializes exactly the executable its dead
     predecessor rank N compiled (the sharded supervised-relaunch path).
     The runtime fingerprint already pins ``process_count``, so a world
-    resized between runs is a loud miss, never a wrong execution."""
+    resized between runs is a loud miss, never a wrong execution.
+
+    ``code=<source_digest>`` joins the identity, hence the artifact's
+    name: two versions of the program sharing one store (an upgrade, two
+    checkouts under one ``JAX_COMPILATION_CACHE_DIR``) keep separate
+    artifacts and never load each other's."""
     from dct_tpu.compilecache.cache import aot_enabled
 
     on = bool(root) and aot_enabled()
-    identity = {"family": family, "config_hash": config_hash, "mesh": mesh}
+    identity = {
+        "family": family, "config_hash": config_hash, "mesh": mesh,
+        "code": source_digest(),
+    }
     if on:
         try:
             import jax

@@ -1258,8 +1258,6 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_RING_STRIPED": "zigzag layout for the causal ring: auto|on|off",
     # --- attention kernels -----------------------------------------
     "DCT_FLASH": "Pallas flash attention: auto | on | off | interpret",
-    "DCT_FLASH_BLOCK_Q": "flash kernel query-tile size",
-    "DCT_FLASH_BLOCK_K": "flash kernel key-tile size",
     "DCT_FLASH_BWD": "flash backward: kernel | remat escape hatch",
     # --- launcher / orchestration plumbing -------------------------
     "DCT_TRAIN_HOSTS": "comma-separated trainer hosts the DAG launches onto",

@@ -354,7 +354,7 @@ def _ring_window_steps(window: int | None, t_local: int, ring_size: int) -> int:
 
 def _ring_body_flash(q, k, v, *, axis_name: str, ring_size: int,
                      causal: bool, scale: float | None, interpret: bool,
-                     block_q: int = 128, block_k: int = 128,
+                     block_q: int | None = None, block_k: int | None = None,
                      window: int | None = None):
     """Ring attention whose per-shard block compute is the Pallas flash
     kernel. Runs inside shard_map on LOCAL shards [B, h_local, T_local, D].
@@ -436,7 +436,8 @@ def _ring_body_flash(q, k, v, *, axis_name: str, ring_size: int,
 
 def _ring_body_flash_striped(q, k, v, *, axis_name: str, ring_size: int,
                              scale: float | None, interpret: bool,
-                             block_q: int = 128, block_k: int = 128):
+                             block_q: int | None = None,
+                             block_k: int | None = None):
     """Balanced CAUSAL ring attention on the striped layout, flash
     per-shard compute. Local shards are [B, h, L, D] in striped order
     (first half = chunk ``my``, second half = chunk ``2R-1-my``; see
@@ -915,33 +916,26 @@ def make_attention_fn(mesh: Mesh | None = None, *, causal: bool = False,
 
     def attn(q, k, v):
         t = q.shape[-2]
-        # Tunable kernel tiles; the selection check uses the SAME values,
-        # so a non-dividing override degrades to blockwise. The degrade
-        # is by SHAPE only: a kernel that fails to compile raises.
-        bq = int(os.environ.get("DCT_FLASH_BLOCK_Q", "128"))
-        bk = int(os.environ.get("DCT_FLASH_BLOCK_K", "128"))
-        path = select_attention_path(
-            t, block_size=block_size, flash_block=max(bq, bk)
-        )
+        path = select_attention_path(t, block_size=block_size)
         init_trace = mesh is not None and _is_init_trace_escape(
             q, q.shape[0], mesh.shape["data"]
         )
-        if (
-            path == "flash" and t % bq == 0 and t % bk == 0
-            and not init_trace
-        ):
+        if path == "flash" and not init_trace:
             from dct_tpu.ops.pallas_attention import flash_attention
 
+            # The kernels pick their tiles from the shape
+            # (pallas_attention.flash_tiles): every multiple of 128 the
+            # policy sends here has a tile that divides it. A kernel that
+            # fails to compile raises; nothing degrades silently.
             # Windowed calls stay kernel-resident: the band mask lives in
             # the kernel and out-of-band tiles skip compute + DMA.
             return _flash_per_shard(
                 functools.partial(
-                    flash_attention, block_q=bq, block_k=bk, causal=causal,
+                    flash_attention, causal=causal,
                     interpret=bool(flash_interpret_mode()), window=window,
                 ),
                 mesh, q, k, v,
             )
-        # 'flash' whose override blocks do not divide t degrades here too.
         if t > block_size and t % block_size == 0:
             return blockwise_attention(
                 q, k, v, block_size=block_size, causal=causal, window=window

@@ -15,6 +15,17 @@ kernels) — O(T·window) FLOPs instead of the causal O(T²/2). ``q_offset``
 statically shifts the q positions so the windowed ring's partial-band
 shards (q-k distance = step·T_local) reuse the same kernel.
 
+The tiles are a function of the shape (:func:`flash_tiles`): the largest
+multiple of 128 that divides the sequence extent, up to a cap
+measured on the chip (1024 at head size 128 in bf16: 1024 x 1024 at 4,096
+positions, one 512 x 512 tile at 512). A grid step costs a fixed ~0.35 us
+and the per-step bookkeeping is per q row, so a step has to carry enough
+MXU work to bury both; at 128 x 128 the kernels spent nine tenths of their
+time on neither matmuls nor softmax (PERF.md section 6, PR 26). The mask
+(two iotas, the compares, two selects over the f32 score tile) is built
+only on tiles the diagonal or the band's trailing edge cuts; an interior
+tile, where every pair is visible, runs the same math without it.
+
 The running stats use the same online update as
 :func:`dct_tpu.ops.attention._online_block`; they are re-expressed here in
 2-D keepdims layout ([block_q, 1] rows, lane-broadcast scratch tiles)
@@ -75,6 +86,107 @@ def _compiler_params():
     )
 
 
+#: Largest tile side, measured on a v5e at head size 128 in bf16 (PERF.md
+#: section 6, PR 26: the sweep over {128..2048}^2 at T=4096 and T=512,
+#: each kernel timed alone — the forward, dK/dV and dQ all came out at
+#: the same cap, for block_q and block_k alike). A grid step costs
+#: ~0.35 us whatever it holds, and the per-step bookkeeping (accumulator
+#: rescale, lane-broadcast stats, the backward's delta) is per q row, so
+#: the wider the tile the smaller its share; past the cap the causal
+#: diagonal's wasted half-tiles cost more than that saves, and the f32
+#: score temporaries outgrow the 16 MiB of scoped VMEM.
+_TILE_CAP = 1024
+#: Bytes of one operand row (d * itemsize) the cap was measured at.
+_CAP_ROW_BYTES = 128 * 2
+
+
+def _largest_tile(n: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``n``, at most ``cap``.
+    An extent that is no multiple of 128 gets ``min(n, 128)``: a short
+    sequence is one tile, and a long unaligned one is refused by the
+    kernel's own divisibility check ("pad upstream")."""
+    if n % 128:
+        return min(n, 128)
+    return max(c for c in range(128, min(n, cap) + 1, 128) if n % c == 0)
+
+
+def flash_tiles(t: int, tk: int, d: int, dtype) -> tuple[int, int]:
+    """``(block_q, block_k)`` of the three kernels from what they can see:
+    the two sequence extents, the head size and the operand dtype. Pure,
+    static per shape, no knob: the largest multiple of 128 that divides
+    the extent, up to the measured cap; the cap halves for every doubling
+    (rounded up) of the operand row's bytes over the measured 256: the
+    streamed tiles and the f32 accumulators grow with d, the scoped VMEM
+    does not, and at the measured row 1024 x 1024 is the last pair that
+    fits."""
+    row_bytes = d * jnp.dtype(dtype).itemsize
+    cap = _TILE_CAP
+    while cap > 128 and _CAP_ROW_BYTES * (_TILE_CAP // cap) < row_bytes:
+        cap //= 2
+    return _largest_tile(t, cap), _largest_tile(tk, cap)
+
+
+def _resolve_tiles(block_q, block_k, t: int, tk: int, d: int,
+                   dtype) -> tuple[int, int]:
+    """The tiles a kernel runs on: explicit ``block_q`` / ``block_k``
+    (tests, sweeps) win over :func:`flash_tiles`, clamped to the extents;
+    a tile that does not divide its extent is refused."""
+    rule_q, rule_k = flash_tiles(t, tk, d, dtype)
+    block_q = rule_q if block_q is None else min(block_q, t)
+    block_k = rule_k if block_k is None else min(block_k, tk)
+    if t % block_q or tk % block_k:
+        raise ValueError(
+            f"seq lens q={t}, kv={tk} must be multiples of "
+            f"block_q={block_q} and block_k={block_k} (pad upstream)"
+        )
+    return block_q, block_k
+
+
+def _tile_visibility(q_first, bq: int, k_first, bk: int, window):
+    """For the causal (banded) tile of q rows ``[q_first, q_first+bq)``
+    against keys ``[k_first, k_first+bk)``: ``(work, cut)``. ``work``:
+    some key is visible to some row, else the tile is skipped whole.
+    ``cut``: some (row, key) pair is masked — the diagonal or the band's
+    trailing edge crosses the tile — so the mask must be built; an
+    interior tile (work and not cut) needs no mask at all. The single
+    source of both conditions for the three kernels."""
+    q_last = q_first + bq - 1
+    k_last = k_first + bk - 1
+    work = k_first <= q_last
+    cut = k_last > q_first
+    if window is not None:
+        work &= q_first - k_last < window
+        cut |= q_last - k_first >= window
+    return work, cut
+
+
+def _tile_keep(q_first, bq: int, k_first, bk: int, window):
+    """The [bq, bk] visibility mask of a tile the diagonal or the band
+    cuts: attend iff 0 <= q_pos - k_pos (< window)."""
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = k_first + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
+
+
+def _run_tile(block, causal: bool, q_first, bq: int, k_first, bk: int,
+              window):
+    """Run ``block(keep)`` for one grid step: not at all on a tile with
+    no visible key, with the mask on a tile the diagonal or the band's
+    trailing edge cuts, and with ``keep=None`` (no iotas, compares or
+    selects) on an interior tile, where every pair is visible."""
+    if not causal:
+        block(None)
+        return
+    work, cut = _tile_visibility(q_first, bq, k_first, bk, window)
+    pl.when(work & cut)(
+        lambda: block(_tile_keep(q_first, bq, k_first, bk, window))
+    )
+    pl.when(work & jnp.logical_not(cut))(lambda: block(None))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
                       n_kv: int, causal: bool, scale: float,
                       with_lse: bool, window: int | None = None,
@@ -93,7 +205,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _block():
+    def _block(keep):
         # MXU operands stay in the INPUT dtype (bf16 on the product
         # path): upcasting q/k/v to f32 before the dots would run the
         # matmuls at f32 MXU rate — a fraction of bf16 throughput, and
@@ -110,26 +222,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [bq, block_k] f32
-        if causal:
-            # ``q_offset`` shifts the q positions (the windowed ring's
-            # static inter-shard distance); k positions stay 0-based.
-            q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0
-            )
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1
-            )
-            keep = q_pos >= k_pos
-            if window is not None:
-                # Sliding window band: attend iff 0 <= q_pos-k_pos < window.
-                keep &= q_pos - k_pos < window
+        if keep is not None:
             s = jnp.where(keep, s, _NEG)
         m_prev = m_ref[:, :1]  # [bq, 1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        if causal:
+        if keep is not None:
             # A fully-masked row would otherwise get p=exp(0)=1 per entry
             # (same guard as attention._online_block).
             p = jnp.where(keep, p, 0.0)
@@ -141,20 +241,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
-        # KV block j overlaps the triangle iff its first key position
-        # j*block_k is <= the block's last query position (qi+1)*bq - 1;
-        # blocks fully above the diagonal skip all compute (their DMA is
-        # also elided — the index map refetches the resident block).
-        work = j * block_k < q_offset + (qi + 1) * bq
-        if window is not None:
-            # ...and entirely-behind-the-band blocks (every distance
-            # >= window) skip too: this is where windowed flash recovers
-            # O(T*window) FLOPs from the O(T^2) causal sweep.
-            work &= q_offset + qi * bq - (j + 1) * block_k + 1 < window
-        pl.when(work)(_block)
-    else:
-        _block()
+    # A KV tile entirely above the diagonal, or entirely behind the
+    # window's band, skips all compute (its DMA is elided too — the index
+    # map refetches the resident block): this is where windowed flash
+    # recovers O(T*window) FLOPs from the O(T^2) causal sweep.
+    # ``q_offset`` shifts the q positions (the windowed ring's static
+    # inter-shard distance); k positions stay 0-based.
+    _run_tile(_block, causal, q_offset + qi * bq, bq, j * block_k, block_k,
+              window)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
@@ -168,7 +262,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
             )
 
 
-def _flash_fwd(q, k, v, *, block_q: int, block_k: int, causal: bool,
+def _flash_fwd(q, k, v, *, block_q: int | None = None,
+               block_k: int | None = None, causal: bool,
                scale: float | None, interpret: bool, with_lse: bool = False,
                window: int | None = None, q_offset: int = 0):
     b, h, t, d = q.shape
@@ -194,13 +289,7 @@ def _flash_fwd(q, k, v, *, block_q: int, block_k: int, causal: bool,
     # H/h_kv times through memory.
     group = h // h_kv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q = min(block_q, t)
-    block_k = min(block_k, tk)
-    if t % block_q or tk % block_k:
-        raise ValueError(
-            f"seq lens q={t}, kv={tk} must be multiples of "
-            f"block_q={block_q} and block_k={block_k} (pad upstream)"
-        )
+    block_q, block_k = _resolve_tiles(block_q, block_k, t, tk, d, q.dtype)
     n_kv = tk // block_k
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h_kv, tk, d)
@@ -314,7 +403,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _block():
+    def _block(keep):
         q = q_ref[...]
         k = k_ref[...]
         v = v_ref[...]
@@ -328,14 +417,6 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             do.astype(jnp.float32) * o.astype(jnp.float32),
             axis=-1, keepdims=True,
         )
-        keep = None
-        if causal:
-            bq = q.shape[0]
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep &= q_pos - k_pos < window
         p, ds = _bwd_block(q, k, v, do, lse, delta, scale, keep)
         # dV_j += P^T dO_i ; dK_j += dS^T Q_i  (contract over the q rows)
         dv_acc[...] += jax.lax.dot_general(
@@ -347,16 +428,10 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        # q block qi contributes to kv block j iff its last query position
-        # reaches the block's first key position (and, windowed, iff its
-        # first query is still inside the band of the block's last key).
-        work = (qi + 1) * block_q > j * bk
-        if window is not None:
-            work &= qi * block_q - (j + 1) * bk + 1 < window
-        pl.when(work)(_block)
-    else:
-        _block()
+    # q block qi contributes to kv block j iff its last query position
+    # reaches the block's first key position (and, windowed, iff its
+    # first query is still inside the band of the block's last key).
+    _run_tile(_block, causal, qi * block_q, block_q, j * bk, bk, window)
 
     @pl.when(i == group * n_q - 1)
     def _finalize():
@@ -376,7 +451,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _block():
+    def _block(keep):
         q = q_ref[...]
         k = k_ref[...]
         v = v_ref[...]
@@ -387,17 +462,6 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             do.astype(jnp.float32) * o.astype(jnp.float32),
             axis=-1, keepdims=True,
         )
-        keep = None
-        if causal:
-            q_pos = i * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0
-            )
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1
-            )
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep &= q_pos - k_pos < window
         _, ds = _bwd_block(q, k, v, do, lse, delta, scale, keep)
         # dQ_i += dS K_j
         dq_acc[...] += jax.lax.dot_general(
@@ -405,55 +469,50 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        work = j * block_k < (i + 1) * bq
-        if window is not None:
-            work &= i * bq - (j + 1) * block_k + 1 < window
-        pl.when(work)(_block)
-    else:
-        _block()
+    _run_tile(_block, causal, i * bq, bq, j * block_k, block_k, window)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
-               causal: bool, scale: float | None, interpret: bool,
-               window: int | None = None):
-    """FlashAttention-2-style backward: two Pallas kernels (dK/dV with the
-    Q sweep innermost; dQ with the KV sweep innermost). The score matrix
-    is recovered blockwise from the forward's lse — nothing O(T^2) ever
-    touches HBM in the backward either.
-
-    GQA runs kernel-resident in BOTH directions: dQ reads the grouped KV
-    through divided index maps (like the forward), and dK/dV grids over
-    the b*h_kv KV heads with the group's q heads swept sequentially into
-    one accumulator (a q-head-parallel grid would race); dk/dv come back
-    at the grouped head count."""
+def _bwd_operands(q, k, v, o, lse, do):
+    """Flat [bh, T, d] views of the backward's operands, the forward lse
+    [B,H,T] lane-broadcast to [bh, T, LANES] (Mosaic wants >=2-D vector
+    tiles; lane 0 is read back in-kernel), and the vma declaration the
+    outputs need under a checked shard_map."""
     b, h, t, d = q.shape
     h_kv = k.shape[1]
-    group = h // h_kv
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    n_q = t // block_q
-    n_kv = t // block_k
     flat = lambda a: a.reshape(b * h, t, d)
-    qf, of, dof = map(flat, (q, o, do))
-    kf = k.reshape(b * h_kv, t, d)
-    vf = v.reshape(b * h_kv, t, d)
-    # Forward lse [B,H,T] -> lane-broadcast [bh, T, LANES] (Mosaic wants
-    # >=2-D vector tiles; lane 0 is read back in-kernel).
     lsef = jnp.broadcast_to(
         lse.reshape(b * h, t, 1), (b * h, t, _STATS_LANES)
     )
     vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
-    vma_kw = {"vma": vma} if vma else {}
+    return (
+        flat(q), k.reshape(b * h_kv, t, d), v.reshape(b * h_kv, t, d),
+        flat(o), flat(do), lsef,
+    ), ({"vma": vma} if vma else {})
+
+
+def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
+                    block_k: int | None = None, causal: bool,
+                    scale: float | None, interpret: bool,
+                    window: int | None = None):
+    """dK/dV kernel: grid over the b*h_kv KV heads and kv tiles, with the
+    group's q heads swept sequentially into one resident accumulator pair
+    (a q-head-parallel grid would race); dk/dv come back at the grouped
+    head count."""
+    b, h, t, d = q.shape
+    h_kv = k.shape[1]
+    group = h // h_kv
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = _resolve_tiles(block_q, block_k, t, t, d, q.dtype)
+    n_q = t // block_q
+    operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
 
     # Same DMA-elision trick as the forward: clamp skipped blocks'
     # addresses onto a needed (resident) block so their fetch is elided.
-    # dK/dV sweeps i = member*n_q + qi per kv block j (grid row is a KV
+    # The sweep is i = member*n_q + qi per kv block j (grid row is a KV
     # head): causal needs qi >= j*bk/bq, a window needs
     # qi*bq <= window + (j+1)*bk - 2; the flat q row is the member's head.
     def q_row(bh, i):
@@ -473,14 +532,12 @@ def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
     q_spec = pl.BlockSpec((None, block_q, d), q_index)
     kv_spec = pl.BlockSpec((None, block_k, d), lambda bh, j, i: (bh, j, 0))
     lse_spec = pl.BlockSpec((None, block_q, _STATS_LANES), q_index)
-    compiler_params = _compiler_params()
-
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkdv_kernel, block_q=block_q, n_q=n_q,
             causal=causal, scale=scale, window=window, group=group,
         ),
-        grid=(b * h_kv, n_kv, group * n_q),
+        grid=(b * h_kv, t // block_k, group * n_q),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[
@@ -491,17 +548,30 @@ def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
             pltpu.VMEM((block_k, d), jnp.float32),  # dk accumulator
             pltpu.VMEM((block_k, d), jnp.float32),  # dv accumulator
         ],
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qf, kf, vf, of, dof, lsef)
+    )(*operands)
+    return dk.reshape(b, h_kv, t, d), dv.reshape(b, h_kv, t, d)
 
+
+def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
+                  block_k: int | None = None, causal: bool,
+                  scale: float | None, interpret: bool,
+                  window: int | None = None):
+    """dQ kernel: a q tile resident, the kv sweep innermost; the grouped
+    KV are read through divided index maps, like the forward."""
+    b, h, t, d = q.shape
+    h_kv = k.shape[1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = _resolve_tiles(block_q, block_k, t, t, d, q.dtype)
+    n_kv = t // block_k
+    operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
     kv_row = lambda bh: _kv_flat_row(bh, h, h_kv)
 
-    # dQ sweeps kv blocks j per q block i — same clamp as the forward's
-    # kv_index (above-diagonal down, behind-the-band up), KV rows divided
-    # to the grouped head.
+    # Same clamp as the forward's kv_index (above-diagonal down,
+    # behind-the-band up), KV rows divided to the grouped head.
     if causal:
-        def kv_index2(bh, i, j):
+        def kv_index(bh, i, j):
             jj = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
             if window is not None:
                 j_first = jnp.maximum(
@@ -510,11 +580,11 @@ def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
                 jj = jnp.maximum(jj, jnp.minimum(j_first, n_kv - 1))
             return (kv_row(bh), jj, 0)
     else:
-        def kv_index2(bh, i, j):
+        def kv_index(bh, i, j):
             return (kv_row(bh), j, 0)
-    q_spec2 = pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec2 = pl.BlockSpec((None, block_k, d), kv_index2)
-    lse_spec2 = pl.BlockSpec(
+    q_spec = pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    lse_spec = pl.BlockSpec(
         (None, block_q, _STATS_LANES), lambda bh, i, j: (bh, i, 0)
     )
     dq = pl.pallas_call(
@@ -522,25 +592,37 @@ def _flash_bwd(q, k, v, o, lse, do, *, block_q: int, block_k: int,
             _flash_bwd_dq_kernel, block_k=block_k, n_kv=n_kv,
             causal=causal, scale=scale, window=window,
         ),
-        grid=(b * h, n_q, n_kv),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2, lse_spec2],
-        out_specs=q_spec2,
+        grid=(b * h, t // block_q, n_kv),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, **vma_kw),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qf, kf, vf, of, dof, lsef)
+    )(*operands)
+    return dq.reshape(b, h, t, d)
 
-    unflat = lambda a: a.reshape(b, h, t, d)
-    return unflat(dq), dk.reshape(b, h_kv, t, d), dv.reshape(b, h_kv, t, d)
+
+def _flash_bwd(q, k, v, o, lse, do, **kw):
+    """FlashAttention-2-style backward: two Pallas kernels (dK/dV with the
+    Q sweep innermost; dQ with the KV sweep innermost). The score matrix
+    is recovered blockwise from the forward's lse — nothing O(T^2) ever
+    touches HBM in the backward either — and GQA runs kernel-resident in
+    BOTH directions."""
+    dk, dv = _flash_bwd_dkdv(q, k, v, o, lse, do, **kw)
+    return _flash_bwd_dq(q, k, v, o, lse, do, **kw), dk, dv
 
 
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
-def flash_attention(q, k, v, block_q=128, block_k=128, causal=False,
+def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
                     scale=None, interpret=False, window=None):
     """Flash attention; q,k,v [B, H, T, D] -> [B, H, T, D].
+
+    ``block_q`` / ``block_k`` None (the product path): the three kernels
+    take their tiles from :func:`flash_tiles`. Explicit values win
+    (tests, sweeps).
 
     ``window`` (causal-only sliding window): the band mask lives in the
     kernel and fully-out-of-band KV tiles skip compute AND DMA — the
@@ -559,6 +641,14 @@ def _vjp_fwd(q, k, v, block_q, block_k, causal, scale, interpret, window):
     return out, (q, k, v, out, lse)
 
 
+def _remat_block(block_k, k) -> int:
+    """KV block of the blockwise remat backward: the explicit key tile,
+    else 128 as before the shape rule — the JAX-level scan keeps a
+    [.., Tq, block] f32 score block in HBM, so the kernels' wide tiles
+    are not its optimum."""
+    return min(128 if block_k is None else block_k, k.shape[-2])
+
+
 def _vjp_bwd(block_q, block_k, causal, scale, interpret, window, res, g):
     q, k, v, o, lse = res
     rectangular = q.shape[-2] != k.shape[-2]  # bwd kernels assume square
@@ -569,7 +659,7 @@ def _vjp_bwd(block_q, block_k, causal, scale, interpret, window, res, g):
         # path instead of running the backward kernels.
         from dct_tpu.ops.attention import blockwise_attention
 
-        block = min(block_k, k.shape[-2])
+        block = _remat_block(block_k, k)
         _, vjp = jax.vjp(
             lambda q_, k_, v_: blockwise_attention(
                 q_, k_, v_, block_size=block, causal=causal, scale=scale,
@@ -588,7 +678,7 @@ flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def flash_attention_lse(q, k, v, block_q=128, block_k=128, causal=False,
+def flash_attention_lse(q, k, v, block_q=None, block_k=None, causal=False,
                         scale=None, interpret=False, window=None,
                         q_offset=0):
     """Flash attention that also returns the per-row log-sum-exp:
@@ -626,6 +716,7 @@ def _vjp_lse_bwd(block_q, block_k, causal, scale, interpret, window,
     from dct_tpu.ops.attention import blockwise_attention_lse
 
     q, k, v = res
+    block_k = _remat_block(block_k, k)
     # Static KV front-slice: with an offset band (the windowed ring's
     # partial shards), keys at j <= q_offset - window are behind the band
     # for EVERY q row — scanning them in the remat backward would waste
@@ -634,7 +725,7 @@ def _vjp_lse_bwd(block_q, block_k, causal, scale, interpret, window,
     j0 = 0
     if window is not None and q_offset:
         j0 = max(0, q_offset - window + 1)
-        j0 -= j0 % max(block_k, 1)
+        j0 -= j0 % block_k
         j0 = min(j0, k.shape[-2])  # fully-out-of-band shard: empty slice
     k_sl = k[..., j0:, :] if j0 else k
     v_sl = v[..., j0:, :] if j0 else v
@@ -642,10 +733,9 @@ def _vjp_lse_bwd(block_q, block_k, causal, scale, interpret, window,
         return (
             jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
         )
-    block = min(block_k, k_sl.shape[-2])
     _, vjp = jax.vjp(
         lambda q_, k_, v_: blockwise_attention_lse(
-            q_, k_, v_, block_size=block, causal=causal, scale=scale,
+            q_, k_, v_, block_size=block_k, causal=causal, scale=scale,
             window=window, q_offset=q_offset - j0,
         ),
         q, k_sl, v_sl,

@@ -175,6 +175,29 @@ def test_identity_mismatch_never_loads_foreign_program(tmp_path):
     assert len([f for f in os.listdir(tmp_path) if f.endswith(".aotx")]) == 2
 
 
+def test_code_change_never_loads_the_previous_versions_artifact(
+        tmp_path, monkeypatch):
+    """Same configuration, changed program source (a kernel rewritten
+    under an unchanged config): the store must miss, not run the old
+    machine code. Two versions sharing one store keep separate artifacts."""
+    from dct_tpu.compilecache import aot
+
+    monkeypatch.setenv("DCT_COMPILE_CACHE", "on")
+    digest = aot.source_digest()
+    assert digest == aot.source_digest() and len(digest) == 16
+    old = store_from_env(str(tmp_path), family="f", config_hash="c")
+    assert old.identity["code"] == digest
+    old.wrap(_jit_fn(), program="p")(*ARGS)
+    assert store_from_env(
+        str(tmp_path), family="f", config_hash="c"
+    ).wrap(_jit_fn(), program="p")(*ARGS) is not None
+    monkeypatch.setattr(aot, "source_digest", lambda: "0" * 16)
+    new = store_from_env(str(tmp_path), family="f", config_hash="c")
+    new.wrap(_jit_fn(), program="p")(*ARGS)
+    assert old.states == {"p": "miss"} and new.states == {"p": "miss"}
+    assert len([f for f in os.listdir(tmp_path) if f.endswith(".aotx")]) == 2
+
+
 def test_disabled_store_is_transparent(tmp_path):
     store = ExecutableStore(str(tmp_path), enabled=False)
     prog = store.wrap(_jit_fn(), program="p")

@@ -148,19 +148,68 @@ def test_ring_unaligned_falls_back(monkeypatch, rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_nondividing_flash_block_override_degrades(monkeypatch, rng):
-    """Review regression: a DCT_FLASH_BLOCK_K that does not divide T must
-    degrade to the blockwise/dense path, not crash inside the kernel."""
+@pytest.mark.parametrize("t", [384, 640])
+def test_every_aligned_length_takes_the_kernel(monkeypatch, rng, t):
+    """A length whose only 128-multiple divisors are small (384 = 3 x 128,
+    640 = 5 x 128) takes the kernel on a tile that divides it — it neither
+    falls to blockwise nor crashes inside the kernel."""
     monkeypatch.setenv("DCT_FLASH", "interpret")
-    monkeypatch.setenv("DCT_FLASH_BLOCK_K", "96")
     q, k, v = (
-        jnp.asarray(rng.standard_normal((1, 2, 256, 8)), jnp.float32)
+        jnp.asarray(rng.standard_normal((1, 2, t, 8)), jnp.float32)
         for _ in range(3)
     )
-    attn = make_attention_fn(None)
+    attn = make_attention_fn(None, causal=True)
+    assert "pallas_call" in str(jax.make_jaxpr(attn)(q, k, v))
     out = attn(q, k, v)
-    ref = dense_attention(q, k, v)
+    ref = dense_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize("t", [256, 384, 512, 640, 1024, 4096, 16384])
+def test_flash_tile_rule_table(monkeypatch, t):
+    """The shape rule, pinned: the tiles are multiples of 128 that divide
+    T, the largest such under the cap, and the policy still sends T to the
+    kernel. Wider operand rows (f32, or a head of 256) halve the cap and
+    never drop under 128."""
+    from dct_tpu.ops.pallas_attention import _TILE_CAP, flash_tiles
+
+    monkeypatch.setenv("DCT_FLASH", "interpret")
+    assert select_attention_path(t) == "flash"
+    tiles = flash_tiles(t, t, 128, jnp.bfloat16)
+    for tile in tiles:
+        assert tile % 128 == 0 and t % tile == 0 and tile <= _TILE_CAP
+        assert not any(
+            t % c == 0 for c in range(tile + 128, min(t, _TILE_CAP) + 1, 128)
+        )
+    wide = flash_tiles(t, t, 128, jnp.float32)
+    assert wide == flash_tiles(t, t, 256, jnp.bfloat16)
+    for tile, narrow in zip(wide, tiles):
+        assert tile % 128 == 0 and t % tile == 0
+        assert 128 <= tile <= _TILE_CAP // 2 and tile <= narrow
+    assert min(flash_tiles(t, t, 1024, jnp.float32)) >= 128
+    # A smaller head than the measured one never raises the cap.
+    assert flash_tiles(t, t, 64, jnp.bfloat16) == tiles
+
+
+def test_flash_tile_rule_benchmark_shapes_and_short_extents():
+    """The tiles the benchmark's two shapes run (PERF.md section 6, PR 26),
+    rectangular extents, and extents under or off the 128 grid: a short
+    sequence is one tile, a long unaligned one keeps 128 and is refused by
+    the kernel's divisibility check."""
+    from dct_tpu.ops.pallas_attention import flash_attention, flash_tiles
+
+    bf16 = jnp.bfloat16
+    assert flash_tiles(4096, 4096, 128, bf16) == (1024, 1024)
+    assert flash_tiles(512, 512, 128, bf16) == (512, 512)
+    assert flash_tiles(384, 384, 128, bf16) == (384, 384)
+    assert flash_tiles(640, 640, 128, bf16) == (640, 640)
+    assert flash_tiles(256, 2048, 128, bf16) == (256, 1024)
+    assert flash_tiles(4096, 4096, 192, bf16) == (512, 512)
+    assert flash_tiles(64, 48, 16, bf16) == (64, 48)
+    assert flash_tiles(320, 320, 16, bf16) == (128, 128)
+    q = jnp.zeros((1, 1, 320, 8), jnp.float32)
+    with pytest.raises(ValueError, match="pad upstream"):
+        flash_attention(q, q, q, interpret=True)
 
 
 def test_flash_under_a_mesh_runs_per_shard(monkeypatch, rng):

@@ -90,27 +90,58 @@ def test_grouped_dense_and_blockwise_match_oracle(grouped_qkv, causal):
     )
 
 
+#: (T, block_q, block_k) of the grouped kernel cases: the toy tile, and
+#: tiles above 128 (group 2 on [1, 4, T, 8]): square with an interior
+#: tile, rectangular either way round, and the shape rule's own (None).
+GROUPED_TILES = [
+    (T, 16, 16), (512, 256, 256), (1024, 512, 256), (1024, 256, 512),
+    (512, None, None),
+]
+
+
+def _grouped(rng, t):
+    if t == T:
+        b, d = B, D
+    else:
+        b, d = 1, 8
+    q = jnp.asarray(rng.standard_normal((b, H, t, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, HKV, t, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, HKV, t, d)), jnp.float32)
+    return q, k, v
+
+
+def _band(t, window):
+    """The toy band (24 of 64) scaled to the case's length (192 of 512,
+    384 of 1024): its trailing edge cuts some tiles above 128, leaves
+    others interior and puts the far ones wholly behind it."""
+    return None if window is None else window * t // T
+
+
+@pytest.mark.parametrize("t,block_q,block_k", GROUPED_TILES)
 @pytest.mark.parametrize("causal,window", [(False, None), (True, None),
                                            (True, 24)])
-def test_grouped_flash_matches_oracle(grouped_qkv, causal, window):
+def test_grouped_flash_matches_oracle(rng, causal, window, t, block_q,
+                                      block_k):
     """The kernel's divided KV index maps (KV tiles fetched once per
     group, never materialized at H heads) against the repeat oracle —
     composed with the causal skip and the window band."""
     from dct_tpu.ops.pallas_attention import flash_attention
 
-    q, k, v = grouped_qkv
+    q, k, v = _grouped(rng, t)
+    window = _band(t, window)
     ref = _dense_oracle(q, k, v, causal=causal, window=window)
     out = flash_attention(
-        q, k, v, block_q=16, block_k=16, causal=causal, interpret=True,
-        window=window,
+        q, k, v, block_q=block_q, block_k=block_k, causal=causal,
+        interpret=True, window=window,
     )
     np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5)
 
 
+@pytest.mark.parametrize("t,block_q,block_k", GROUPED_TILES)
 @pytest.mark.parametrize("bwd_mode", ["kernel", "remat"])
 @pytest.mark.parametrize("window", [None, 24])
-def test_grouped_flash_grad_matches_dense(grouped_qkv, bwd_mode, window,
-                                          monkeypatch):
+def test_grouped_flash_grad_matches_dense(rng, bwd_mode, window, t, block_q,
+                                          block_k, monkeypatch):
     """GQA backward, both modes: the kernel path grids dK/dV over the KV
     heads and sweeps the group's q heads sequentially into one
     accumulator (no race — a q-head-parallel grid would have one); the
@@ -119,12 +150,13 @@ def test_grouped_flash_grad_matches_dense(grouped_qkv, bwd_mode, window,
     from dct_tpu.ops.pallas_attention import flash_attention
 
     monkeypatch.setenv("DCT_FLASH_BWD", bwd_mode)
-    q, k, v = grouped_qkv
+    q, k, v = _grouped(rng, t)
+    window = _band(t, window)
 
     def loss_flash(q, k, v):
         return flash_attention(
-            q, k, v, block_q=16, block_k=16, causal=True, interpret=True,
-            window=window,
+            q, k, v, block_q=block_q, block_k=block_k, causal=True,
+            interpret=True, window=window,
         ).sum()
 
     def loss_dense(q, k, v):
@@ -132,7 +164,7 @@ def test_grouped_flash_grad_matches_dense(grouped_qkv, bwd_mode, window,
 
     g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    assert g_flash[1].shape == (B, HKV, T, D)  # grads stay grouped
+    assert g_flash[1].shape == k.shape  # grads stay grouped
     for gf, gd in zip(g_flash, g_dense):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd), atol=1e-4)
 

@@ -11,34 +11,58 @@ from dct_tpu.ops.pallas_attention import flash_attention
 
 B, H, T, D = 2, 2, 128, 16
 
+#: (T, block_q, block_k). The first is the historical toy tile; the rest
+#: are tiles above 128, as the shape rule picks them on the chip: a square
+#: tile pair where the diagonal cuts tiles (0,0), (1,1) and leaves (1,0)
+#: interior (no mask built), rectangular pairs either way round, and the
+#: rule's own choice (None: one 512 tile, the whole triangle in one step).
+TILE_CASES = [
+    (128, 32, 32),
+    (512, 256, 256),
+    (1024, 512, 256),
+    (1024, 256, 512),
+    (512, None, None),
+]
+BIG_D = 8  # head size of the T >= 512 cases: interpret mode stays cheap
 
-@pytest.fixture()
-def qkv(rng):
+
+def _qkv(rng, t):
+    """The toy [B, H, T, D] at T=128; above it one batch row at head size
+    8, so the interpreter and the dense oracle stay cheap."""
+    shape = (B, H, T, D) if t == T else (1, H, t, BIG_D)
     return tuple(
-        jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
-        for _ in range(3)
+        jnp.asarray(rng.standard_normal(shape), jnp.float32) for _ in range(3)
     )
 
 
+@pytest.fixture()
+def qkv(rng):
+    return _qkv(rng, T)
+
+
+@pytest.mark.parametrize("t,block_q,block_k", TILE_CASES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_dense(qkv, causal):
-    q, k, v = qkv
+def test_flash_matches_dense(rng, causal, t, block_q, block_k):
+    q, k, v = _qkv(rng, t)
     ref = dense_attention(q, k, v, causal=causal)
     out = flash_attention(
-        q, k, v, block_q=32, block_k=32, causal=causal, interpret=True
+        q, k, v, block_q=block_q, block_k=block_k, causal=causal,
+        interpret=True,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.parametrize("t,block_q,block_k", TILE_CASES)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_grad_matches_dense(qkv, causal):
+def test_flash_grad_matches_dense(rng, causal, t, block_q, block_k):
     """The Pallas backward kernels (dQ / dK+dV) against AD through the
     dense oracle."""
-    q, k, v = qkv
+    q, k, v = _qkv(rng, t)
 
     def loss_flash(q, k, v):
         return flash_attention(
-            q, k, v, block_q=32, block_k=32, causal=causal, interpret=True
+            q, k, v, block_q=block_q, block_k=block_k, causal=causal,
+            interpret=True,
         ).sum()
 
     def loss_dense(q, k, v):
@@ -125,6 +149,40 @@ def test_flash_short_seq_default_blocks(rng):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd), atol=1e-4)
 
 
+@pytest.mark.parametrize(
+    "tq,tk,block_q,block_k",
+    [(256, 512, 128, 256), (512, 256, 256, 256), (256, 1024, None, None)],
+)
+def test_flash_rectangular_matches_dense(rng, tq, tk, block_q, block_k):
+    """Tq != Tk (the striped ring's blocks; non-causal only): forward on
+    the kernel at tiles above 128, backward through the blockwise remat
+    the rectangular case takes, both against dense."""
+    q = jnp.asarray(rng.standard_normal((1, 2, tq, BIG_D)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.standard_normal((1, 2, tk, BIG_D)), jnp.float32)
+        for _ in range(2)
+    )
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, block_q=block_q, block_k=block_k, interpret=True
+        )
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(dense_attention(q, k, v)),
+        atol=1e-5,
+    )
+    g_flash = jax.grad(
+        lambda q, k, v: (flash(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+    )(q, k, v)
+    g_dense = jax.grad(
+        lambda q, k, v: (dense_attention(q, k, v) ** 2).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for gf, gd in zip(g_flash, g_dense):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd), atol=1e-4)
+
+
 def test_flash_rejects_bad_blocks(qkv):
     q, k, v = qkv
     with pytest.raises(ValueError):
@@ -145,32 +203,54 @@ def test_flash_under_jit(qkv):
 # --- sliding window in the kernel ----------------------------------------
 
 
-@pytest.mark.parametrize("window", [1, 17, 32, 100, 128])
-def test_flash_window_matches_dense(qkv, window):
+#: (T, block_q, block_k, window) above 128: a band whose trailing edge
+#: cuts tile (1,0) and skips none; one that also puts whole tiles behind
+#: the band (768 x 256: tile (2,0) is skipped, (2,1) edge-cut, (1,0)
+#: interior); window == T, which cuts nothing and must mask nothing; and
+#: the rule's own tiles with the band inside the single tile.
+BIG_WINDOW_CASES = [
+    (512, 256, 256, 300),
+    (768, 256, 256, 257),
+    (512, 256, 256, 512),
+    (1024, 512, 256, 384),
+    (512, None, None, 200),
+]
+
+
+@pytest.mark.parametrize(
+    "t,block_q,block_k,window",
+    [(T, 32, 32, w) for w in (1, 17, 32, 100, 128)] + BIG_WINDOW_CASES,
+)
+def test_flash_window_matches_dense(rng, t, block_q, block_k, window):
     """The in-kernel band mask (incl. the tile-skip conditions: blocks
-    entirely behind the band execute nothing) against the masked dense
-    oracle, at windows inside one tile, spanning tiles, and >= T."""
-    q, k, v = qkv
+    entirely behind the band execute nothing, interior blocks build no
+    mask) against the masked dense oracle, at windows inside one tile,
+    spanning tiles, and >= T."""
+    q, k, v = _qkv(rng, t)
     ref = dense_attention(q, k, v, causal=True, window=window)
     out = flash_attention(
-        q, k, v, block_q=32, block_k=32, causal=True, interpret=True,
-        window=window,
+        q, k, v, block_q=block_q, block_k=block_k, causal=True,
+        interpret=True, window=window,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
 @pytest.mark.parametrize("bwd_mode", ["kernel", "remat"])
-@pytest.mark.parametrize("window", [17, 64])
-def test_flash_window_grad_matches_dense(qkv, window, bwd_mode, monkeypatch):
+@pytest.mark.parametrize(
+    "t,block_q,block_k,window",
+    [(T, 32, 32, 17), (T, 32, 32, 64)] + BIG_WINDOW_CASES,
+)
+def test_flash_window_grad_matches_dense(rng, t, block_q, block_k, window,
+                                         bwd_mode, monkeypatch):
     """Windowed backward: both the FA2 backward kernels (band mask +
     tile skip) and the blockwise remat escape against dense AD."""
     monkeypatch.setenv("DCT_FLASH_BWD", bwd_mode)
-    q, k, v = qkv
+    q, k, v = _qkv(rng, t)
 
     def loss_flash(q, k, v):
         return flash_attention(
-            q, k, v, block_q=32, block_k=32, causal=True, interpret=True,
-            window=window,
+            q, k, v, block_q=block_q, block_k=block_k, causal=True,
+            interpret=True, window=window,
         ).sum()
 
     def loss_dense(q, k, v):
@@ -191,22 +271,32 @@ def test_flash_window_requires_causal(qkv):
         )
 
 
-@pytest.mark.parametrize("offset_blocks", [1, 3])
-def test_flash_lse_q_offset_matches_blockwise(qkv, offset_blocks):
+@pytest.mark.parametrize(
+    "t,block,window,offset_blocks",
+    [
+        (T, 32, 100, 1), (T, 32, 100, 3),
+        # Tiles above 128 one shard away: the band's trailing edge cuts
+        # tiles (0,0) and (1,1), (0,1) is interior, (1,0) behind the band.
+        (512, 256, 512, 1),
+        # The rule's tiles, band inside the one tile.
+        (512, None, 700, 1),
+    ],
+)
+def test_flash_lse_q_offset_matches_blockwise(rng, t, block, window,
+                                              offset_blocks):
     """The static q_offset (the windowed ring's inter-shard distance)
     against the JAX-level blockwise twin with the same offset — forward
     o AND lse, since the ring's merge weights come from the lse."""
     from dct_tpu.ops.attention import blockwise_attention_lse
     from dct_tpu.ops.pallas_attention import flash_attention_lse
 
-    q, k, v = qkv
-    window = 100
-    q_offset = offset_blocks * T  # whole-shard distances like the ring's
+    q, k, v = _qkv(rng, t)
+    q_offset = offset_blocks * t  # whole-shard distances like the ring's
     o_k, lse_k = flash_attention_lse(
-        q, k, v, 32, 32, True, None, True, window, q_offset
+        q, k, v, block, block, True, None, True, window, q_offset
     )
     o_b, lse_b = blockwise_attention_lse(
-        q, k, v, block_size=32, causal=True, window=window,
+        q, k, v, block_size=block or 128, causal=True, window=window,
         q_offset=q_offset,
     )
     # Rows fully out of band produce o=0 and lse ~ -inf in both paths;
