@@ -92,6 +92,11 @@ FULL = Size(
         # at 4,096 and at 512 positions), on the tiles the cells run.
         ("sc2_3b_seq4096", 2, 24, 2, 4096, 128, 4096),
         ("sc2_3b_seq512", 16, 24, 2, 512, 128, 4096),
+        # lfm2_24b_ep8.fit_seq8192: head size 64, 8,192 positions, full
+        # causal, 4 query heads a key-value head. The kernels run the
+        # cell's whole grid; the comparator takes it 8 query heads at a
+        # time (_comparator_kv_heads).
+        ("lfm2_seq8192", 1, 32, 8, 8192, 64, None),
     ),
     ring_t=2048,
     ring_d=128,
@@ -536,6 +541,18 @@ def _errors(got, want) -> dict:
 KERNEL_TOL = 8 * BF16_EPS
 
 
+def _comparator_kv_heads(b: int, h: int, h_kv: int, t: int) -> int:
+    """Key-value heads the blockwise comparator takes at once: its backward
+    holds several [B, heads, T, T] f32 buffers (23.7 GB at 32 heads of
+    8,192 positions), so the most whose query heads keep B x heads x T x T
+    within 3 x 2^28 elements (5.9 GB measured at 8 heads of 8,192)."""
+    group = h // h_kv
+    return max(
+        c for c in range(1, h_kv + 1)
+        if h_kv % c == 0 and (c == 1 or b * c * group * t * t <= 3 << 28)
+    )
+
+
 def phase_kernels(cases) -> None:
     """Each case: flash forward + both FA2 backward kernels, against
     ``blockwise_attention`` on the same device in the same dtype."""
@@ -572,7 +589,18 @@ def phase_kernels(cases) -> None:
         t0 = time.perf_counter()
         got = jax.block_until_ready(_fwd_bwd(flash)(q, k, v, g))
         t_flash = time.perf_counter() - t0
-        want = jax.block_until_ready(_fwd_bwd(block)(q, k, v, g))
+        # Query head j reads key-value head j // (h / h_kv), so a run of
+        # key-value heads with its query heads is an attention of its own.
+        c = _comparator_kv_heads(b, h, h_kv, t)
+        cq = c * (h // h_kv)
+        block_fwd_bwd = _fwd_bwd(block)
+        want = tuple(
+            jnp.concatenate(part, axis=1) for part in zip(*(
+                jax.block_until_ready(block_fwd_bwd(
+                    q[:, i * cq:(i + 1) * cq], k[:, i * c:(i + 1) * c],
+                    v[:, i * c:(i + 1) * c], g[:, i * cq:(i + 1) * cq]))
+                for i in range(h_kv // c)))
+        )
         errs = _errors(got, want)
         check(
             all(np.isfinite(np.asarray(a, np.float32)).all() for a in got),
@@ -600,7 +628,7 @@ def phase_kernels(cases) -> None:
             f"kernel {name}",
             shape=(b, h, h_kv, t, d), window=window,
             tiles=flash_tiles(t, t, d, jnp.bfloat16),
-            interpret=bool(interpret),
+            interpret=bool(interpret), comparator_heads=cq,
             rel_err={n: f"{e:.2g}" for n, e in errs.items()},
             tol=f"{KERNEL_TOL:.3g}",
             **({} if band is None else {"band_vs_full_causal": f"{band:.2g}"}),

@@ -204,6 +204,10 @@ class ExecutableStore:
         # at compile (or load) time. Populated regardless of `enabled`
         # — a disabled store is still every CachedProgram's cost book.
         self.costs: dict[str, dict] = {}
+        # Per program key, the executable its first dispatch resolved
+        # (loaded or freshly compiled): what ran, for whoever wants its
+        # HLO text beside a device trace (``.as_text()``).
+        self.executables: dict[str, object] = {}
         self._lock = threading.Lock()
 
     # -- bookkeeping ---------------------------------------------------
@@ -528,6 +532,7 @@ class CachedProgram:
                         )
                 with self._lock:
                     self._entries[(program, sig)] = loaded
+                    store.executables[program] = loaded
                 return out
         store._note(program, "miss")
         hits_before = _persistent_cache_hits()
@@ -551,6 +556,7 @@ class CachedProgram:
             store.save(program, sig, compiled, cost=cost)
         with self._lock:
             self._entries[(program, sig)] = compiled
+            store.executables[program] = compiled
         return compiled(*args)
 
 

@@ -124,6 +124,32 @@ class ModelConfig:
     # engines (global positions, rotation happens before the seq-sharded
     # op) and with GQA.
     pos_embed: str = "sincos"
+    rope_theta: float = 10000.0
+    # The transformer block's form, each default the block the families
+    # started with: normalisation ("layernorm" | "rmsnorm") and its
+    # epsilon, the dense MLP ("gelu" | "swiglu": a gated MLP with three
+    # matrices), biases on the projections, an RMS norm of q and k over
+    # each head before the rotation.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"
+    use_bias: bool = True
+    qk_norm: bool = False
+    # Hybrid family (weather_hybrid_moe_causal): the operator of each
+    # layer, comma-separated, one entry a layer ("full_attention" |
+    # "conv", the gated short convolution of ``conv_kernel`` taps); the
+    # first ``num_dense_layers`` layers carry the dense MLP of width
+    # ``d_ff``, the others ``n_experts`` routed experts of width
+    # ``moe_d_ff`` chosen ``router_top_k`` at a time by sigmoid scores
+    # plus a selection bias. ``experts_held`` (0 = all) and
+    # ``first_expert`` name the share of the experts this job holds of
+    # an expert-parallel layer: the router stays ``n_experts`` wide.
+    layer_types: str = ""
+    num_dense_layers: int = 0
+    conv_kernel: int = 3
+    moe_d_ff: int = 0
+    experts_held: int = 0
+    first_expert: int = 0
 
     @classmethod
     def from_env(cls) -> "ModelConfig":
@@ -157,6 +183,20 @@ class ModelConfig:
         c.pos_embed = _env(
             "DCT_POS_EMBED", c.pos_embed, str
         ).strip().lower()
+        c.rope_theta = _env("DCT_ROPE_THETA", c.rope_theta, float)
+        c.norm = _env("DCT_NORM", c.norm, str).strip().lower()
+        c.norm_eps = _env("DCT_NORM_EPS", c.norm_eps, float)
+        c.mlp = _env("DCT_MLP", c.mlp, str).strip().lower()
+        c.use_bias = _env("DCT_USE_BIAS", c.use_bias, bool)
+        c.qk_norm = _env("DCT_QK_NORM", c.qk_norm, bool)
+        c.layer_types = _env("DCT_LAYER_TYPES", c.layer_types, str)
+        c.num_dense_layers = _env(
+            "DCT_NUM_DENSE_LAYERS", c.num_dense_layers, int
+        )
+        c.conv_kernel = _env("DCT_CONV_KERNEL", c.conv_kernel, int)
+        c.moe_d_ff = _env("DCT_MOE_D_FF", c.moe_d_ff, int)
+        c.experts_held = _env("DCT_EXPERTS_HELD", c.experts_held, int)
+        c.first_expert = _env("DCT_FIRST_EXPERT", c.first_expert, int)
         return c
 
 
@@ -1218,6 +1258,18 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_ATTN_WINDOW": "sliding-window local attention (0 = full causal)",
     "DCT_N_KV_HEADS": "grouped-query attention KV heads (0 = MHA)",
     "DCT_POS_EMBED": "position encoding: sincos | rope",
+    "DCT_ROPE_THETA": "rotary frequency base (default 10000)",
+    "DCT_NORM": "block normalisation: layernorm | rmsnorm",
+    "DCT_NORM_EPS": "normalisation epsilon (default 1e-6)",
+    "DCT_MLP": "dense MLP: gelu | swiglu (gated, three matrices)",
+    "DCT_USE_BIAS": "biases on the block's projections (default 1)",
+    "DCT_QK_NORM": "RMS norm of q and k per head before the rotation",
+    "DCT_LAYER_TYPES": "hybrid family: operator per layer, comma list of full_attention | conv",
+    "DCT_NUM_DENSE_LAYERS": "hybrid family: leading layers with the dense MLP",
+    "DCT_CONV_KERNEL": "gated short convolution taps (default 3)",
+    "DCT_MOE_D_FF": "hybrid family: routed experts' width (0 = d_ff)",
+    "DCT_EXPERTS_HELD": "experts this job holds of each layer (0 = all)",
+    "DCT_FIRST_EXPERT": "first expert of the held share",
     # --- optimization loop -----------------------------------------
     "DCT_EPOCHS": "epoch budget per cycle (reference 10)",
     "DCT_BATCH_SIZE": "per-device batch size (reference 4 per rank)",

@@ -39,6 +39,7 @@ class TorchStyleDense(nn.Module):
 
     features: int
     dtype: jnp.dtype | None = None
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -46,16 +47,17 @@ class TorchStyleDense(nn.Module):
         kernel = self.param(
             "kernel", torch_linear_init(), (fan_in, self.features), jnp.float32
         )
+        dtype = self.dtype or x.dtype
+        y = jnp.asarray(x, dtype) @ jnp.asarray(kernel, dtype)
+        if not self.use_bias:
+            return y
         bias = self.param(
             "bias",
             lambda k, s, d=jnp.float32: torch_linear_init()(k, s, d, fan_in=fan_in),
             (self.features,),
             jnp.float32,
         )
-        dtype = self.dtype or x.dtype
-        return jnp.asarray(x, dtype) @ jnp.asarray(kernel, dtype) + jnp.asarray(
-            bias, dtype
-        )
+        return y + jnp.asarray(bias, dtype)
 
 
 class WeatherMLP(nn.Module):

@@ -5,8 +5,10 @@ DDP, SURVEY §2.3); this family completes the mesh's parallelism matrix —
 experts shard over the ``model`` axis (expert parallelism), composing with
 batch DP and attention TP/SP in the same jitted step.
 
-TPU-first routing: no ragged tensors, no data-dependent shapes. Two
-dispatch engines share one router and one capacity policy:
+TPU-first routing: no data-dependent shapes. Two dispatch engines share
+one softmax router and one capacity policy that drops what does not fit;
+a third is the dropless layer of the hybrid family
+(``weather_hybrid_moe_causal``):
 
 - ``einsum`` — dense one-hot dispatch/combine einsums with a STATIC
   per-expert capacity:
@@ -31,7 +33,21 @@ dispatch engines share one router and one capacity policy:
   all-gathers the combined outputs — the canonical MoE a2a pipeline,
   visible as ``all-to-all`` in the compiled HLO (asserted by tests).
 
-Tokens over capacity are dropped (their dispatch row is zero); the block's
+- ``grouped`` — top-k of sigmoid scores plus a per-expert selection
+  bias, weights normalised over the chosen experts,
+  bias-free SwiGLU experts, and NO dropping: the routed rows are sorted by
+  expert and go through three grouped products (``jax.lax.ragged_dot``, a
+  Mosaic grouped matmul on the TPU) over a static row bound, the most rows
+  that can be routed to the held experts. The layer is
+  told which experts it holds (``experts_held``, ``first_expert``): the
+  router stays ``n_experts`` wide, the top-k is over all of them, and the
+  layer computes its own experts' part of the sum, one chip's share of an
+  expert-parallel layer without the exchange (:meth:`MoEFFN._grouped`).
+  It sows what it routed into the ``counters`` collection, which the
+  train step hands to the trainer's epoch metrics.
+
+In the first two, tokens over capacity are dropped (their dispatch row is
+zero); the block's
 residual connection passes them through unchanged — standard switch
 behavior. Expert weights are [E, D, F] tensors named ``experts_in`` /
 ``experts_out``; the sharding rules place them ``P("model", None, None)``.
@@ -111,6 +127,57 @@ def _sorted_moe(tokens, expert_idx, gate, w_in, b_in, w_out, b_out, *,
     return out * gate[:, None]
 
 
+def _grouped_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
+                 first_expert: int, row_bound: int):
+    """The held experts' part of a top-k layer, no row dropped up to
+    ``row_bound``: ``sum_{i in topk, i held} gate_i * E_i(x)``.
+
+    tokens [N, D] (compute dtype), topi / gates [N, k] (global expert ids,
+    f32 weights); the expert weights are the HELD stack [held, ...] of
+    bias-free SwiGLU experts. The N * k routed rows are sorted by held
+    expert, the rows of experts held elsewhere last, and the first
+    ``row_bound`` go through three grouped products
+    (``jax.lax.ragged_dot``: on the TPU a Mosaic grouped matmul that
+    visits only the tiles the group sizes cover). Past the routed rows a
+    grouped product's output is whatever the buffer held, NaN included,
+    and so is the cotangent its transpose hands back: the rows are zeroed
+    going in and after EVERY product, so nothing the tail holds reaches
+    the result, a gradient, or (as 0 x NaN) the next product's backward.
+    The group sizes are the routed rows: the kernel visits the tiles they
+    cover and skips the tail, so a step's time follows the router's load.
+    Returns (out [N, D] f32, rows [held] int32 routed to each held expert,
+    overflow int32 = routed rows past ``row_bound``, which are dropped: 0
+    or the bound is wrong)."""
+    n, d = tokens.shape
+    k = topi.shape[1]
+    held = w_in.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        local = topi.astype(jnp.int32) - first_expert
+        flat = jnp.where(
+            (local >= 0) & (local < held), local, held
+        ).reshape(n * k)
+        rows = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+        order = jnp.argsort(flat, stable=True)[:row_bound]
+        ends = jnp.minimum(jnp.cumsum(rows), row_bound)
+        overflow = rows.sum() - ends[-1]
+        valid = (jnp.arange(row_bound) < ends[-1])[:, None]
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        token_of = order // k
+        x = jnp.where(valid, tokens[token_of], 0)
+
+    def product(a, w):
+        return jnp.where(valid, lax.ragged_dot(a, w, sizes), 0)
+
+    with jax.named_scope("moe.experts"):
+        h = nn.silu(product(x, w_gate)) * product(x, w_in)
+        y = product(h, w_out)
+    with jax.named_scope("moe.combine"):
+        weight = gates.reshape(n * k)[order][:, None]
+        y = y.astype(jnp.float32) * weight
+        out = jnp.zeros((n, d), jnp.float32).at[token_of].add(y)
+    return out, rows, overflow
+
+
 class MoEFFN(nn.Module):
     """Switch (top-1) mixture of expert FFNs over flattened tokens.
 
@@ -138,6 +205,10 @@ class MoEFFN(nn.Module):
     mesh: object = None
     top_k: int = 1
     auto_threshold: int = 1 << 21
+    # The 'grouped' layer (:meth:`_grouped`): the share of the experts
+    # this layer holds (0 = all of them).
+    experts_held: int = 0
+    first_expert: int = 0
 
     @nn.compact
     def __call__(self, x):  # [B, S, D] -> [B, S, D]
@@ -147,10 +218,16 @@ class MoEFFN(nn.Module):
         k = self.top_k
         if not 1 <= k <= e:
             raise ValueError(f"top_k={k} must be in [1, n_experts={e}]")
-        if self.dispatch not in ("auto", "sorted", "einsum"):
+        if self.dispatch not in ("auto", "sorted", "einsum", "grouped"):
             raise ValueError(
                 f"moe_dispatch={self.dispatch!r} must be "
-                "'auto' | 'sorted' | 'einsum'"
+                "'auto' | 'sorted' | 'einsum' | 'grouped'"
+            )
+        if self.dispatch == "grouped":
+            return self._grouped(x)
+        if self.experts_held:
+            raise ValueError(
+                "experts_held belongs to moe_dispatch='grouped'"
             )
         capacity = max(1, int(self.capacity_factor * k * n / e))
         tokens = x.reshape(n, d)
@@ -302,6 +379,75 @@ class MoEFFN(nn.Module):
         out2 = out2 * flat_gate[:, None]
         out = out2.reshape(k, n, d).sum(axis=0) if k > 1 else out2
         return out.reshape(b, s, d)
+
+    def _grouped(self, x):
+        """The dropless layer of bias-free SwiGLU experts, as one chip's
+        share of an expert-parallel layer: the router is ``n_experts``
+        wide and the top ``top_k`` are chosen over all of them; this layer
+        holds experts ``first_expert .. first_expert + experts_held - 1``
+        and computes their part of the result
+        (:func:`_grouped_moe`). What the experts held elsewhere would add
+        is left out; no exchange, nothing stands in for the other chips.
+
+        Scores: a sigmoid an expert, with a per-expert bias added for
+        the SELECTION only (a parameter under ``stop_gradient``: the
+        optimizer sees a zero gradient and leaves it where it is; its
+        balancing update is not run). The weights are the chosen
+        experts' scores, without the bias, over their sum. The grouped
+        products' row bound is the ``N * min(top_k, held)`` rows that can
+        be routed here, so no row is ever past it (``capacity_factor``
+        belongs to the engines that drop). Sows ``counters`` (rows per
+        held expert, rows past the bound, the uniform expectation per
+        expert) and, for a caller that asks, ``intermediates`` (the chosen
+        experts)."""
+        b, s, d = x.shape
+        n, e, k = b * s, self.n_experts, self.top_k
+        held = self.experts_held or e
+        if not 0 <= self.first_expert <= e - held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + held - 1}"
+                f" are not among n_experts={e}"
+            )
+        tokens = x.reshape(n, d)
+        with jax.named_scope("moe.route"):
+            # f32 at full precision: a [N, D] x [D, E] product decides
+            # which experts run.
+            with jax.default_matmul_precision("highest"):
+                logits = TorchStyleDense(
+                    e, dtype=jnp.float32, use_bias=False, name="router"
+                )(jnp.asarray(tokens, jnp.float32))
+            scores = jax.nn.sigmoid(logits)
+            bias = self.param(
+                "expert_bias", nn.initializers.zeros, (e,), jnp.float32
+            )
+            _, topi = lax.top_k(scores + lax.stop_gradient(bias), k)
+            chosen = jnp.take_along_axis(scores, topi, axis=-1)
+            gates = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-6)
+        self.sow("intermediates", "topk", topi)
+
+        def stack(name, fan_in, shape):
+            w = self.param(
+                name,
+                lambda key, sh, dt=jnp.float32: torch_linear_init()(
+                    key, sh, dt, fan_in=fan_in
+                ),
+                (held, *shape),
+                jnp.float32,
+            )
+            return jnp.asarray(w, self.dtype)
+
+        w_gate = stack("experts_gate_kernel", d, (d, self.d_ff))
+        w_in = stack("experts_in_kernel", d, (d, self.d_ff))
+        w_out = stack("experts_out_kernel", self.d_ff, (self.d_ff, d))
+        out, rows, overflow = _grouped_moe(
+            jnp.asarray(tokens, self.dtype), topi, gates, w_gate, w_in,
+            w_out, first_expert=self.first_expert,
+            row_bound=n * min(k, held),
+        )
+        self.sow("counters", "moe_rows", rows)
+        self.sow("counters", "moe_rows_overflowed", overflow)
+        self.sow("counters", "moe_rows_uniform", jnp.float32(n * k / e))
+        return jnp.asarray(out, self.dtype).reshape(b, s, d)
 
     def _sorted_sharded(self, x, expert_choice, gate_choice, wi, bi, wo,
                         bo, *, mesh, dp: int, sp: int, ep: int):

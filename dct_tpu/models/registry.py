@@ -132,17 +132,19 @@ def _build_moe(
     )
 
 
-@register_model("weather_transformer_causal", sequence=True, causal=True)
-def _build_transformer_causal(
-    cfg: ModelConfig, *, input_dim: int, compute_dtype=None, attn_fn=None,
-    mesh=None,
-):
-    """Decoder-style causal forecaster: per-position next-step supervision
-    through CAUSAL attention — the product path for the causal flash
-    kernel and the causal ring (the non-causal families never exercise
-    them). The Trainer-supplied attn_fn is non-causal, so this builder
-    constructs its own from the mesh."""
-    del attn_fn
+def _block_form(cfg: ModelConfig) -> dict:
+    """The block's form as WeatherTransformer's keyword arguments."""
+    return dict(
+        norm=cfg.norm, norm_eps=cfg.norm_eps, mlp=cfg.mlp,
+        use_bias=cfg.use_bias, qk_norm=cfg.qk_norm,
+        rope_theta=cfg.rope_theta,
+    )
+
+
+def _causal_transformer(cfg, *, input_dim, compute_dtype, mesh, **extra):
+    """WeatherTransformer as the causal per-position families build it:
+    their own causal attention from the mesh (the Trainer-supplied attn_fn
+    is non-causal), the per-position head, the block's form from ``cfg``."""
     import jax.numpy as jnp
 
     from dct_tpu.models.transformer import WeatherTransformer
@@ -167,6 +169,51 @@ def _build_transformer_causal(
         compute_dtype=compute_dtype or jnp.float32,
         n_kv_heads=cfg.n_kv_heads if cfg.n_kv_heads > 0 else None,
         pos_embed=cfg.pos_embed,
+        **_block_form(cfg),
+        **extra,
+    )
+
+
+@register_model("weather_transformer_causal", sequence=True, causal=True)
+def _build_transformer_causal(
+    cfg: ModelConfig, *, input_dim: int, compute_dtype=None, attn_fn=None,
+    mesh=None,
+):
+    """Decoder-style causal forecaster: per-position next-step supervision
+    through CAUSAL attention — the product path for the causal flash
+    kernel and the causal ring (the non-causal families never exercise
+    them)."""
+    del attn_fn
+    return _causal_transformer(
+        cfg, input_dim=input_dim, compute_dtype=compute_dtype, mesh=mesh
+    )
+
+
+@register_model("weather_hybrid_moe_causal", sequence=True, causal=True)
+def _build_hybrid_moe_causal(
+    cfg: ModelConfig, *, input_dim: int, compute_dtype=None, attn_fn=None,
+    mesh=None,
+):
+    """The causal per-position family with a per-layer operator
+    (``layer_types``: attention or the gated short convolution) and routed
+    experts after ``num_dense_layers`` dense layers: the block of
+    ``weather_transformer_causal`` by its fields, one chip's share of the
+    experts (``experts_held``, ``first_expert``) computed without an
+    exchange."""
+    del attn_fn
+    moe = dict(
+        d_ff=cfg.moe_d_ff or cfg.d_ff, n_experts=cfg.n_experts,
+        aux_weight=0.0, dispatch="grouped", top_k=cfg.router_top_k,
+        experts_held=cfg.experts_held, first_expert=cfg.first_expert,
+    )
+    return _causal_transformer(
+        cfg, input_dim=input_dim, compute_dtype=compute_dtype, mesh=mesh,
+        layer_types=tuple(
+            t.strip() for t in cfg.layer_types.split(",") if t.strip()
+        ),
+        conv_kernel=cfg.conv_kernel,
+        moe=tuple(sorted(moe.items())),
+        num_dense_layers=cfg.num_dense_layers,
     )
 
 

@@ -22,13 +22,15 @@ window of ``seq_len`` past weather rows, mean-pooled into the same
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from dct_tpu.models.mlp import TorchStyleDense
+from dct_tpu.models.mlp import TorchStyleDense, torch_linear_init
 
 
 def sincos_positions(seq_len: int, d_model: int) -> np.ndarray:
@@ -42,14 +44,28 @@ def sincos_positions(seq_len: int, d_model: int) -> np.ndarray:
     return out
 
 
-def rope_tables(seq_len: int, head_dim: int) -> tuple[np.ndarray, np.ndarray]:
+def rope_tables(
+    seq_len: int, head_dim: int, base: float = 10000.0
+) -> tuple[np.ndarray, np.ndarray]:
     """Rotary-embedding cos/sin tables [S, Dh/2] (RoFormer/Llama-style,
-    rotate-half pairing). Static numpy — nothing to shard, and the tables
-    bake into the compiled program as constants."""
+    rotate-half pairing) at frequency base ``base``. Static numpy — nothing
+    to shard, and the tables bake into the compiled program as constants."""
     half = head_dim // 2
-    inv = 1.0 / np.power(10000.0, np.arange(half, dtype=np.float32) / half)
+    inv = 1.0 / np.power(
+        np.float32(base), np.arange(half, dtype=np.float32) / half
+    )
     ang = np.arange(seq_len, dtype=np.float32)[:, None] * inv[None, :]
     return np.cos(ang), np.sin(ang)
+
+
+def make_norm(kind: str, eps: float, dtype, name: str) -> nn.Module:
+    """The block's normalisation by name: ``layernorm`` (scale and bias)
+    or ``rmsnorm`` (x / sqrt(mean(x^2) + eps) * scale, no bias)."""
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError(f"norm={kind!r} must be 'layernorm' or 'rmsnorm'")
 
 
 def apply_rope(x, cos, sin):
@@ -89,6 +105,12 @@ class MultiHeadAttention(nn.Module):
     dtype: jnp.dtype = jnp.float32
     n_kv_heads: int | None = None
     rope: bool = False
+    rope_theta: float = 10000.0
+    use_bias: bool = True
+    # RMS norm of q and k over each head's dims (own weight each),
+    # before the rotation.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -102,7 +124,7 @@ class MultiHeadAttention(nn.Module):
         hg = self.n_heads // g
         qkv = TorchStyleDense(
             (self.n_heads + 2 * g) * head_dim, dtype=self.dtype,
-            name="qkv_proj",
+            use_bias=self.use_bias, name="qkv_proj",
         )(x)
         qkv = qkv.reshape(b, t, g, hg + 2, head_dim)
         # [B, T, G, Hg+2, Dh]: per group, Hg q heads then one k and one v.
@@ -110,20 +132,74 @@ class MultiHeadAttention(nn.Module):
         q = jnp.swapaxes(q, 1, 2)  # [B, H, T, Dh]
         k = jnp.swapaxes(qkv[:, :, :, hg], 1, 2)  # [B, G, T, Dh]
         v = jnp.swapaxes(qkv[:, :, :, hg + 1], 1, 2)
+        if self.qk_norm:
+            q = make_norm("rmsnorm", self.norm_eps, self.dtype, "q_norm")(q)
+            k = make_norm("rmsnorm", self.norm_eps, self.dtype, "k_norm")(k)
         if self.rope:
             if head_dim % 2:
                 raise ValueError(
                     f"rope needs an even head_dim (got {head_dim})"
                 )
-            cos, sin = rope_tables(t, head_dim)
+            cos, sin = rope_tables(t, head_dim, self.rope_theta)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
         o = self.attn_fn(q, k, v)  # [B, H, T, D]
         o = jnp.moveaxis(o, 1, 2).reshape(b, t, self.d_model)
-        return TorchStyleDense(self.d_model, dtype=self.dtype, name="o_proj")(o)
+        return TorchStyleDense(
+            self.d_model, dtype=self.dtype, use_bias=self.use_bias,
+            name="o_proj",
+        )(o)
+
+
+class GatedShortConv(nn.Module):
+    """The gated short convolution operator
+    (:func:`dct_tpu.ops.shortconv.gated_short_conv`) between its two
+    projections: ``in_proj`` d_model -> 3 x d_model (column-parallel by
+    name would split B, C and X apart, so no rule matches it), a depthwise
+    kernel of ``kernel_size`` taps a channel, ``out_proj`` d_model ->
+    d_model."""
+
+    d_model: int
+    kernel_size: int = 3
+    dtype: jnp.dtype = jnp.float32
+    use_bias: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        from dct_tpu.ops.shortconv import gated_short_conv
+
+        with jax.named_scope("shortconv"):
+            bcx = TorchStyleDense(
+                3 * self.d_model, dtype=self.dtype, use_bias=self.use_bias,
+                name="in_proj",
+            )(x)
+            taps = self.param(
+                "conv_kernel",
+                lambda k, sh, dt=jnp.float32: torch_linear_init()(
+                    k, sh, dt, fan_in=self.kernel_size
+                ),
+                (self.d_model, self.kernel_size),
+                jnp.float32,
+            )
+            y = gated_short_conv(bcx, jnp.asarray(taps, self.dtype))
+            return TorchStyleDense(
+                self.d_model, dtype=self.dtype, use_bias=self.use_bias,
+                name="out_proj",
+            )(y)
 
 
 class TransformerBlock(nn.Module):
+    """One pre-norm block: ``x + Op(norm(x))`` then ``x + FFN(norm(x))``.
+
+    The defaults are the block the family started with (LayerNorm, biased
+    projections, softmax attention, gelu MLP). The fields select the
+    others: ``norm`` / ``norm_eps``, ``use_bias``, ``qk_norm`` and
+    ``rope_theta`` for the attention operator, ``op="conv"`` for the gated
+    short convolution in attention's place, ``mlp="swiglu"`` for the gated
+    MLP, and ``moe`` (the keyword arguments of
+    :class:`dct_tpu.models.moe.MoEFFN` as a tuple of pairs) for routed
+    experts in the dense MLP's place."""
+
     d_model: int
     n_heads: int
     d_ff: int
@@ -132,22 +208,61 @@ class TransformerBlock(nn.Module):
     dtype: jnp.dtype = jnp.float32
     n_kv_heads: int | None = None
     rope: bool = False
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"
+    use_bias: bool = True
+    qk_norm: bool = False
+    rope_theta: float = 10000.0
+    op: str = "full_attention"
+    conv_kernel: int = 3
+    moe: tuple | None = None
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         # ``train`` is positional-or-keyword (not kw-only) so nn.remat's
         # static_argnums can reach it (WeatherTransformer's remat path).
-        h = nn.LayerNorm(dtype=self.dtype, name="ln_attn")(x)
-        h = MultiHeadAttention(
-            self.d_model, self.n_heads, self.attn_fn, dtype=self.dtype,
-            n_kv_heads=self.n_kv_heads, rope=self.rope, name="attn",
-        )(h)
+        h = make_norm(self.norm, self.norm_eps, self.dtype, "ln_attn")(x)
+        if self.op == "conv":
+            h = GatedShortConv(
+                self.d_model, self.conv_kernel, dtype=self.dtype,
+                use_bias=self.use_bias, name="conv",
+            )(h)
+        elif self.op == "full_attention":
+            h = MultiHeadAttention(
+                self.d_model, self.n_heads, self.attn_fn, dtype=self.dtype,
+                n_kv_heads=self.n_kv_heads, rope=self.rope,
+                rope_theta=self.rope_theta, use_bias=self.use_bias,
+                qk_norm=self.qk_norm, norm_eps=self.norm_eps, name="attn",
+            )(h)
+        else:
+            raise ValueError(
+                f"layer type {self.op!r} must be 'full_attention' or 'conv'"
+            )
         h = nn.Dropout(rate=self.dropout, deterministic=not train)(h)
         x = x + h
-        h = nn.LayerNorm(dtype=self.dtype, name="ln_ffn")(x)
-        h = TorchStyleDense(self.d_ff, dtype=self.dtype, name="ffn_in")(h)
-        h = nn.gelu(h)
-        h = TorchStyleDense(self.d_model, dtype=self.dtype, name="ffn_out")(h)
+        h = make_norm(self.norm, self.norm_eps, self.dtype, "ln_ffn")(x)
+        dense = functools.partial(
+            TorchStyleDense, dtype=self.dtype, use_bias=self.use_bias
+        )
+        if self.moe is not None:
+            from dct_tpu.models.moe import MoEFFN
+
+            h = MoEFFN(
+                d_model=self.d_model, dtype=self.dtype, name="moe",
+                **dict(self.moe),
+            )(h)
+        elif self.mlp == "swiglu":
+            with jax.named_scope("dense_mlp"):
+                h = nn.silu(dense(self.d_ff, name="ffn_gate")(h)) * dense(
+                    self.d_ff, name="ffn_in"
+                )(h)
+                h = dense(self.d_model, name="ffn_out")(h)
+        elif self.mlp == "gelu":
+            h = nn.gelu(dense(self.d_ff, name="ffn_in")(h))
+            h = dense(self.d_model, name="ffn_out")(h)
+        else:
+            raise ValueError(f"mlp={self.mlp!r} must be 'gelu' or 'swiglu'")
         h = nn.Dropout(rate=self.dropout, deterministic=not train)(h)
         return x + h
 
@@ -320,6 +435,22 @@ class WeatherTransformer(nn.Module):
     compute_dtype: jnp.dtype = jnp.float32
     n_kv_heads: int | None = None
     pos_embed: str = "sincos"
+    # The block's form (TransformerBlock's fields of the same names).
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    mlp: str = "gelu"
+    use_bias: bool = True
+    qk_norm: bool = False
+    rope_theta: float = 10000.0
+    # Per-layer operator, one entry a layer ("full_attention" | "conv");
+    # empty = attention everywhere.
+    layer_types: tuple = ()
+    conv_kernel: int = 3
+    # Routed experts (MoEFFN's keyword arguments as a tuple of pairs) in
+    # every layer from ``num_dense_layers`` on; the leading layers keep
+    # the dense MLP of width ``d_ff``.
+    moe: tuple | None = None
+    num_dense_layers: int = 0
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
@@ -330,9 +461,17 @@ class WeatherTransformer(nn.Module):
                 f"d_model={self.d_model} must be even (sinusoidal positions)"
                 f" and divisible by n_heads={self.n_heads}"
             )
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers={self.n_layers}"
+            )
         attn_fn = self.attn_fn or make_attention_fn(None)
+        dense = functools.partial(
+            TorchStyleDense, dtype=self.compute_dtype, use_bias=self.use_bias
+        )
         x = jnp.asarray(x, self.compute_dtype)
-        h = TorchStyleDense(self.d_model, dtype=self.compute_dtype, name="in_proj")(x)
+        h = dense(self.d_model, name="in_proj")(x)
         if self.pos_embed != "rope":  # rope rotates q/k inside attention
             h = h + jnp.asarray(
                 sincos_positions(self.seq_len, self.d_model),
@@ -359,21 +498,24 @@ class WeatherTransformer(nn.Module):
                 dtype=self.compute_dtype,
                 n_kv_heads=self.n_kv_heads,
                 rope=self.pos_embed == "rope",
+                norm=self.norm,
+                norm_eps=self.norm_eps,
+                mlp=self.mlp,
+                use_bias=self.use_bias,
+                qk_norm=self.qk_norm,
+                rope_theta=self.rope_theta,
+                op=self.layer_types[i] if self.layer_types else "full_attention",
+                conv_kernel=self.conv_kernel,
+                moe=self.moe if i >= self.num_dense_layers else None,
                 name=f"block_{i}",
             )(h, train)
-        h = nn.LayerNorm(dtype=self.compute_dtype, name="ln_out")(h)
+        h = make_norm(self.norm, self.norm_eps, self.compute_dtype, "ln_out")(h)
         if self.per_position and self.horizon > 1:
-            logits = TorchStyleDense(
-                self.num_classes * self.horizon, dtype=self.compute_dtype,
-                name="head",
+            logits = dense(
+                self.num_classes * self.horizon, name="head"
             )(h).reshape(*h.shape[:-1], self.horizon, self.num_classes)
         elif self.per_position:
-            logits = TorchStyleDense(
-                self.num_classes, dtype=self.compute_dtype, name="head"
-            )(h)  # [B, S, classes]
+            logits = dense(self.num_classes, name="head")(h)  # [B, S, classes]
         else:
-            pooled = h.mean(axis=1)
-            logits = TorchStyleDense(
-                self.num_classes, dtype=self.compute_dtype, name="head"
-            )(pooled)
+            logits = dense(self.num_classes, name="head")(h.mean(axis=1))
         return jnp.asarray(logits, jnp.float32)

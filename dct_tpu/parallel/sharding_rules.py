@@ -75,6 +75,12 @@ FAMILY_RULES: dict = {
     "weather_transformer_causal": _TENSOR_PARALLEL_RULES,
     "weather_transformer_pp": _TENSOR_PARALLEL_RULES,
     "weather_moe": _TENSOR_PARALLEL_RULES,
+    # The gated MLP's second column-parallel matrix and the gated
+    # experts' third stack.
+    "weather_hybrid_moe_causal": (
+        (r"(^|/)experts_gate_kernel$", P("model", None, None)),
+        (r"ffn_gate.*/kernel$", P(None, "model")),
+    ) + _TENSOR_PARALLEL_RULES,
 }
 
 

@@ -57,8 +57,50 @@ def _position_weight(logits, y, weight):
     return weight
 
 
+def _objective(updates, loss, aux_share: int = 1):
+    """The CE plus every sown ``aux_loss`` leaf (over ``aux_share``, the
+    microbatches an accumulated step averages them over), and the step's
+    sown ``counters`` (what the model counts while it runs: the MoE
+    layers' routed rows; empty for a model that counts nothing)."""
+    for leaf in jax.tree.leaves(updates.get("aux_loss", {})):
+        loss = loss + (leaf / aux_share if aux_share > 1 else leaf)
+    return loss, updates.get("counters", {})
+
+
+def counter_metrics(counters) -> dict:
+    """An epoch's counters (host arrays, summed over its steps) as flat
+    metrics, by the name each was sown under, summed over the modules that
+    sowed it: a scalar as ``name``; a vector as ``name`` (its total) and
+    ``name_<j>``; and where a vector ``name`` comes with a scalar
+    ``name_uniform`` (what each entry would hold under an even spread), the
+    worst entry of any module over that mean as ``name_max_over_mean``."""
+    import numpy as np
+
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(counters):
+        name = [k.key for k in path if hasattr(k, "key")][-1]
+        by_name.setdefault(name, []).append(np.asarray(leaf, np.float64))
+    out: dict = {}
+    for name, leaves in by_name.items():
+        total = np.sum(leaves, axis=0)
+        out[name] = float(total.sum())
+        if total.ndim == 1:
+            out.update({f"{name}_{j}": float(v) for j, v in enumerate(total)})
+            uniform = by_name.get(name + "_uniform")
+            if uniform is not None:
+                out[name + "_max_over_mean"] = float(max(
+                    v.max() / u for v, u in zip(leaves, uniform)))
+    return out
+
+
 def _train_body(state: TrainState, x, y, weight):
-    """One optimization step: (state, batch) -> (new_state, loss).
+    """One optimization step: (state, batch) -> (new_state, loss, grad
+    norm)."""
+    return _train_body_counted(state, x, y, weight)[:3]
+
+
+def _train_body_counted(state: TrainState, x, y, weight):
+    """:func:`_train_body` plus the step's sown counters.
 
     Computes the global weighted-mean CE (the reference's ``train_loss``,
     jobs/train_lightning_ddp.py:70), its grads, and the Adam update.
@@ -72,20 +114,22 @@ def _train_body(state: TrainState, x, y, weight):
     def loss_fn(params):
         logits, updates = state.apply_fn(
             cast_params_by_rules(params), x, train=True,
-            rngs={"dropout": step_rng}, mutable=["aux_loss"],
+            rngs={"dropout": step_rng}, mutable=["aux_loss", "counters"],
         )
         w = _position_weight(logits, y, weight)
         loss_sum, count = masked_cross_entropy(logits, y, w)
-        loss = loss_sum / jnp.maximum(count, 1.0)
-        for leaf in jax.tree.leaves(updates):
-            loss = loss + leaf
-        return loss
+        return _objective(updates, loss_sum / jnp.maximum(count, 1.0))
 
-    loss, grads = jax.value_and_grad(loss_fn)(state.params)
+    (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        state.params
+    )
     # Gradient global norm: the health monitor's drift signal. One fused
     # reduction over leaves XLA already has resident — and dead-code
     # eliminated entirely by factories that do not emit it.
-    return state.apply_gradients(grads), loss, optax.global_norm(grads)
+    return (
+        state.apply_gradients(grads), loss, optax.global_norm(grads),
+        counters,
+    )
 
 
 def _eval_body(state: TrainState, x, y, weight):
@@ -128,32 +172,34 @@ def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
     def chunk_loss(params, cx, cy, cw, rng):
         logits, updates = state.apply_fn(
             cast_params_by_rules(params), cx, train=True,
-            rngs={"dropout": rng}, mutable=["aux_loss"],
+            rngs={"dropout": rng}, mutable=["aux_loss", "counters"],
         )
         loss_sum, _ = masked_cross_entropy(
             logits, cy, _position_weight(logits, cy, cw)
         )
-        loss = loss_sum / total
-        for leaf in jax.tree.leaves(updates):
-            loss = loss + leaf / accum_steps
-        return loss
+        return _objective(updates, loss_sum / total, accum_steps)
 
-    grad_fn = jax.value_and_grad(chunk_loss)
+    grad_fn = jax.value_and_grad(chunk_loss, has_aux=True)
 
     def body(carry, chunk):
         gacc, lacc, i = carry
         cx, cy, cw = chunk
-        loss_i, g = grad_fn(
+        (loss_i, counters), g = grad_fn(
             state.params, cx, cy, cw, jax.random.fold_in(step_rng, i)
         )
-        return (jax.tree.map(jnp.add, gacc, g), lacc + loss_i, i + 1), None
+        return (
+            (jax.tree.map(jnp.add, gacc, g), lacc + loss_i, i + 1), counters
+        )
 
     zeros = jax.tree.map(jnp.zeros_like, state.params)
-    (grads, loss, _), _ = jax.lax.scan(
+    (grads, loss, _), counters = jax.lax.scan(
         body, (zeros, jnp.zeros(()), jnp.zeros((), jnp.int32)), (xs, ys, ws)
     )
     # Norm of the ACCUMULATED gradient — the update the optimizer sees.
-    return state.apply_gradients(grads), loss, optax.global_norm(grads)
+    return (
+        state.apply_gradients(grads), loss, optax.global_norm(grads),
+        jax.tree.map(lambda c: c.sum(axis=0), counters),
+    )
 
 
 def make_train_step(donate: bool = True, accum_steps: int = 1,
@@ -167,7 +213,7 @@ def make_train_step(donate: bool = True, accum_steps: int = 1,
 
     def train_step(state: TrainState, x, y, weight):
         if accum_steps > 1:
-            new_state, loss, gnorm = _train_accum_body(
+            new_state, loss, gnorm, _ = _train_accum_body(
                 state, x, y, weight, accum_steps
             )
         else:
@@ -182,9 +228,10 @@ def make_train_step(donate: bool = True, accum_steps: int = 1,
 
 def _epoch_train_scan(state: TrainState, xs, ys, ws, accum_steps: int):
     """Shared whole-epoch train scan body (see make_epoch_train_step):
-    -> (state, losses[S'], grad_norms[S']) with S' = optimizer updates.
-    The stacked grad norms are free for callers that drop them (XLA
-    DCEs unused scan outputs at lowering)."""
+    -> (state, losses[S'], grad_norms[S'], counters) with S' = optimizer
+    updates and the counters summed over them. The stacked grad norms and
+    the counters are free for callers that drop them (XLA DCEs unused
+    scan outputs at lowering)."""
     if accum_steps > 1:
         s, b = xs.shape[0], xs.shape[1]
         xs = xs.reshape(s // accum_steps, accum_steps * b, *xs.shape[2:])
@@ -194,15 +241,20 @@ def _epoch_train_scan(state: TrainState, xs, ys, ws, accum_steps: int):
         ws = ws.reshape(s // accum_steps, accum_steps * b)
 
         def body(st, batch):
-            st, loss, gnorm = _train_accum_body(st, *batch, accum_steps)
-            return st, (loss, gnorm)
+            st, *out = _train_accum_body(st, *batch, accum_steps)
+            return st, tuple(out)
     else:
         def body(st, batch):
-            st, loss, gnorm = _train_body(st, *batch)
-            return st, (loss, gnorm)
+            st, *out = _train_body_counted(st, *batch)
+            return st, tuple(out)
 
-    state, (losses, gnorms) = jax.lax.scan(body, state, (xs, ys, ws))
-    return state, losses, gnorms
+    state, (losses, gnorms, counters) = jax.lax.scan(
+        body, state, (xs, ys, ws)
+    )
+    return (
+        state, losses, gnorms,
+        jax.tree.map(lambda c: c.sum(axis=0), counters),
+    )
 
 
 def _epoch_eval_scan(state: TrainState, xs, ys, ws):
@@ -242,7 +294,7 @@ def make_epoch_train_step(donate: bool = True, accum_steps: int = 1,
     """
 
     def epoch_train(state: TrainState, xs, ys, ws):
-        state, losses, gnorms = _epoch_train_scan(
+        state, losses, gnorms, _ = _epoch_train_scan(
             state, xs, ys, ws, accum_steps
         )
         if with_grad_norms:
@@ -274,19 +326,21 @@ def make_epoch_train_eval_step(donate: bool = True, accum_steps: int = 1,
     make_epoch_eval_step (eval runs on the post-epoch state).
 
     Returns (state, losses[S], the 6 eval sums (val_loss_sum,
-    val_acc_sum, val_count, tp, fp, fn)); ``with_grad_norms=True``
-    appends the per-update grad global norms [S]. The validation stacks
-    are NOT donated — they are reused every epoch.
+    val_acc_sum, val_count, tp, fp, fn), then with ``with_grad_norms=True``
+    the per-update grad global norms [S], and last the model's sown
+    counters summed over the epoch's updates (an empty tree for a model
+    that counts nothing)). The validation stacks are NOT donated — they
+    are reused every epoch.
     """
 
     def epoch_fused(state: TrainState, xs, ys, ws, vxs, vys, vws):
-        state, losses, gnorms = _epoch_train_scan(
+        state, losses, gnorms, counters = _epoch_train_scan(
             state, xs, ys, ws, accum_steps
         )
-        sums = _epoch_eval_scan(state, vxs, vys, vws)
+        out = (state, losses, _epoch_eval_scan(state, vxs, vys, vws))
         if with_grad_norms:
-            return state, losses, sums, gnorms
-        return state, losses, sums
+            out += (gnorms,)
+        return out + (counters,)
 
     donate_argnums = _epoch_donate(donate, donate_stacks)
     return jax.jit(epoch_fused, donate_argnums=donate_argnums)
@@ -307,8 +361,9 @@ def make_multi_epoch_train_eval_step(donate: bool = True,
     xs/ys/ws: [K, S, B, ...]; the validation stacks [S_v, B, ...] are
     shared (fixed order) across epochs and NOT donated.
 
-    Returns (state, losses[K, S], val_sums = 6-tuple of [K] arrays);
-    ``with_grad_norms=True`` appends the grad global norms [K, S].
+    Returns (state, losses[K, S], val_sums = 6-tuple of [K] arrays, then
+    with ``with_grad_norms=True`` the grad global norms [K, S], and last
+    the model's sown counters per epoch [K, ...]).
     The sums come back as a TUPLE (the scan stacks each leaf separately)
     rather than one jnp.stack'd [K, 6] array, so every sum keeps its own
     dtype — a single f32 stack would silently coerce any future integer
@@ -322,18 +377,19 @@ def make_multi_epoch_train_eval_step(donate: bool = True,
     def multi_epoch(state: TrainState, xs, ys, ws, vxs, vys, vws):
         def epoch_body(st, stacks):
             exs, eys, ews = stacks
-            st, losses, gnorms = _epoch_train_scan(
+            st, losses, gnorms, counters = _epoch_train_scan(
                 st, exs, eys, ews, accum_steps
             )
             sums = _epoch_eval_scan(st, vxs, vys, vws)
-            return st, (losses, gnorms, sums)
+            return st, (losses, gnorms, sums, counters)
 
-        state, (losses, gnorms, val_sums) = jax.lax.scan(
+        state, (losses, gnorms, val_sums, counters) = jax.lax.scan(
             epoch_body, state, (xs, ys, ws)
         )
+        out = (state, losses, val_sums)
         if with_grad_norms:
-            return state, losses, val_sums, gnorms
-        return state, losses, val_sums
+            out += (gnorms,)
+        return out + (counters,)
 
     return jax.jit(
         multi_epoch, donate_argnums=_epoch_donate(donate, donate_stacks)

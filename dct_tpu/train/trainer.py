@@ -21,6 +21,7 @@ Plus what the reference lacks: true resume from full optimizer state
 
 from __future__ import annotations
 
+import gc
 import math
 import os
 import sys
@@ -67,6 +68,7 @@ from dct_tpu.tracking.client import get_tracker
 from dct_tpu.train.state import create_train_state
 from dct_tpu.utils.profiling import EpochTimer, Profiler
 from dct_tpu.train.steps import (
+    counter_metrics,
     make_epoch_train_eval_step,
     make_eval_step,
     make_train_step,
@@ -201,6 +203,8 @@ class _SpanInFlight:
     losses: object = None
     val_sums: object = None
     gnorms: object = None
+    # The model's sown counters, summed per epoch (steps.py).
+    counters: object = None
     t_dispatch: float = 0.0
     # Host seconds the dispatch call itself blocked (jit tracing + XLA
     # compile on a program's first span, ~enqueue cost after). Pipelined
@@ -656,11 +660,19 @@ class Trainer:
         accum = max(1, cfg.train.grad_accum_steps)
         # Span pipelining (the dispatch-gap work): with prefetch_spans
         # >= 1, span e+1 is DISPATCHED before span e's bookkeeping runs,
-        # so metric device_gets, the health pass, tracker/event logging,
-        # and both checkpoint tiers' writes all overlap device compute
-        # instead of serializing the hot loop. Bounded to ONE span in
-        # flight past the bookkeeping (early-stop and health decisions
-        # trail the device by at most that span — see _consume_span).
+        # so the health pass, tracker/event logging, and both checkpoint
+        # tiers' writes all overlap device compute instead of
+        # serializing the hot loop. The loop JOINS span e first (a
+        # device_get of a few scalars that returns when its program
+        # ends) and dispatches e+1 right after: program e's input state
+        # is free by then, so TWO states are alive at a dispatch (e's
+        # output, which e+1 reads and the bookkeeping saves, and e+1's
+        # output), where dispatching behind a running program held
+        # three. The price is the host time from the join's return to
+        # the enqueue, once a span (trainer.epoch_gap_ms). Bounded to
+        # ONE span in flight past the bookkeeping (early-stop and health
+        # decisions trail the device by at most that span — see
+        # _finish_span).
         # Auto-disabled under an armed fault plan: the injection drills
         # assert the exact serial crash/checkpoint ordering.
         pipelined = (
@@ -725,6 +737,9 @@ class Trainer:
             },
             emit=events.emit,
         )
+        # Kept for whoever drives the trainer (the benchmark reads the
+        # epoch program's HLO text off ``aot_store.executables``).
+        self.aot_store = aot_store
         if use_scan:
             # Built only for the per-epoch path: with epoch_chunk > 1
             # every span (including k == 1 remainders) dispatches the
@@ -734,8 +749,8 @@ class Trainer:
             # only in serial mode: pipelined bookkeeping still reads the
             # previous span's output state (checkpoint gather + resume
             # snapshot) while the next span computes from it, so that
-            # buffer must survive the dispatch — one extra resident
-            # state copy is the documented price of the overlap.
+            # buffer must survive the dispatch — the second resident
+            # state is the documented price of the overlap.
             if max(1, cfg.train.epoch_chunk) == 1:
                 epoch_fused = aot_store.wrap(make_epoch_train_eval_step(
                     donate=not pipelined,
@@ -924,13 +939,18 @@ class Trainer:
         timer_running = False
         layout_checked = False
 
-        def _bookkeep_span(sp, sub_epochs, epoch_stats, span_updates):
+        def _bookkeep_span(sp, sub_epochs, epoch_stats, span_updates,
+                           counted=None):
             """Every host-side consequence of a finished span: goodput
             report, per-epoch history/tracker/event records, early-stop
             updates, and BOTH checkpoint tiers. Shared by the scan
             path's consume (where, pipelined, it all overlaps the next
-            span's device compute) and the eager path. Returns
-            ``stop_early``."""
+            span's device compute) and the eager path. ``counted`` holds
+            one dict an epoch of the model's own counters
+            (steps.counter_metrics); they join the epoch's tracker
+            metrics and its ``epoch_end`` event. Returns
+            ``stop_early``, and lets go of the span's state: the next
+            dispatch must find two states alive, not three."""
             nonlocal es_best, es_stale, span_end_vl_min
             nonlocal consumed_through, bookkeep, layout_checked
             e0, k = sp.epoch0, sp.k
@@ -1007,6 +1027,8 @@ class Trainer:
                 history.append(epoch_rec)
                 if epoch_stats.mfu is not None:
                     epoch_metrics["mfu"] = epoch_stats.mfu
+                epoch_counted = counted[i] if counted else {}
+                epoch_metrics.update(epoch_counted)
                 metric_step = (
                     global_step - span_updates
                     + (i + 1) * per_epoch_updates
@@ -1019,6 +1041,7 @@ class Trainer:
                     train_loss=epoch_rec["train_loss"],
                     val_loss=val_loss, val_acc=val_acc,
                     goodput_fraction=span_goodput["goodput_fraction"],
+                    **epoch_counted,
                 )
                 if live_metrics is not None:
                     live_metrics.epoch_end(
@@ -1117,56 +1140,76 @@ class Trainer:
                 )
             sp.epoch_span.end(val_loss=sub_epochs[-1][1])
             consumed_through = e0 + k
+            # The next dispatch must find two states alive, not three.
+            sp.state = None
             return stop_early
 
-        def _consume_span(sp):
-            """Join span ``sp``'s device results and run all its host
-            bookkeeping. Serial mode calls it right after dispatch;
-            pipelined mode one span late, while the NEXT span computes
-            on device (so early-stop/health decisions trail the device
-            by at most one span — the documented trade). Returns
-            ``stop_early``."""
+        def _join_span(sp):
+            """Wait for span ``sp``'s program: returns the join bracket.
+            Once it returns the program's input state is no longer held
+            by the device. Pipelined, the loop calls it right BEFORE the
+            next dispatch, so it waits on ONE small output and reads
+            nothing back: every device_get of a result is a round trip
+            of its own on the TPU, and what stands between this return
+            and the next enqueue is time the device idles."""
+            with timed(
+                None, "trainer.join", epoch=sp.epoch0, k=sp.k
+            ) as join:
+                # While the program still runs, collect: a full gc, which
+                # also has jax drop the Python references of the buffers
+                # the bookkeeping let go (the state before this one). Left
+                # alone, both happen inside the next dispatch call (159 ms
+                # of PythonRefManager::CollectGarbage in the profile) or
+                # whenever the allocator's counters say, between this
+                # join's return and the enqueue. A program that has ended
+                # means the host sets the pace: nothing to hide it under.
+                if pipelined and not sp.losses.is_ready():
+                    gc.collect()
+                jax.block_until_ready(sp.losses)
+            return join
+
+        def _finish_span(sp, join):
+            """All host bookkeeping of the joined span ``sp``. Serial
+            mode runs it right after the join; pipelined mode after the
+            NEXT span's dispatch, while that span computes on device (so
+            early-stop/health decisions trail the device by at most one
+            span — the documented trade). Returns ``stop_early``."""
             nonlocal global_step, dispatch_span, epoch_span, bookkeep
             import numpy as _np
 
             e0, k = sp.epoch0, sp.k
-            # Point the crash sweep at the span being joined: if the
-            # join or its bookkeeping dies, THESE are the spans still
-            # in flight (a pipelined successor's live in pending).
+            # The program has ended and the D2H copies were started right
+            # after its dispatch: the bytes are on the host or on their
+            # way.
+            if multi_fused is not None:
+                # [K, S] losses; val_sums is a 6-tuple of [K] arrays
+                # (dtype-preserving per leaf — see
+                # make_multi_epoch_train_eval_step). Stack host-side as
+                # float64 -> [K, 6]; the upcast only protects the
+                # stacking, precision is bounded by the on-device f32
+                # accumulation (exact for integral weights up to 2^24
+                # per epoch, steps.py).
+                losses_host = _np.asarray(jax.device_get(sp.losses))
+                gnorms_host = _np.asarray(jax.device_get(sp.gnorms))
+                val_host = _np.stack(
+                    [
+                        _np.asarray(v, dtype=_np.float64)
+                        for v in jax.device_get(sp.val_sums)
+                    ],
+                    axis=1,
+                )
+            else:  # [S] / 6-tuple — the k == 1 parity layout
+                losses_host = _np.asarray(jax.device_get(sp.losses))[None]
+                gnorms_host = _np.asarray(jax.device_get(sp.gnorms))[None]
+                val_host = _np.asarray(
+                    [float(v) for v in jax.device_get(sp.val_sums)]
+                )[None]
+            counters_host = jax.device_get(sp.counters)
+            # Point the crash sweep at the span being bookkept: if this
+            # dies, THESE are the spans still in flight (a pipelined
+            # successor's live in pending).
             dispatch_span = sp.dispatch_span
             epoch_span = sp.epoch_span
-            # The device_get joins the span's program; the D2H copies
-            # were started right after its dispatch, so in steady
-            # pipelined state the bytes are already on the host: this
-            # is the thread waiting for the device.
-            with timed(None, "trainer.join", epoch=e0, k=k) as join:
-                if multi_fused is not None:
-                    # [K, S] losses; val_sums is a 6-tuple of [K] arrays
-                    # (dtype-preserving per leaf — see
-                    # make_multi_epoch_train_eval_step). Stack host-side as
-                    # float64 -> [K, 6]; the upcast only protects the
-                    # stacking, precision is bounded by the on-device f32
-                    # accumulation (exact for integral weights up to 2^24
-                    # per epoch, steps.py).
-                    losses_host = _np.asarray(jax.device_get(sp.losses))
-                    gnorms_host = _np.asarray(jax.device_get(sp.gnorms))
-                    val_host = _np.stack(
-                        [
-                            _np.asarray(v, dtype=_np.float64)
-                            for v in jax.device_get(sp.val_sums)
-                        ],
-                        axis=1,
-                    )
-                else:  # [S] / 6-tuple — the k == 1 parity layout
-                    losses_host = _np.asarray(
-                        jax.device_get(sp.losses)
-                    )[None]
-                    gnorms_host = _np.asarray(
-                        jax.device_get(sp.gnorms)
-                    )[None]
-                    val_host = _np.asarray(
-                        [float(v) for v in jax.device_get(sp.val_sums)]
-                    )[None]
             # Everything between the join and the checkpoint section
             # (tracker, events, health, heartbeat); the ledger leaves
             # it unattributed. _bookkeep_span closes it.
@@ -1184,6 +1227,8 @@ class Trainer:
             # plus the join above. Device time overlapped by host
             # bookkeeping is exactly the overlap the mode buys; it
             # surfaces as the other categories' windows, never twice.
+            # (The join now precedes the successor's dispatch call, so
+            # the two windows stay disjoint.)
             _billed = (
                 (sp.dispatch_elapsed + join.seconds)
                 if pipelined
@@ -1267,7 +1312,22 @@ class Trainer:
                     accs / c if c else float("nan"),
                     (tp, fp, fn),
                 ))
-            return _bookkeep_span(sp, sub_epochs, epoch_stats, flat.size)
+            # The model's counters: one tree a span on the k == 1 path,
+            # a leading epoch axis under the multi-epoch program.
+            counted = [
+                counter_metrics(
+                    counters_host if multi_fused is None
+                    else jax.tree.map(lambda c, i=i: c[i], counters_host)
+                )
+                for i in range(k)
+            ] if jax.tree.leaves(counters_host) else None
+            return _bookkeep_span(
+                sp, sub_epochs, epoch_stats, flat.size, counted
+            )
+
+        def _consume_span(sp):
+            """Join, then bookkeep (the serial order)."""
+            return _finish_span(sp, _join_span(sp))
 
         try:
             epoch = start_epoch
@@ -1347,17 +1407,28 @@ class Trainer:
                         heartbeat.beat(
                             step=global_step, epoch=epoch, phase="dispatch",
                         )
+                    # Pipelined: wait for the span in flight BEFORE
+                    # dispatching this one (its data is staged already).
+                    # Its program's input state is then free and the
+                    # state it bookkept last was let go, so this
+                    # dispatch finds two states alive. Its read-back and
+                    # bookkeeping wait until this span runs on the device.
+                    joined = (
+                        _join_span(pending)
+                        if pipelined and pending is not None else None
+                    )
                     # The dispatch window closes at block_until_ready
                     # below; a span of k epochs and a ragged remainder
                     # span are DIFFERENT XLA programs, so the ledger's
                     # compile detection keys on k.
                     # dct: begin-no-host-sync — the pipelined dispatch
-                    # region: from here until the consume swap, nothing
-                    # may join device results (device_get, float()/int()
-                    # on arrays, .block_until_ready()) or the one-span
-                    # overlap PR 5 bought collapses back to serial. The
-                    # join belongs in _consume_span, one span later.
-                    # Enforced by dct-lint rule `span-sync`.
+                    # region: from here until the bookkeeping swap,
+                    # nothing may join device results (device_get,
+                    # float()/int() on arrays, .block_until_ready()) or
+                    # the one-span overlap PR 5 bought collapses back to
+                    # serial. The join belongs in _join_span, above for
+                    # the span in flight and one iteration later for
+                    # this one. Enforced by dct-lint rule `span-sync`.
                     _key = f"scan_k{k}"
                     # Dispatch to join: overlaps its successor under
                     # pipelining, so JSONL-only (spans.py).
@@ -1383,19 +1454,16 @@ class Trainer:
                         # 1:1 with the compile.window accounting below.
                         if not batch_devices:
                             batch_devices = _device_ids(globs)
-                        if multi_fused is not None:
-                            state, losses, val_sums, gnorms = multi_fused(
-                                state, *globs, *val_global, key=_key
-                            )
-                        else:
-                            state, losses, val_sums, gnorms = epoch_fused(
-                                state, *globs, *val_global, key=_key
-                            )
+                        state, losses, val_sums, gnorms, counters = (
+                            multi_fused or epoch_fused
+                        )(state, *globs, *val_global, key=_key)
                     # Non-blocking bookkeeping: start the D2H copies of
                     # everything consume will read NOW, so by the time
                     # the span is bookkept the bytes are already on the
                     # host and device_get just unblocks.
-                    for _buf in (losses, gnorms, *val_sums):
+                    for _buf in (
+                        losses, gnorms, *val_sums, *jax.tree.leaves(counters)
+                    ):
                         try:
                             _buf.copy_to_host_async()
                         except (AttributeError, RuntimeError):
@@ -1428,21 +1496,22 @@ class Trainer:
                     cur = _SpanInFlight(
                         epoch0=epoch, k=k, n_steps=n_steps, state=state,
                         losses=losses, val_sums=val_sums, gnorms=gnorms,
+                        counters=counters,
                         t_dispatch=dispatch_call.t0,
                         dispatch_elapsed=dispatch_call.seconds,
                         dispatch_span=dispatch_span,
                         epoch_span=epoch_span,
                     )
-                    # dct: end-no-host-sync — the consume below is the
-                    # intended join point (serial mode joins its own
-                    # span; pipelined joins the PREVIOUS one).
+                    # dct: end-no-host-sync — serial mode joins its own
+                    # span here; pipelined joined the PREVIOUS one above,
+                    # before this dispatch, and bookkeeps it now.
                     if pipelined:
-                        # Swap FIRST: if consuming the previous span
+                        # Swap FIRST: if bookkeeping the previous span
                         # raises (health halt), the finally sweep still
                         # finds the in-flight successor via `pending`.
                         _sp, pending = pending, cur
                         stop_early = (
-                            _consume_span(_sp) if _sp is not None
+                            _finish_span(_sp, joined) if _sp is not None
                             else False
                         )
                     else:

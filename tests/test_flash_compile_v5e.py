@@ -21,11 +21,13 @@ import pytest
 from dct_tpu.ops import pallas_attention as pa
 
 #: (b, q heads, kv heads, T, d, window): BENCHMARK.json's sc2_3b cells at
-#: 4,096 and 512 positions, then a length whose one dividing tile is no
+#: 4,096 and 512 positions and its lfm2 cell (head size 64, 8,192
+#: positions, full causal), then a length whose one dividing tile is no
 #: power of two (640 = 5 x 128: the rule picks the 640-row tile).
 SHAPES = {
     "sc2_3b_seq4096": (2, 24, 2, 4096, 128, 4096),
     "sc2_3b_seq512": (16, 24, 2, 512, 128, 4096),
+    "lfm2_24b_seq8192": (1, 32, 8, 8192, 64, None),
     "odd_seq640": (1, 4, 2, 640, 128, None),
 }
 

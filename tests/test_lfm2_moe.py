@@ -1,0 +1,316 @@
+"""The hybrid family (``weather_hybrid_moe_causal``: per-layer operator,
+gated short convolution, QK-norm GQA, sigmoid-routed SwiGLU experts held as
+one share of an expert-parallel layer) against its plain float32 reference,
+at small widths on the CPU with seeded weights."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import reference_lfm2_moe as REF
+from dct_tpu.config import ModelConfig
+from dct_tpu.models.moe import MoEFFN, _grouped_moe
+from dct_tpu.models.registry import (
+    get_model,
+    is_causal_model,
+    is_sequence_model,
+)
+from dct_tpu.ops.shortconv import causal_depthwise_conv, gated_short_conv
+
+TOL = 2e-5
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+#: A small lfm2_moe: (conv, attention, conv, conv, conv), one dense layer,
+#: 16 experts of which 4 are held, top-4.
+REF_CONFIG = {
+    "layer_types": ["conv", "conv", "full_attention", "conv", "conv", "conv"],
+    "layers_run": [0, 2, 3, 4, 5],
+    "num_hidden_layers": 5, "num_dense_layers": 1,
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "rope_parameters": {"rope_theta": 1000000.0}, "norm_eps": 1e-5,
+    "num_experts_per_tok": 4, "num_experts": 4, "first_expert": 4,
+    "routed_scaling_factor": 1.0,
+}
+ENV = {
+    "DCT_MODEL": "weather_hybrid_moe_causal", "DCT_D_MODEL": "32",
+    "DCT_N_HEADS": "4", "DCT_N_KV_HEADS": "2", "DCT_N_LAYERS": "5",
+    "DCT_D_FF": "96", "DCT_SEQ_LEN": "48", "DCT_POS_EMBED": "rope",
+    "DCT_ROPE_THETA": "1000000", "DCT_DROPOUT": "0", "DCT_NORM": "rmsnorm",
+    "DCT_NORM_EPS": "1e-5", "DCT_MLP": "swiglu", "DCT_USE_BIAS": "0",
+    "DCT_QK_NORM": "1",
+    "DCT_LAYER_TYPES": "conv,full_attention,conv,conv,conv",
+    "DCT_NUM_DENSE_LAYERS": "1", "DCT_N_EXPERTS": "16",
+    "DCT_ROUTER_TOP_K": "4", "DCT_MOE_D_FF": "24",
+    "DCT_EXPERTS_HELD": "4",
+    "DCT_FIRST_EXPERT": "4", "DCT_CAPACITY_FACTOR": "4",
+}
+
+
+@pytest.fixture(scope="module")
+def family(tmp_path_factory):
+    """(model, params with a seeded non-zero expert bias, x, y), the model
+    built from the environment the way ``RunConfig.from_env`` builds it."""
+    saved = {k: os.environ.get(k) for k in ENV}
+    os.environ.update(ENV)
+    try:
+        cfg = ModelConfig.from_env()
+    finally:
+        for k, v in saved.items():
+            os.environ.pop(k, None) if v is None else os.environ.update({k: v})
+    model = get_model(cfg, input_dim=5, compute_dtype=jnp.float32)
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((2, 48, 5)).astype(np.float32)
+    y = rng.integers(0, 2, (2, 48)).astype(np.int32)
+    params = jax.device_get(
+        jax.jit(model.init)(jax.random.PRNGKey(1), jnp.asarray(x[:1]))
+    )["params"]
+    for name, block in params.items():
+        if "moe" in block:
+            block["moe"]["expert_bias"] = (
+                0.05 * rng.standard_normal(16)).astype(np.float32)
+    return model, params, x, y
+
+
+def _ce(logits, y):
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, y[..., None], -1).mean()
+
+
+def test_family_is_causal_per_position_and_reads_its_shape_from_env(family):
+    model, params, x, _ = family
+    assert is_sequence_model("weather_hybrid_moe_causal")
+    assert is_causal_model("weather_hybrid_moe_causal")
+    assert model.layer_types == (
+        "conv", "full_attention", "conv", "conv", "conv")
+    assert "conv" in params["block_0"] and "ffn_gate" in params["block_0"]
+    assert "attn" in params["block_1"] and "moe" in params["block_1"]
+    assert params["block_1"]["moe"]["router"]["kernel"].shape == (32, 16)
+    assert params["block_1"]["moe"]["experts_in_kernel"].shape == (4, 32, 24)
+    assert not any("bias" in k for b in params.values() for k in b
+                   if isinstance(b, dict) and k != "moe")
+
+
+def test_logits_and_loss_match_the_reference(family):
+    model, params, x, y = family
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(model.apply({"params": params}, x, train=False))
+    want, want_loss = REF.forward_and_loss(params, x, y, REF_CONFIG)
+    assert got.shape == want.shape == (2, 48, 2)
+    np.testing.assert_allclose(got, want, rtol=0, atol=TOL)
+    assert abs(REF.cross_entropy(got, y) - want_loss) < TOL
+
+
+def test_gradients_match_the_reference(family):
+    model, params, x, y = family
+    kw = REF.settings(REF_CONFIG)
+
+    def system(p):
+        return _ce(model.apply({"params": p}, x, train=False), y)
+
+    def reference(p):
+        logits = jnp.stack(
+            [REF.forward_one(p, xi, None, **kw)[0] for xi in x])
+        return _ce(logits, y)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(system)(params)
+        want = jax.grad(reference)(params)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want))
+    assert len(flat_got) == len(flat_want)
+    for path, g in flat_got:
+        np.testing.assert_allclose(
+            g, flat_want[path], rtol=0, atol=TOL, err_msg=str(path))
+    # The selection bias is under stop_gradient: Adam sees zero.
+    assert not np.asarray(got["block_2"]["moe"]["expert_bias"]).any()
+
+
+def test_short_convolution_is_causal():
+    rng = np.random.default_rng(2)
+    z = rng.standard_normal((2, 16, 8)).astype(np.float32)
+    taps = rng.standard_normal((8, 3)).astype(np.float32)
+    base = np.asarray(causal_depthwise_conv(z, taps))
+    later = z.copy()
+    later[:, 9:] += 1.0
+    moved = np.asarray(causal_depthwise_conv(later, taps))
+    np.testing.assert_array_equal(base[:, :9], moved[:, :9])
+    assert np.abs(base[:, 9:] - moved[:, 9:]).max() > 0.1
+
+
+def test_short_convolution_equals_the_depthwise_lax_form():
+    rng = np.random.default_rng(3)
+    bcx = rng.standard_normal((2, 16, 24)).astype(np.float32)
+    taps = rng.standard_normal((8, 3)).astype(np.float32)
+    b, c, x = np.split(bcx, 3, axis=-1)
+    conv = jax.lax.conv_general_dilated(
+        jnp.asarray(b * x), jnp.asarray(taps.T[:, None, :]),
+        window_strides=(1,), padding=[(2, 0)],
+        dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=8,
+        precision="highest")
+    np.testing.assert_allclose(
+        gated_short_conv(bcx, taps), c * np.asarray(conv), rtol=0, atol=1e-5)
+
+
+def _layer(held, first, e=16):
+    return MoEFFN(
+        d_model=32, d_ff=24, n_experts=e,
+        aux_weight=0.0, dispatch="grouped", top_k=4,
+        experts_held=held, first_expert=first)
+
+
+@pytest.fixture(scope="module")
+def whole_layer():
+    """An uncut 16-expert layer's parameters and 96 tokens."""
+    rng = np.random.default_rng(4)
+    x = rng.standard_normal((2, 48, 32)).astype(np.float32)
+    layer = _layer(0, 0)
+    p = jax.device_get(layer.init(jax.random.PRNGKey(5), x))["params"]
+    return p, x
+
+
+def _apply(layer, p, x):
+    with jax.default_matmul_precision("highest"):
+        out, sown = layer.apply(
+            {"params": p}, x, mutable=["counters", "intermediates"])
+    return np.asarray(out), jax.device_get(sown)
+
+
+def test_expert_bias_changes_which_experts_not_their_weights(whole_layer):
+    p, x = whole_layer
+    rng = np.random.default_rng(6)
+    biased = {**p, "expert_bias": (
+        0.2 * rng.standard_normal(16)).astype(np.float32)}
+    _, plain = _apply(_layer(0, 0), p, x)
+    out, sown = _apply(_layer(0, 0), biased, x)
+    top_plain = np.sort(plain["intermediates"]["topk"][0], -1)
+    top_biased = np.sort(sown["intermediates"]["topk"][0], -1)
+    moved = (top_plain != top_biased).any(-1)
+    assert 0.2 < moved.mean() < 1.0
+    # The output is the reference's for the biased choice with weights
+    # from the UNBIASED scores: s at the chosen experts over their sum.
+    want, chosen, _ = REF._moe(
+        jnp.asarray(x.reshape(-1, 32)), biased, top_k=4, first=0,
+        scaling=1.0, routing=None)
+    np.testing.assert_array_equal(np.sort(chosen, -1), top_biased)
+    np.testing.assert_allclose(
+        out.reshape(-1, 32), want, rtol=0, atol=TOL)
+    # A bias added to the weights as well would fail this by far.
+    s = jax.nn.sigmoid(x.reshape(-1, 32) @ p["router"]["kernel"])
+    w = np.take_along_axis(np.asarray(s), np.asarray(chosen), -1)
+    wb = w + biased["expert_bias"][np.asarray(chosen)]
+    assert np.abs(w / w.sum(-1, keepdims=True)
+                  - wb / wb.sum(-1, keepdims=True)).max() > 0.02
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer(whole_layer):
+    p, x = whole_layer
+    rng = np.random.default_rng(7)
+    p = {**p, "expert_bias": (
+        0.1 * rng.standard_normal(16)).astype(np.float32)}
+    want, _, _ = REF._moe(
+        jnp.asarray(x.reshape(-1, 32)), p, top_k=4, first=0, scaling=1.0,
+        routing=None)
+    total = np.zeros_like(np.asarray(want))
+    rows = 0
+    for share in range(8):
+        first = 2 * share
+        held = {
+            k: (v[first:first + 2] if k.startswith("experts_") else v)
+            for k, v in p.items()}
+        out, sown = _apply(_layer(2, first), held, x)
+        total += out.reshape(-1, 32)
+        rows += int(sown["counters"]["moe_rows"][0].sum())
+        assert int(sown["counters"]["moe_rows_overflowed"][0]) == 0
+    np.testing.assert_allclose(total, want, rtol=0, atol=TOL)
+    assert rows == 96 * 4  # every routed row is some share's
+
+
+def test_no_row_is_dropped_when_one_held_expert_takes_half_the_rows(
+        whole_layer):
+    p, x = whole_layer
+    tokens = x.reshape(-1, 32)
+    # Expert 5's score is ~1 for the tokens on one side of a hyperplane
+    # and ~0 for the others: it is chosen by half of them.
+    u = np.random.default_rng(8).standard_normal(32).astype(np.float32)
+    kernel = np.array(p["router"]["kernel"])
+    kernel[:, 5] = 4.0 * u
+    held = {
+        k: (v[4:8] if k.startswith("experts_") else v) for k, v in p.items()}
+    held["router"] = {"kernel": kernel}
+    out, sown = _apply(_layer(4, 4), held, x)
+    rows = sown["counters"]["moe_rows"][0]
+    half = int((tokens @ u > 0).sum())
+    assert abs(int(rows[1]) - half) <= 4 and 40 <= half <= 56
+    # Twice the uniform mean at 16 experts (eight times at 64).
+    assert rows[1] > 1.8 * float(sown["counters"]["moe_rows_uniform"][0])
+    assert int(sown["counters"]["moe_rows_overflowed"][0]) == 0
+    want, _, _ = REF._moe(
+        jnp.asarray(tokens), held, top_k=4, first=4, scaling=1.0,
+        routing=None)
+    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=0, atol=TOL)
+
+
+def test_rows_past_the_bound_are_counted_not_hidden(whole_layer):
+    p, x = whole_layer
+    held = {
+        k: (v[:4] if k.startswith("experts_") else v) for k, v in p.items()}
+    _, full = _apply(_layer(4, 0), held, x)
+    routed = int(full["counters"]["moe_rows"][0].sum())
+    assert int(full["counters"]["moe_rows_overflowed"][0]) == 0
+    # The layer's bound is every row that can come (96 x 4); the grouped
+    # engine under a tighter one counts what it leaves out.
+    topi = full["intermediates"]["topk"][0]
+    _, rows, overflow = _grouped_moe(
+        jnp.asarray(x.reshape(-1, 32)), topi,
+        jnp.full(topi.shape, 0.25, jnp.float32),
+        held["experts_gate_kernel"], held["experts_in_kernel"],
+        held["experts_out_kernel"], first_expert=0, row_bound=48)
+    assert int(rows.sum()) == routed
+    assert int(overflow) == routed - 48
+
+
+def test_the_two_reference_copies_are_byte_identical():
+    bench = os.path.join(
+        os.path.dirname(HERE), "benchmark", "reference", "lfm2_moe.py")
+    with open(bench, "rb") as a, open(REF.__file__, "rb") as b:
+        assert a.read() == b.read()
+
+
+def test_sc2_block_environment_builds_the_same_tree_and_logits_as_before():
+    """``weather_transformer_causal`` at sc2_3b_block's environment (small
+    widths): the parameters tree and the logits the parent commit gave,
+    recorded there. The block's new fields default to that block."""
+    cfg = ModelConfig(
+        name="weather_transformer_causal", d_model=48, n_heads=6,
+        n_kv_heads=2, d_ff=96, n_layers=2, attn_window=16, pos_embed="rope",
+        dropout=0.1, num_classes=2, seq_len=32)
+    model = get_model(cfg, input_dim=5, compute_dtype=jnp.float32)
+    x = np.random.default_rng(7).standard_normal((2, 32, 5)).astype(
+        np.float32)
+    v = jax.jit(model.init)(jax.random.PRNGKey(3), jnp.asarray(x[:1]))
+    block = {
+        "attn": {"o_proj": {"bias": (48,), "kernel": (48, 48)},
+                 "qkv_proj": {"bias": (80,), "kernel": (48, 80)}},
+        "ffn_in": {"bias": (96,), "kernel": (48, 96)},
+        "ffn_out": {"bias": (48,), "kernel": (96, 48)},
+        "ln_attn": {"bias": (48,), "scale": (48,)},
+        "ln_ffn": {"bias": (48,), "scale": (48,)},
+    }
+    assert jax.tree.map(lambda a: a.shape, jax.device_get(v["params"])) == {
+        "block_0": block, "block_1": block,
+        "head": {"bias": (2,), "kernel": (48, 2)},
+        "in_proj": {"bias": (48,), "kernel": (5, 48)},
+        "ln_out": {"bias": (48,), "scale": (48,)},
+    }
+    with jax.default_matmul_precision("highest"):
+        out = np.asarray(model.apply(v, x, train=False))
+    np.testing.assert_allclose(out[0, :3], [
+        [-0.95113057, -0.31314194], [-0.2711224, -0.593053],
+        [-0.6395803, -0.34496617]], rtol=0, atol=2e-6)
+    np.testing.assert_allclose(out[1, -2:], [
+        [-0.7167114, -0.68504643], [-0.42777747, -0.17218004]],
+        rtol=0, atol=2e-6)
+    assert abs(float(np.abs(out).sum()) - 56.353355407714844) < 1e-3

@@ -73,7 +73,7 @@ def test_fused_train_eval_matches_separate(rng):
 
     def fused():
         state = create_train_state(model, input_dim=5, lr=0.01, seed=42)
-        state, losses, sums = make_epoch_train_eval_step(
+        state, losses, sums, _ = make_epoch_train_eval_step(
             donate=False
         )(
             state, jnp.asarray(x), jnp.asarray(y), jnp.asarray(w),

@@ -393,10 +393,10 @@ def test_train_step_exposes_grad_norm():
     # consumers measure the exact prior program).
     _, plain = make_train_step(donate=False)(state, x, y, w)
     assert "grad_norm" not in plain
-    # Scan path: with_grad_norms appends per-update norms; the default
-    # signature is unchanged (pinned by tests/test_scan_path.py).
+    # Scan path: with_grad_norms puts per-update norms before the
+    # model's counters, which every signature ends with.
     xs, ys, ws = x[None], y[None], w[None]
-    _, losses, sums, gnorms = make_epoch_train_eval_step(
+    _, losses, sums, gnorms, _ = make_epoch_train_eval_step(
         donate=False, with_grad_norms=True
     )(state, xs, ys, ws, xs, ys, ws)
     assert gnorms.shape == losses.shape == (1,)

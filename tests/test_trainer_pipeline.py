@@ -101,6 +101,72 @@ def test_pipelined_goodput_windows_never_double_count(
     assert all(0.0 <= f <= 1.0 + 1e-9 for f in fracs), fracs
 
 
+def test_pipelined_loop_holds_two_states_at_a_dispatch(
+    processed_dir, tmp_path, monkeypatch
+):
+    """At every dispatch of the pipelined loop the states reachable are
+    the program's input and nothing older (its output makes two): the span
+    joined before the dispatch has finished, and the span bookkept before
+    it let its state go. Bookkeeping of span e still starts after the
+    dispatch of e+1."""
+    import gc
+    import weakref
+
+    from dct_tpu.train import trainer as trainer_mod
+
+    order: list = []
+    states: list = []
+    real = trainer_mod.make_epoch_train_eval_step
+
+    def counting(**kw):
+        fused = real(**kw)
+
+        def epoch_fused(state, *stacks):
+            gc.collect()
+            alive = sum(1 for r in states if r() is not None)
+            order.append(("dispatch", len(
+                [o for o in order if o[0] == "dispatch"]), alive))
+            out = fused(state, *stacks)
+            states.append(weakref.ref(out[0]))
+            return out
+
+        return epoch_fused
+
+    monkeypatch.setattr(trainer_mod, "make_epoch_train_eval_step", counting)
+
+    class Recording(LocalTracking):
+        def log_metrics(self, metrics, step=None):
+            if "val_loss" in metrics:
+                order.append(("bookkeep", sum(
+                    1 for o in order if o[0] == "bookkeep")))
+            return super().log_metrics(metrics, step=step)
+
+    cfg = RunConfig(
+        data=DataConfig(
+            processed_dir=processed_dir, models_dir=str(tmp_path / "m2s")),
+        train=TrainConfig(
+            epochs=5, batch_size=8, bf16_compute=False, prefetch_spans=1),
+        tracking=TrackingConfig(experiment="pl"),
+        obs=ObservabilityConfig(
+            events_dir=str(tmp_path / "ev2s"),
+            heartbeat_dir=str(tmp_path / "hb2s")),
+    )
+    res = Trainer(cfg, tracker=Recording(
+        root=str(tmp_path / "r2s"), experiment="pl")).fit()
+    assert len(res.history) == 5
+    dispatches = [o for o in order if o[0] == "dispatch"]
+    assert len(dispatches) == 5
+    # Outputs of earlier programs still reachable when program n is
+    # dispatched: its input, never its input's predecessor.
+    assert [d[2] for d in dispatches] == [0, 1, 1, 1, 1]
+    kinds = [(o[0], o[1]) for o in order]
+    for e in range(4):
+        assert kinds.index(("dispatch", e + 1)) < kinds.index(("bookkeep", e))
+        if e:
+            assert kinds.index(("bookkeep", e - 1)) < kinds.index(
+                ("dispatch", e + 1))
+
+
 def test_pipelined_matches_serial_with_epoch_chunk(processed_dir, tmp_path):
     _, r1 = _fit(
         processed_dir, tmp_path, "ec_pf1", epoch_chunk=2, prefetch_spans=1
