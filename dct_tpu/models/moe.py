@@ -37,8 +37,9 @@ a third is the dropless layer of the hybrid family
   bias, weights normalised over the chosen experts,
   bias-free SwiGLU experts, and NO dropping: the routed rows are sorted by
   expert and go through three grouped products (``jax.lax.ragged_dot``, a
-  Mosaic grouped matmul on the TPU) over a static row bound, the most rows
-  that can be routed to the held experts. The layer is
+  Mosaic grouped matmul on the TPU), a static chunk of rows at a time, as
+  many chunks as hold the rows that were routed this step
+  (``_chunked_moe``). The layer is
   told which experts it holds (``experts_held``, ``first_expert``): the
   router stays ``n_experts`` wide, the top-k is over all of them, and the
   layer computes its own experts' part of the sum, one chip's share of an
@@ -58,6 +59,8 @@ via ``self.sow("aux_loss", ...)``; the train step folds every sown
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -127,55 +130,223 @@ def _sorted_moe(tokens, expert_idx, gate, w_in, b_in, w_out, b_out, *,
     return out * gate[:, None]
 
 
+def _held_rows(topi, *, held: int, first_expert: int):
+    """The N * k routed rows by held expert: (``order``, the stable sort
+    of the rows by held expert with the rows of experts held elsewhere
+    last; ``rows`` [held] int32 routed to each held expert). Integer work
+    on the router's choice only."""
+    local = topi.astype(jnp.int32) - first_expert
+    flat = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    rows = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    return jnp.argsort(flat, stable=True), rows
+
+
+def _chunk_rows(tokens, order, rows, *, k: int, chunk: int, index):
+    """Sorted rows ``index * chunk .. (index + 1) * chunk - 1``: (x
+    [chunk, D], each row's token; row [chunk], its place among the N * k;
+    ``zeroed``, which zeroes a [chunk, ...] past the routed rows, as x is;
+    ``product``, the grouped product of such rows with a held stack,
+    zeroed). ``order`` must reach the chunk's end.
+
+    A grouped product is ``jax.lax.ragged_dot``: on the TPU a Mosaic
+    grouped matmul that visits only the tiles the group sizes cover. Past
+    the routed rows its output is whatever the buffer held, NaN included,
+    and so is the cotangent its transpose hands back: the rows are zeroed
+    going in and after EVERY product, so nothing the tail holds reaches
+    the result, a gradient, or (as 0 x NaN) the next product's backward."""
+    first = index * chunk
+    ends = jnp.cumsum(rows)
+    sizes = (jnp.clip(ends, first, first + chunk)
+             - jnp.clip(ends - rows, first, first + chunk))
+    valid = (first + jnp.arange(chunk) < ends[-1])[:, None]
+    row = lax.dynamic_slice_in_dim(order, first, chunk)
+
+    def zeroed(a):
+        return jnp.where(valid, a, 0)
+
+    def product(a, w):
+        return zeroed(lax.ragged_dot(a, w, sizes))
+
+    return zeroed(tokens[row // k]), row, zeroed, product
+
+
+def _grouped_chunk(tokens, gates, order, rows, w_gate, w_in, w_out, *,
+                   chunk: int, index):
+    """One chunk of sorted rows through the held experts: (y [chunk, D]
+    f32, each row its expert's output times its gate, zero past the
+    routed rows; token_of [chunk], the token each row belongs to)."""
+    k = gates.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        x, row, _, product = _chunk_rows(
+            tokens, order, rows, k=k, chunk=chunk, index=index)
+    with jax.named_scope("moe.experts"):
+        h = nn.silu(product(x, w_gate)) * product(x, w_in)
+        y = product(h, w_out)
+    with jax.named_scope("moe.combine"):
+        y = y.astype(jnp.float32) * gates.reshape(-1)[row][:, None]
+    return y, row // k
+
+
+def _grouped_chunk_transposed(ct, tokens, gates, order, rows, stacks,
+                              transposed, *, chunk: int, index):
+    """What ``ct`` [N, D] f32, the cotangent of the layer's output, hands
+    back through one chunk: (dx [chunk, D] for its rows' tokens, d_gate
+    [chunk] f32 for their gates, row [chunk], and (x, h, dg, du, dy), the
+    operands of the three stacks' gradients ``x^T dg``, ``x^T du`` and
+    ``h^T dy``, which are left to the caller), all zero past the routed
+    rows. :func:`_grouped_chunk` computed again and transposed step for
+    step, as autodiff would, less those three products; ``transposed`` are
+    the three ``stacks`` [held, out, in], which autodiff would form here."""
+    k = gates.shape[1]
+    w_gate, w_in, w_out = stacks
+    w_gate_t, w_in_t, w_out_t = transposed
+    with jax.named_scope("moe.dispatch"):
+        x, row, zeroed, product = _chunk_rows(
+            tokens, order, rows, k=k, chunk=chunk, index=index)
+    with jax.named_scope("moe.experts"):
+        h, act = jax.vjp(
+            lambda g, u: nn.silu(g) * u, product(x, w_gate),
+            product(x, w_in))
+        y = product(h, w_out)
+    with jax.named_scope("moe.combine"):
+        d_y = ct[row // k]
+        d_gate = (d_y * y.astype(jnp.float32)).sum(-1)
+        dy = zeroed((d_y * gates.reshape(-1)[row][:, None]).astype(y.dtype))
+    with jax.named_scope("moe.experts"):
+        dg, du = act(product(dy, w_out_t))
+        dx = product(dg, w_gate_t) + product(du, w_in_t)
+    return dx, d_gate, row, (x, h, dg, du, dy)
+
+
 def _grouped_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
                  first_expert: int, row_bound: int):
-    """The held experts' part of a top-k layer, no row dropped up to
-    ``row_bound``: ``sum_{i in topk, i held} gate_i * E_i(x)``.
+    """The held experts' part of a top-k layer under ONE static row
+    bound, no row dropped up to it:
+    ``sum_{i in topk, i held} gate_i * E_i(x)``.
 
     tokens [N, D] (compute dtype), topi / gates [N, k] (global expert ids,
     f32 weights); the expert weights are the HELD stack [held, ...] of
     bias-free SwiGLU experts. The N * k routed rows are sorted by held
     expert, the rows of experts held elsewhere last, and the first
-    ``row_bound`` go through three grouped products
-    (``jax.lax.ragged_dot``: on the TPU a Mosaic grouped matmul that
-    visits only the tiles the group sizes cover). Past the routed rows a
-    grouped product's output is whatever the buffer held, NaN included,
-    and so is the cotangent its transpose hands back: the rows are zeroed
-    going in and after EVERY product, so nothing the tail holds reaches
-    the result, a gradient, or (as 0 x NaN) the next product's backward.
-    The group sizes are the routed rows: the kernel visits the tiles they
-    cover and skips the tail, so a step's time follows the router's load.
+    ``row_bound`` are one :func:`_grouped_chunk`. The products skip the
+    tail past the routed rows; the gather, the masks, the silu and the
+    weighted scatter-add run on ``row_bound`` rows whatever was routed,
+    which is why the layer runs :func:`_chunked_moe` instead.
     Returns (out [N, D] f32, rows [held] int32 routed to each held expert,
     overflow int32 = routed rows past ``row_bound``, which are dropped: 0
     or the bound is wrong)."""
-    n, d = tokens.shape
-    k = topi.shape[1]
-    held = w_in.shape[0]
     with jax.named_scope("moe.dispatch"):
-        local = topi.astype(jnp.int32) - first_expert
-        flat = jnp.where(
-            (local >= 0) & (local < held), local, held
-        ).reshape(n * k)
-        rows = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
-        order = jnp.argsort(flat, stable=True)[:row_bound]
-        ends = jnp.minimum(jnp.cumsum(rows), row_bound)
-        overflow = rows.sum() - ends[-1]
-        valid = (jnp.arange(row_bound) < ends[-1])[:, None]
-        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-        token_of = order // k
-        x = jnp.where(valid, tokens[token_of], 0)
+        order, rows = _held_rows(
+            topi, held=w_in.shape[0], first_expert=first_expert)
+    y, token_of = _grouped_chunk(
+        tokens, gates, order, rows, w_gate, w_in, w_out, chunk=row_bound,
+        index=0)
+    with jax.named_scope("moe.combine"):
+        out = jnp.zeros(tokens.shape, jnp.float32).at[token_of].add(y)
+    return out, rows, jnp.maximum(rows.sum() - row_bound, 0)
 
-    def product(a, w):
-        return jnp.where(valid, lax.ragged_dot(a, w, sizes), 0)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunk_loop(chunk, tokens, gates, order, rows, w_gate, w_in, w_out):
+    """:func:`_grouped_chunk` after :func:`_grouped_chunk` until the
+    chunks hold every routed row: (out [N, D] f32, chunks int32, how many
+    ran). ``order`` comes cut to whole chunks.
+
+    A loop of traced length has no reverse-mode rule, so the backward is
+    written out. The forward saves its inputs only. The backward is the
+    same loop over :func:`_grouped_chunk_transposed`, which computes each
+    chunk again; the operands of the stacks' gradients go into buffers of
+    every row that can come, and each stack's gradient is ONE grouped
+    product over the routed rows after the loop, as under one bound: a
+    further chunk costs its rows, not a pass over the stacks."""
+    routed = rows.sum()
+
+    def body(state):
+        index, out = state
+        y, token_of = _grouped_chunk(
+            tokens, gates, order, rows, w_gate, w_in, w_out, chunk=chunk,
+            index=index)
+        with jax.named_scope("moe.combine"):
+            return index + 1, out.at[token_of].add(y)
+
+    chunks, out = lax.while_loop(
+        lambda state: state[0] * chunk < routed, body,
+        (jnp.int32(0), jnp.zeros(tokens.shape, jnp.float32)))
+    return out, chunks
+
+
+def _chunk_loop_fwd(chunk, *operands):
+    out, chunks = _chunk_loop(chunk, *operands)
+    return (out, chunks), (operands, chunks)
+
+
+def _chunk_loop_bwd(chunk, saved, cts):
+    (tokens, gates, order, rows, *stacks), chunks = saved
+    ct, _ = cts
+    k = gates.shape[1]
+    w_gate, w_in, w_out = stacks
+
+    def body(index, carry):
+        d_tokens, d_gates, kept = carry
+        dx, d_gate, row, operands = _grouped_chunk_transposed(
+            ct, tokens, gates, order, rows, stacks, transposed,
+            chunk=chunk, index=index)
+        with jax.named_scope("moe.combine"):
+            d_tokens = d_tokens.at[row // k].add(dx)
+            d_gates = d_gates.at[row].add(d_gate)
+        with jax.named_scope("moe.experts"):
+            kept = tuple(
+                lax.dynamic_update_slice_in_dim(b, a, index * chunk, 0)
+                for b, a in zip(kept, operands))
+        return d_tokens, d_gates, kept
+
+    def stack_grad(a, d, w):
+        return jax.linear_transpose(
+            lambda w: lax.ragged_dot(a, w, rows), w)(d)[0]
 
     with jax.named_scope("moe.experts"):
-        h = nn.silu(product(x, w_gate)) * product(x, w_in)
-        y = product(h, w_out)
-    with jax.named_scope("moe.combine"):
-        weight = gates.reshape(n * k)[order][:, None]
-        y = y.astype(jnp.float32) * weight
-        out = jnp.zeros((n, d), jnp.float32).at[token_of].add(y)
-    return out, rows, overflow
+        transposed = tuple(jnp.swapaxes(w, 1, 2) for w in stacks)
+        d, f = w_out.shape[2], w_out.shape[1]
+        kept = tuple(
+            jnp.zeros((order.shape[0], width), tokens.dtype)
+            for width in (d, f, f, f, d))
+    d_tokens, d_gates, (x, h, dg, du, dy) = lax.fori_loop(
+        0, chunks, body,
+        (jnp.zeros_like(tokens), jnp.zeros(gates.size, gates.dtype), kept))
+    with jax.named_scope("moe.experts"):
+        d_stacks = (stack_grad(x, dg, w_gate), stack_grad(x, du, w_in),
+                    stack_grad(h, dy, w_out))
+        # Only the optimizer waits for the stacks' gradients, so the
+        # scheduler would hold every layer's buffers until it runs: tied
+        # to the tokens' gradient they are computed here, and the buffers
+        # are free for the layer before.
+        d_tokens, d_stacks = lax.optimization_barrier((d_tokens, d_stacks))
+    # The sort and the counts are integers: no cotangent.
+    return (d_tokens, d_gates.reshape(gates.shape), None, None, *d_stacks)
+
+
+_chunk_loop.defvjp(_chunk_loop_fwd, _chunk_loop_bwd)
+
+
+def _chunked_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
+                 first_expert: int, chunk: int):
+    """:func:`_grouped_moe` on the rows that were routed: as many chunks
+    of ``chunk`` sorted rows as hold them (:func:`_chunk_loop`), so what
+    the layer gathers, masks and scatters follows the router's load and no
+    row can be left out. Returns (out [N, D] f32, rows [held] int32, bound
+    int32 = the rows of the chunks that ran, overflow int32 = routed rows
+    past them: 0 or the loop stopped early)."""
+    n, k = topi.shape
+    held = w_in.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        order, rows = _held_rows(topi, held=held, first_expert=first_expert)
+        # Whole chunks that hold every row that can come.
+        top = -(-n * min(k, held) // chunk) * chunk
+        order = jnp.pad(order, (0, max(top - n * k, 0)))[:top]
+    out, chunks = _chunk_loop(
+        chunk, tokens, gates, order, rows, w_gate, w_in, w_out)
+    bound = chunks * chunk
+    return out, rows, bound, jnp.maximum(rows.sum() - bound, 0)
 
 
 class MoEFFN(nn.Module):
@@ -394,12 +565,14 @@ class MoEFFN(nn.Module):
         optimizer sees a zero gradient and leaves it where it is; its
         balancing update is not run). The weights are the chosen
         experts' scores, without the bias, over their sum. The grouped
-        products' row bound is the ``N * min(top_k, held)`` rows that can
-        be routed here, so no row is ever past it (``capacity_factor``
-        belongs to the engines that drop). Sows ``counters`` (rows per
-        held expert, rows past the bound, the uniform expectation per
-        expert) and, for a caller that asks, ``intermediates`` (the chosen
-        experts)."""
+        engine runs as many chunks of sorted rows as hold what the router
+        sent here this step (:func:`_chunked_moe`; a chunk is twice the
+        even share ``N * top_k * held / n_experts``), so no row is ever left
+        out (``capacity_factor`` belongs to the engines that drop). Sows
+        ``counters`` (rows per held expert, the rows of the chunks the
+        step ran, routed rows past them, the uniform expectation per
+        expert) and, for a caller that asks, ``intermediates`` (the
+        chosen experts)."""
         b, s, d = x.shape
         n, e, k = b * s, self.n_experts, self.top_k
         held = self.experts_held or e
@@ -439,12 +612,16 @@ class MoEFFN(nn.Module):
         w_gate = stack("experts_gate_kernel", d, (d, self.d_ff))
         w_in = stack("experts_in_kernel", d, (d, self.d_ff))
         w_out = stack("experts_out_kernel", self.d_ff, (self.d_ff, d))
-        out, rows, overflow = _grouped_moe(
+        out, rows, bound, overflow = _chunked_moe(
             jnp.asarray(tokens, self.dtype), topi, gates, w_gate, w_in,
             w_out, first_expert=self.first_expert,
-            row_bound=n * min(k, held),
+            # Twice what an even router sends the held experts: the step
+            # waits for the heaviest share of the layer, which is above
+            # the even one, and up to twice it goes through in one chunk.
+            chunk=-(-2 * n * k * held // e),
         )
         self.sow("counters", "moe_rows", rows)
+        self.sow("counters", "moe_rows_bound", bound)
         self.sow("counters", "moe_rows_overflowed", overflow)
         self.sow("counters", "moe_rows_uniform", jnp.float32(n * k / e))
         return jnp.asarray(out, self.dtype).reshape(b, s, d)

@@ -12,7 +12,7 @@ import pytest
 
 import reference_lfm2_moe as REF
 from dct_tpu.config import ModelConfig
-from dct_tpu.models.moe import MoEFFN, _grouped_moe
+from dct_tpu.models.moe import MoEFFN, _chunked_moe, _grouped_moe
 from dct_tpu.models.registry import (
     get_model,
     is_causal_model,
@@ -270,6 +270,203 @@ def test_rows_past_the_bound_are_counted_not_hidden(whole_layer):
         held["experts_out_kernel"], first_expert=0, row_bound=48)
     assert int(rows.sum()) == routed
     assert int(overflow) == routed - 48
+
+
+def _held_share(p, first, held):
+    return {
+        k: (v[first:first + held] if k.startswith("experts_") else v)
+        for k, v in p.items()}
+
+
+def _pulled(p, first, favoured, held=4):
+    """``p`` with a selection bias that sends EVERY token to the first
+    ``favoured`` of the held experts and leaves the other held ones to the
+    router; ``favoured`` == ``held`` is every row that can come."""
+    bias = np.zeros(16, np.float32)
+    bias[first:first + favoured] = 10.0
+    return {**_held_share(p, first, held), "expert_bias": bias}
+
+
+def _layer_loss(layer, x, cot):
+    def loss(p, x):
+        out = layer.apply({"params": p}, x)
+        return (out * cot).sum()
+    return loss
+
+
+def _reference_loss(first, cot):
+    def loss(p, x):
+        out, _, _ = REF._moe(
+            x.reshape(-1, 32), p, top_k=4, first=first, scaling=1.0,
+            routing=None)
+        return (out.reshape(cot.shape) * cot).sum()
+    return loss
+
+
+@pytest.mark.parametrize("held, favoured, low, high, bound", [
+    (4, 0, 40, 96, 192),     # the router's own load: under the even share
+    (4, 1, 97, 192, 192),    # one held expert takes every token
+    (4, 2, 193, 288, 384),
+    (4, 3, 289, 383, 384),   # every chunk, the last not full
+    (4, 4, 384, 384, 384),   # every row that can come
+    (5, 4, 384, 384, 480),   # chunks of 240: the last passes the 384 rows
+    (2, 0, 10, 96, 96),      # chunks of 96, 192 rows can come
+    (2, 2, 192, 192, 192),
+])
+def test_each_load_gives_the_reference_and_drops_no_row(
+        whole_layer, held, favoured, low, high, bound):
+    p, x = whole_layer
+    share = _pulled(p, 4, favoured, held)
+    layer = _layer(held, 4)
+    cot = np.random.default_rng(9).standard_normal(x.shape).astype(np.float32)
+    out, sown = _apply(layer, share, x)
+    counters = sown["counters"]
+    routed = int(counters["moe_rows"][0].sum())
+    chunk = -(-2 * 96 * 4 * held // 16)
+    assert low <= routed <= high
+    assert int(counters["moe_rows_bound"][0]) == bound == (
+        -(-routed // chunk) * chunk)
+    assert int(counters["moe_rows_overflowed"][0]) == 0
+    want, _, _ = REF._moe(
+        jnp.asarray(x.reshape(-1, 32)), share, top_k=4, first=4, scaling=1.0,
+        routing=None)
+    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=0, atol=TOL)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(_layer_loss(layer, x, cot), (0, 1))(share, x)
+        ref = jax.grad(_reference_loss(4, cot), (0, 1))(share, jnp.asarray(x))
+    for name in ("experts_gate_kernel", "experts_in_kernel",
+                 "experts_out_kernel"):
+        np.testing.assert_allclose(
+            got[0][name], ref[0][name], rtol=0, atol=TOL, err_msg=name)
+    # The weights' gradient reaches the router through the scores.
+    assert np.abs(ref[0]["router"]["kernel"]).max() > 10 * TOL
+    np.testing.assert_allclose(
+        got[0]["router"]["kernel"], ref[0]["router"]["kernel"], rtol=0,
+        atol=TOL)
+    np.testing.assert_allclose(got[1], ref[1], rtol=0, atol=TOL)
+    assert not np.asarray(got[0]["expert_bias"]).any()
+
+
+@pytest.mark.parametrize("chunk, favoured, bound", [
+    (384, 1, 384),   # one chunk of every row that can come
+    (100, 0, 100),   # the router's own load in one chunk of four
+    (100, 3, 300),   # three of four
+    (100, 4, 400),   # four chunks, the last past the 384 rows
+    (40, 2, 240),    # six of ten
+    (32, 4, 384),    # every row that can come, twelve chunks
+])
+def test_the_chunks_equal_the_engine_under_one_bound(
+        whole_layer, chunk, favoured, bound):
+    """The loop over chunks against the engine under the one bound the
+    layer ran under before, differentiated as it stands: the same routing,
+    gates and weights, output and every gradient."""
+    p, x = whole_layer
+    held = _pulled(p, 4, favoured)
+    _, sown = _apply(_layer(4, 4), held, x)
+    topi = sown["intermediates"]["topk"][0]
+    routed = int(sown["counters"]["moe_rows"][0].sum())
+    rng = np.random.default_rng(10)
+    cot = rng.standard_normal((96, 32)).astype(np.float32)
+    operands = (
+        jnp.asarray(x.reshape(-1, 32)),
+        jnp.asarray(rng.random((96, 4)), jnp.float32),
+        *(held[f"experts_{name}_kernel"] for name in ("gate", "in", "out")))
+
+    def chunked(*operands):
+        out, rows, ran, overflow = _chunked_moe(
+            operands[0], topi, *operands[1:], first_expert=4, chunk=chunk)
+        return (out * cot).sum(), (rows.sum(), ran, overflow)
+
+    def one_bound(*operands):
+        out, rows, overflow = _grouped_moe(
+            operands[0], topi, *operands[1:], first_expert=4, row_bound=384)
+        return (out * cot).sum(), (rows.sum(), overflow)
+
+    with jax.default_matmul_precision("highest"):
+        (got, counted), g_got = jax.value_and_grad(
+            chunked, range(5), has_aux=True)(*operands)
+        (want, _), g_want = jax.value_and_grad(
+            one_bound, range(5), has_aux=True)(*operands)
+    assert [int(c) for c in counted] == [routed, bound, 0]
+    assert bound == -(-routed // chunk) * chunk
+    assert abs(float(got) - float(want)) < TOL * max(1.0, abs(float(want)))
+    for name, a, b in zip(
+            ("tokens", "gates", "gate", "in", "out"), g_got, g_want):
+        assert np.abs(np.asarray(b)).max() > 10 * TOL, name
+        np.testing.assert_allclose(a, b, rtol=0, atol=TOL, err_msg=name)
+
+
+def test_the_backward_keeps_no_chunk_of_rows(whole_layer):
+    """What the layer saves for its backward: its inputs, and nothing
+    with a chunk's rows (it computes each chunk again)."""
+    p, x = whole_layer
+    held = _pulled(p, 4, 1, held=3)
+    chunks = {72, 144, 216, 288}  # none is a token or a weight extent
+
+    def saved_rows(fn, *args):
+        _, vjp = jax.vjp(fn, *args)
+        return {a.shape[0] for a in jax.tree.leaves(vjp)
+                if hasattr(a, "shape") and a.ndim == 2} & chunks
+
+    layer = _layer(3, 4)
+    assert saved_rows(
+        lambda p, x: layer.apply({"params": p}, x), held, x) == set()
+    # The scan sees row-bounded residuals where there are some: the
+    # engine under one bound, differentiated as it stands.
+    topi = jnp.asarray(np.random.default_rng(11).integers(0, 16, (96, 4)))
+    assert saved_rows(
+        lambda t, *w: _grouped_moe(
+            t, topi, jnp.full((96, 4), 0.25), *w, first_expert=4,
+            row_bound=288)[0],
+        jnp.asarray(x.reshape(-1, 32)), held["experts_gate_kernel"],
+        held["experts_in_kernel"], held["experts_out_kernel"]) == {288}
+
+
+def test_each_stack_has_one_gradient_product_after_the_chunks(whole_layer):
+    """Where the backward forms the stacks' gradients: no chunk's loop
+    holds a value of a stack's shape, and the three grouped products that
+    do come after it, over the rows of every chunk that can run."""
+    p, x = whole_layer
+    held = _pulled(p, 4, 1, held=3)
+    stacks = {(3, 32, 24), (3, 24, 32)}
+
+    def walk(jaxpr, in_loop, found):
+        for eqn in jaxpr.eqns:
+            shapes = {getattr(v.aval, "shape", None) for v in eqn.outvars}
+            if shapes & stacks:
+                found.append((in_loop, eqn.primitive.name, tuple(
+                    v.aval.shape for v in eqn.invars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, in_loop or eqn.primitive.name == "while", found)
+        return found
+
+    layer = _layer(3, 4)
+    found = walk(jax.make_jaxpr(jax.grad(
+        lambda p, x: layer.apply({"params": p}, x).sum()))(held, x).jaxpr,
+        False, [])
+    assert not [f for f in found if f[0]]
+    products = [f for f in found if f[1] == "ragged_dot_general"]
+    assert sorted(shapes[:2] for _, _, shapes in products) == [
+        ((288, 24), (288, 32)), ((288, 32), (288, 24)),
+        ((288, 32), (288, 24))]
+
+
+def test_the_bound_is_counted_with_the_rows(whole_layer):
+    """``moe_rows_bound`` through the trainer's flattening, two layers
+    under different loads."""
+    from dct_tpu.train.steps import counter_metrics
+
+    p, x = whole_layer
+    sown = {
+        f"block_{i}": {"moe": jax.tree.map(
+            lambda leaf: leaf[0],
+            _apply(_layer(4, 4), _pulled(p, 4, favoured), x)[1]["counters"],
+            is_leaf=lambda leaf: isinstance(leaf, tuple))}
+        for i, favoured in enumerate((1, 4))}
+    flat = counter_metrics(sown)
+    assert flat["moe_rows_bound"] == 192 + 384
+    assert flat["moe_rows_overflowed"] == 0
+    assert 97 + 384 <= flat["moe_rows"] <= 192 + 384
 
 
 def test_the_two_reference_copies_are_byte_identical():
