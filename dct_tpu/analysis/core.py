@@ -270,7 +270,7 @@ def default_root() -> str:
 #: monkeypatching ``DCT_FOO`` does not make ``DCT_FOO`` part of the
 #: platform's env contract.
 REPO_CODE_DIRS = ("dct_tpu", "jobs", "dags", "scripts")
-REPO_CODE_FILES = ("bench.py",)
+REPO_CODE_FILES = ("chip_smoke.py",)
 
 
 class Project:
@@ -566,7 +566,7 @@ def analyze(
         for f in rule.check(project):
             # Resolve the finding's file for suppression even when it
             # is not a lint target (repo-wide rules anchor findings in
-            # bench.py/.env.example/config.py regardless of CLI paths;
+            # chip_smoke.py/.env.example/config.py regardless of CLI paths;
             # a noqa there must bind under every invocation).
             ctx = project.parse_aux(f.path)
             if ctx is not None and ctx.suppressed(f.rule, f.line):

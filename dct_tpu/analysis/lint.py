@@ -12,7 +12,7 @@ Exit codes (CI contract):
 Examples::
 
     python -m dct_tpu.analysis.lint dct_tpu/
-    python -m dct_tpu.analysis.lint dct_tpu jobs dags scripts bench.py
+    python -m dct_tpu.analysis.lint dct_tpu jobs dags scripts chip_smoke.py
     python -m dct_tpu.analysis.lint --format json dct_tpu/ | jq .
     python -m dct_tpu.analysis.lint --select env-registry,event-names
     python -m dct_tpu.analysis.lint --write-baseline   # grandfather, then justify

@@ -6,9 +6,9 @@ documented ``.env.example`` names a key nobody reads, or the declared
 registry carries a dead entry. The single source of truth is
 ``ENV_REGISTRY`` in ``dct_tpu/config.py``; this rule holds all three
 surfaces equal. The scan is repo-wide (``dct_tpu``/``jobs``/``dags``/
-``scripts``/``bench.py``, tests excluded) regardless of which paths the
-CLI was pointed at, so a partial lint cannot mistake a bench-only knob
-for a dead one.
+``scripts``/``chip_smoke.py``, tests excluded) regardless of which
+paths the CLI was pointed at, so a partial lint cannot mistake a knob
+only a root-level program reads for a dead one.
 
 ``event-names`` — ``EventLog.emit(component, event, ...)`` sites must
 use (component, event) pairs documented in ``docs/OBSERVABILITY.md``'s
@@ -37,7 +37,7 @@ _ENV_TOKEN_RE = re.compile(r"DCT_[A-Z0-9_]+")
 
 def _env_mentions(text: str) -> dict[str, int]:
     """DCT_* names mentioned in free text -> first line number.
-    Wildcard mentions (``DCT_BENCH_*``, trailing underscore) are not
+    Wildcard mentions (``DCT_SERVE_*``, trailing underscore) are not
     names and are skipped."""
     out: dict[str, int] = {}
     for i, line in enumerate(text.splitlines(), start=1):

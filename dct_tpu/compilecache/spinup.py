@@ -16,9 +16,8 @@ REAL paths:
   deployed package's jitted jax scorer, in a fresh subprocess per
   measurement so in-process jit caches cannot flatter the warm number.
 
-Used by three consumers with one implementation: the bench's
-``restart_spinup`` leg, the ``compile-cache`` CI smoke
-(scripts/compile_cache_smoke.py), and the e2e tests.
+Used by two consumers with one implementation: the ``compile-cache``
+CI smoke (scripts/compile_cache_smoke.py) and the e2e tests.
 
 Run this module as a CLI for the subprocess halves::
 
@@ -76,7 +75,6 @@ def _measure_env(
         DCT_EPOCHS="1",
         DCT_BATCH_SIZE="32",
         DCT_USE_SCAN="1",
-        DCT_EPOCH_CHUNK="1",
         # Telemetry write-through: the event timestamps ARE the
         # measurement, and the crash path must not owe them a flush.
         DCT_TELEMETRY_FLUSH_S="0",

@@ -89,8 +89,8 @@ class ModelConfig:
     router_aux_weight: float = 0.01
     moe_dispatch: str = "auto"
     # 'auto' dispatch crossover: elements of the one-hot [kN, E, C]
-    # tensor past which the sorted engine is picked. Calibrate on the
-    # target chip with bench.py's scaled_moe section.
+    # tensor past which the sorted engine is picked. A guess: no cell
+    # has timed either engine on the chip (ROADMAP D3, D6).
     moe_auto_threshold: int = 1 << 21
     # 1 = switch (top-1); 2+ = GShard-style top-k with normalized gates.
     router_top_k: int = 1
@@ -260,15 +260,6 @@ class TrainConfig:
     # EarlyStopping with the ModelCheckpoint the reference configures).
     early_stop_patience: int = 0
     early_stop_min_delta: float = 0.0
-    # Epochs fused into ONE XLA dispatch (scan path only; 1 = parity):
-    # K epochs pay one host round trip instead of K.
-    # Trade-offs, all chunk-granular: deploy checkpoints and
-    # resume snapshots land at chunk boundaries (per-epoch metrics are
-    # still returned and logged), early stopping is evaluated per epoch
-    # but can only take effect between chunks, and up to 2K epochs of
-    # batches are resident in HBM at once (the current span plus the
-    # span-ahead prefetch).
-    epoch_chunk: int = 1
     # Spans kept in flight ahead of the host loop (scan path only).
     # 1 (default): the next span's host assembly + H2D staging runs on a
     # worker thread while the current span computes, AND the previous
@@ -316,7 +307,6 @@ class TrainConfig:
         c.early_stop_min_delta = _env(
             "DCT_EARLY_STOP_MIN_DELTA", c.early_stop_min_delta, float
         )
-        c.epoch_chunk = _env("DCT_EPOCH_CHUNK", c.epoch_chunk, int)
         c.prefetch_spans = _env("DCT_PREFETCH_SPANS", c.prefetch_spans, int)
         return c
 
@@ -841,8 +831,7 @@ class ServingConfig:
     # parse_envelope_array); non-rectangular payloads fall back to
     # json.loads transparently. Off = always json.loads.
     fast_parse: bool = True
-    # Load-generation bench (serving/loadgen.py + bench.py serving_load
-    # stanza): open-loop target qps (0 = closed loop), per-level wall
+    # Load generator (serving/loadgen.py): open-loop target qps (0 = closed loop), per-level wall
     # budget, requests per concurrency level, and the sweep's levels.
     loadgen_qps: float = 0.0
     loadgen_duration_s: float = 2.0
@@ -1294,7 +1283,6 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_GRAD_ACCUM_STEPS": "microbatches summed per optimizer update",
     "DCT_EARLY_STOP_PATIENCE": "epochs without val_loss improvement (0 = off)",
     "DCT_EARLY_STOP_MIN_DELTA": "improvement threshold for early stop",
-    "DCT_EPOCH_CHUNK": "epochs fused into one XLA dispatch",
     "DCT_PREFETCH_SPANS": "1 = pipelined span consume; 0 = strict serial",
     # --- mesh / distributed topology -------------------------------
     "DCT_MESH_DATA": "mesh data axis size (-1 = remaining devices)",
@@ -1512,31 +1500,4 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_COMPILE_CACHE_WARM_SIZES": "packaging scorer pre-compile batch sizes",
     "DCT_NATIVE": "enable the native (C++) extension build",
     "DCT_CXX": "C++ compiler for the native build",
-    # --- bench -----------------------------------------------------
-    "DCT_BENCH_ROWS": "bench dataset size (rows)",
-    "DCT_BENCH_EPOCHS": "bench trainer-loop epochs",
-    "DCT_BENCH_TORCH_EPOCHS": "bench torch-reference epochs",
-    "DCT_BENCH_FUSE": "bench fused-step legs on/off",
-    "DCT_BENCH_SCALED": "bench scaled-transformer leg on/off",
-    "DCT_BENCH_SPINUP": "bench restart_spinup (cold/warm relaunch) leg on/off",
-    "DCT_BENCH_FRESHNESS": "bench cycle_freshness (serial vs loop) leg on/off",
-    "DCT_BENCH_SHARDED": "bench model_sharded (sharded vs DP) leg on/off",
-    "DCT_BENCH_TENANTS": "bench multi_tenant (2-tenant scheduler) leg on/off",
-    "DCT_BENCH_MPMD": "bench mpmd_pipeline (MPMD-1F1B vs SPMD-GPipe bubble) leg on/off",
-    "DCT_BENCH_ROOFLINE": "bench roofline (local cost-model MFU) leg on/off",
-    "DCT_BENCH_ELASTIC": "bench elastic_serving (overload controls A/B) leg on/off",
-    "DCT_BENCH_TELEMETRY": "bench telemetry_history (detect latency + publish overhead) leg on/off",
-    "DCT_BENCH_STREAM": "bench stream_ingest (events/s + lag p99 vs polling) leg on/off",
-    "DCT_BENCH_LOWPREC": "bench low_precision (int8/bf16 serving + bf16 rules A/B) leg on/off",
-    "DCT_BENCH_DEADLINE": "bench wall-clock deadline (s); legs self-gate",
-    "DCT_BENCH_PARTIAL": "path for the partial-results stash",
-    "DCT_VAL_PARITY_EPOCHS": "val-loss parity leg epoch budget",
-    "DCT_SCALED_DMODEL": "scaled bench leg: d_model",
-    "DCT_SCALED_LAYERS": "scaled bench leg: layers",
-    "DCT_SCALED_HEADS": "scaled bench leg: heads",
-    "DCT_SCALED_DFF": "scaled bench leg: d_ff",
-    "DCT_SCALED_SEQ": "scaled bench leg: sequence length",
-    "DCT_SCALED_BATCH": "scaled bench leg: per-device batch",
-    "DCT_SCALED_WINDOW": "scaled bench leg: attention window",
-    "DCT_SCALED_SCAN": "scaled bench leg: scan path on/off",
 }

@@ -28,12 +28,11 @@ architecture, promotion semantics, and failure modes.
 
 from dct_tpu.continuous.evaluator import PromotionEvaluator, package_checkpoint
 from dct_tpu.continuous.ingest import IngestWatcher
-from dct_tpu.continuous.loop import AlwaysOnLoop, run_episodic_cycle
+from dct_tpu.continuous.loop import AlwaysOnLoop
 
 __all__ = [
     "AlwaysOnLoop",
     "IngestWatcher",
     "PromotionEvaluator",
     "package_checkpoint",
-    "run_episodic_cycle",
 ]

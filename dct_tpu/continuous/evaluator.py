@@ -18,8 +18,7 @@ atomically-published ``weather-best-*.ckpt``), and for every NEW best:
 Freshness accounting: a promoted package's meta carries
 ``data_generation``/``data_arrival_ts`` (stamped by the trainer from
 ``etl_state.json``), so each ``loop.promoted`` event reports
-``freshness_s`` = promote wall time - data arrival — the number the
-``cycle_freshness`` bench leg aggregates.
+``freshness_s`` = promote wall time - data arrival.
 """
 
 from __future__ import annotations
@@ -75,7 +74,7 @@ def package_checkpoint(
 class PromotionEvaluator:
     """Watches the deploy tier and promotes mid-run.
 
-    ``check_once`` is the unit (poll loops, the episodic comparator and
+    ``check_once`` is the unit (poll loops and
     tests all share it); :meth:`run` is the thread body. State is one
     (name, mtime_ns, size) triple — the last checkpoint considered —
     so a gate-held checkpoint is not retried until a NEW best lands.

@@ -19,10 +19,7 @@ atomically-published artifacts:
   live champion — promotion happens MID-RUN, overlapped with training.
 
 Freshness: data-arrival -> deployed-model latency is bounded by stage
-latencies (round + gate + rollout), not by the episodic cycle sum. The
-``cycle_freshness`` bench leg measures both against
-:func:`run_episodic_cycle`, the serial comparator built from the SAME
-primitives run strictly in sequence.
+latencies (round + gate + rollout), not by the episodic cycle sum.
 
 Shutdown: ``request_stop()`` (or SIGTERM via ``jobs/loop.py``) finishes
 the round in flight — mid-fit, the trainer's own PreemptionGuard turns
@@ -555,54 +552,3 @@ class AlwaysOnLoop:
                 round(sum(sps) / len(sps), 1) if sps else None
             ),
         }
-
-
-# ----------------------------------------------------------------------
-# The episodic comparator: the SAME primitives, strictly serial.
-
-
-def run_episodic_cycle(
-    cfg: RunConfig,
-    *,
-    client,
-    evaluator,
-    clock=time.time,
-) -> dict:
-    """One serial ETL -> train -> gate -> deploy cycle — the reference's
-    episodic DAG semantics built from the loop's own primitives, so the
-    ``cycle_freshness`` bench compares architectures, not
-    implementations. ``evaluator`` is a
-    :class:`~dct_tpu.continuous.evaluator.PromotionEvaluator` reused
-    across cycles (its seen-checkpoint state and package counter
-    persist, exactly like the loop's)."""
-    from dct_tpu.etl.preprocess import preprocess_csv_to_parquet, read_etl_state
-    from dct_tpu.train.trainer import Trainer
-
-    t0 = clock()
-    preprocess_csv_to_parquet(
-        cfg.data.raw_csv, cfg.data.processed_dir, incremental=True
-    )
-    t_etl = clock()
-    result = Trainer(_round_config(cfg, cfg.loop.epochs_per_round)).fit()
-    t_train = clock()
-    promo = evaluator.check_once()
-    t_done = clock()
-    state = read_etl_state(cfg.data.processed_dir)
-    arrival = state.get("arrival_ts")
-    cats = (result.goodput or {}).get("categories") or {}
-    return {
-        "cycle_s": round(t_done - t0, 4),
-        "etl_s": round(t_etl - t0, 4),
-        "train_s": round(t_train - t_etl, 4),
-        "deploy_s": round(t_done - t_train, 4),
-        "train_step_wall_s": float(cats.get("train_step", 0.0)),
-        "train_samples_per_sec_per_chip":
-            result.steady_samples_per_sec_per_chip,
-        "promoted": promo is not None,
-        "generation": state.get("generation"),
-        "freshness_s": (
-            round(t_done - arrival, 4)
-            if promo is not None and arrival else None
-        ),
-        "val_loss": result.val_loss,
-    }

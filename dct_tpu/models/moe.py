@@ -477,8 +477,7 @@ class MoEFFN(nn.Module):
             # ``auto_threshold`` (elements of that tensor) the sort-based
             # engine wins on both memory and time. Default ~2^21; set
             # DCT_MOE_AUTO_THRESHOLD (-> ModelConfig.moe_auto_threshold)
-            # once measured on the target chip (bench.py's scaled_moe
-            # section gives the crossover data).
+            # once measured on the target chip (no cell has, ROADMAP D6).
             engine = (
                 "sorted"
                 if k * n * e * capacity >= self.auto_threshold

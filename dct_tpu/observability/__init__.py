@@ -33,8 +33,6 @@ plane built on four pillars:
   time aggregation into fleet totals + per-``proc`` series, and SLO
   burn-rate monitoring (``slo.alert`` events, ``dct_slo_*`` gauges)
   over the aggregated view.
-- :mod:`report` — the bench-trajectory regression sentinel
-  (``python -m dct_tpu.observability.report <records...>``).
 
 Everything here is dependency-free, failure-isolated (a full disk or an
 unwritable dir degrades telemetry to a no-op, never fails training), and

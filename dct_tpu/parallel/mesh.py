@@ -221,11 +221,3 @@ def make_global_epoch(mesh: Mesh, *host_arrays):
     """[S, B_local, ...] per-process stacks -> global [S, B, ...] arrays
     sharded over ``data`` on the batch dim."""
     return _make_global(stacked_batch_sharding(mesh), host_arrays)
-
-
-def make_global_epoch_chunk(mesh: Mesh, *host_arrays):
-    """[K, S, B_local, ...] per-process epoch-chunk stacks -> global
-    [K, S, B, ...] arrays sharded over ``data`` on the batch dim
-    (epoch and step dims replicated) — the multi-epoch dispatch's input
-    layout (train.steps.make_multi_epoch_train_eval_step)."""
-    return _make_global(NamedSharding(mesh, P(None, None, "data")), host_arrays)

@@ -108,9 +108,9 @@ def gpipe_tick_apply(
     the stacked params/activations are sharded ``P('pipe', ...)`` the
     partitioner turns the vmap into per-shard stage compute and the roll
     into the neighbor collective-permute, with no shard_map involved.
-    The SPMD-GPipe comparator for the MPMD runner (tests/test_mpmd.py,
-    bench.py ``mpmd_pipeline``); the tick structure — and therefore the
-    bubble — is the same either way.
+    The SPMD-GPipe comparator for the MPMD runner (tests/test_mpmd.py);
+    the tick structure — and therefore the bubble — is the same either
+    way.
 
     Differentiable: ``jax.grad`` through the scan+roll yields the
     reverse tick schedule, exactly as with ppermute.

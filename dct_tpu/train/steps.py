@@ -309,12 +309,19 @@ def _epoch_donate(donate: bool, donate_stacks: bool) -> tuple:
     state; 1-3 are the single-use epoch/span stacks (donating them frees
     a full span of HBM before activations peak). The validation stacks
     (4-6) are NEVER donated — they are reused every span. Callers that
-    re-dispatch the same stacks (the bench's timed repeats) must keep
-    donate_stacks=False or their second call reads donated buffers."""
+    re-dispatch the same stacks must keep donate_stacks=False or their
+    second call reads donated buffers."""
     nums = (0,) if donate else ()
     if donate_stacks:
         nums = nums + (1, 2, 3)
     return nums
+
+
+#: The fused epoch program's key in the AOT store, the goodput ledger's
+#: dispatch accounting and the ``trainer.dispatch`` spans. The benchmark
+#: reads ``aot_store.executables[EPOCH_PROGRAM_KEY]`` by this literal and
+#: finds the program on the device's timeline as ``jit_epoch_fused``.
+EPOCH_PROGRAM_KEY = "scan_k1"
 
 
 def make_epoch_train_eval_step(donate: bool = True, accum_steps: int = 1,
@@ -344,56 +351,6 @@ def make_epoch_train_eval_step(donate: bool = True, accum_steps: int = 1,
 
     donate_argnums = _epoch_donate(donate, donate_stacks)
     return jax.jit(epoch_fused, donate_argnums=donate_argnums)
-
-
-def make_multi_epoch_train_eval_step(donate: bool = True,
-                                     accum_steps: int = 1,
-                                     donate_stacks: bool = False,
-                                     with_grad_norms: bool = False):
-    """K training epochs, each followed by a full validation pass, as ONE
-    XLA program — an outer ``lax.scan`` over epochs of the fused
-    epoch-train+eval body. Numerically identical to K sequential calls of
-    make_epoch_train_eval_step (same scan order, same rng folding via the
-    step counter), but one host dispatch where K sequential calls pay K
-    — what ``TrainConfig.epoch_chunk`` selects.
-
-    Args are the per-epoch stacks with a leading epoch dim:
-    xs/ys/ws: [K, S, B, ...]; the validation stacks [S_v, B, ...] are
-    shared (fixed order) across epochs and NOT donated.
-
-    Returns (state, losses[K, S], val_sums = 6-tuple of [K] arrays, then
-    with ``with_grad_norms=True`` the grad global norms [K, S], and last
-    the model's sown counters per epoch [K, ...]).
-    The sums come back as a TUPLE (the scan stacks each leaf separately)
-    rather than one jnp.stack'd [K, 6] array, so every sum keeps its own
-    dtype — a single f32 stack would silently coerce any future integer
-    count leaf, and hosts that want exactness can upcast each leaf to
-    float64 after device_get (ADVICE r4). Today all six are f32 weighted
-    sums by design (fractional sample weights), exact for integral
-    weights up to 2^24 per epoch — the k == 1 fused path shares that
-    bound, it is an accumulation property, not a stacking one.
-    """
-
-    def multi_epoch(state: TrainState, xs, ys, ws, vxs, vys, vws):
-        def epoch_body(st, stacks):
-            exs, eys, ews = stacks
-            st, losses, gnorms, counters = _epoch_train_scan(
-                st, exs, eys, ews, accum_steps
-            )
-            sums = _epoch_eval_scan(st, vxs, vys, vws)
-            return st, (losses, gnorms, sums, counters)
-
-        state, (losses, gnorms, val_sums, counters) = jax.lax.scan(
-            epoch_body, state, (xs, ys, ws)
-        )
-        out = (state, losses, val_sums)
-        if with_grad_norms:
-            out += (gnorms,)
-        return out + (counters,)
-
-    return jax.jit(
-        multi_epoch, donate_argnums=_epoch_donate(donate, donate_stacks)
-    )
 
 
 def make_eval_step():

@@ -89,19 +89,6 @@ class Profiler:
             self._active = False
             _SESSION_LOCK.release()
 
-    def maybe_start_span(self, epoch: int, k: int) -> None:
-        """Span form for epoch-chunked loops: the target epoch fires the
-        trace if it falls anywhere in [epoch, epoch + k) — with K epochs
-        per dispatch the loop never visits it exactly (the trace then
-        covers the whole chunk's dispatch; the target's timeline is
-        inside it)."""
-        if epoch <= self.epoch < epoch + k:
-            self.maybe_start(self.epoch)
-
-    def maybe_stop_span(self, epoch: int, k: int) -> None:
-        if epoch <= self.epoch < epoch + k:
-            self.maybe_stop(self.epoch)
-
     def close(self) -> None:
         """Stop tracing unconditionally (crash-path hygiene: an abandoned
         trace session would corrupt the output directory)."""

@@ -2,8 +2,8 @@
 """Cold→warm restart smoke for the compile cache (tier1.yml job).
 
 Runs the REAL supervised relaunch path twice on CPU — compile cache
-off (cold control) then armed (warm) — over the same crash drill the
-``restart_spinup`` bench leg uses, and gates:
+off (cold control) then armed (warm) — over the crash drill of
+``dct_tpu/compilecache/spinup.py``, and gates:
 
 1. the healed warm attempt resolved its fused program from the AOT
    store (``compile.window`` cache label == ``hit``);

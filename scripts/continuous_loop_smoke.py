@@ -87,8 +87,6 @@ def main() -> int:
         DCT_COMPILE_CACHE="on",
         JAX_COMPILATION_CACHE_DIR=os.path.join(work, "xla_cache"),
         # Keep supervised rounds snappy on the CI box.
-        DCT_EPOCH_CHUNK="1",
-        DCT_BENCH_SPINUP="0",
     )
 
     # Child output goes to a FILE, not a pipe: supervised rounds log per

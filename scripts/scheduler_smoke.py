@@ -117,7 +117,6 @@ def main() -> int:
         DCT_LOOP_SOAK_S="0.1",
         DCT_LOOP_POLL_S="0.3",
         DCT_LOOP_EVAL_POLL_S="0.3",
-        DCT_BENCH_SPINUP="0",
     )
 
     # Child output to a FILE (an undrained pipe would block the session

@@ -99,8 +99,6 @@ def main() -> int:
         # Warm relaunches: the steady-state loop configuration (PR 9).
         DCT_COMPILE_CACHE="on",
         JAX_COMPILATION_CACHE_DIR=os.path.join(work, "xla_cache"),
-        DCT_EPOCH_CHUNK="1",
-        DCT_BENCH_SPINUP="0",
     )
 
     # Child output goes to a FILE, not a pipe: supervised rounds log per

@@ -124,8 +124,6 @@ def main() -> int:
         DCT_LOOP_POLL_S="0.3",
         DCT_LOOP_EVAL_POLL_S="0.3",
         DCT_LOOP_MAX_WALL_S=str(int(WAIT_S)),
-        DCT_EPOCH_CHUNK="1",
-        DCT_BENCH_SPINUP="0",
     )
 
     # Child output to a FILE, not a pipe (see continuous_loop_smoke.py).

@@ -538,10 +538,10 @@ class TestSpanSync:
         assert len(found) == 1 and "jax.device_get" in found[0].message
 
     def test_trainer_region_markers_present(self):
-        # The real trainer carries the markers this rule enforces — if a
-        # refactor drops them, the invariant silently lapses.
+        # The trainer's epoch loop carries the markers this rule enforces
+        # — if a refactor drops them, the invariant silently lapses.
         root = core.default_root()
-        src = open(os.path.join(root, "dct_tpu/train/trainer.py")).read()
+        src = open(os.path.join(root, "dct_tpu/train/epoch_loop.py")).read()
         assert core.REGION_BEGIN_RE.search(src)
         assert core.REGION_END_RE.search(src)
 
@@ -708,7 +708,7 @@ class TestEnvRegistry:
         files = {
             ".env.example": (
                 "# DCT_ALPHA=1\n"
-                "# see DCT_BENCH_* in bench.py for the bench knobs\n"
+                "# see DCT_BENCH_* for the bench knobs\n"
             ),
         }
         assert not run_rule(tmp_path, files, "env-registry")
@@ -861,25 +861,25 @@ class TestSuppressions:
 
     def test_noqa_binds_in_non_target_files(self, tmp_path):
         # Repo-wide rules anchor findings in files outside the lint
-        # targets (bench.py); a noqa there must hold under the default
-        # `lint dct_tpu/` invocation too, not only when bench.py is
-        # itself a target.
+        # targets (chip_smoke.py); a noqa there must hold under the
+        # default `lint dct_tpu/` invocation too, not only when
+        # chip_smoke.py is itself a target.
         files = {
-            "bench.py": (
+            "chip_smoke.py": (
                 "import os\n"
                 "K = os.environ.get('DCT_UNREGISTERED')  "
-                "# dct: noqa[env-registry] — fixture: bench-local knob\n"
+                "# dct: noqa[env-registry] — fixture: smoke-local knob\n"
             )
         }
         assert not run_rule(tmp_path, files, "env-registry")
         # And without the noqa the same setup does flag.
         files_bad = {
-            "bench.py": (
+            "chip_smoke.py": (
                 "import os\nK = os.environ.get('DCT_UNREGISTERED')\n"
             )
         }
         found = run_rule(tmp_path, files_bad, "env-registry")
-        assert len(found) == 1 and found[0].path == "bench.py"
+        assert len(found) == 1 and found[0].path == "chip_smoke.py"
 
 
 # ----------------------------------------------------------------------

@@ -343,29 +343,6 @@ def test_inspect_compile_section_counts_cache_states():
     assert "hit 2 / miss 1" in report
 
 
-def test_sentinel_flags_warm_spinup_regressions(tmp_path):
-    from dct_tpu.observability import report as rpt
-
-    def rec(path, step_s, score_s):
-        with open(path, "w") as f:
-            json.dump({"parsed": {
-                "metric": "m", "value": 100.0,
-                "restart_spinup": {
-                    "warm_step_s": step_s, "warm_score_s": score_s,
-                },
-            }}, f)
-
-    rec(tmp_path / "BENCH_r01.json", 4.0, 0.8)
-    rec(tmp_path / "BENCH_r02.json", 6.0, 0.9)  # step +50%, score +12.5%
-    rounds = [
-        rpt.load_round(str(tmp_path / f"BENCH_r0{i}.json")) for i in (1, 2)
-    ]
-    findings = rpt.compare_rounds(rounds)
-    flagged = {f["series"] for f in findings if f["kind"] == "regression"}
-    assert "warm_step_s" in flagged       # > 25% cold-start rise flags
-    assert "warm_score_s" not in flagged  # 12.5% stays under threshold
-
-
 # ======================================================================
 # trainer integration: bit-identity + labels (the acceptance core)
 

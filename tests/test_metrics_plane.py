@@ -978,46 +978,6 @@ def test_inspect_surfaces_bench_mfu(tmp_path):
     assert _bench_mfu_lines(None)[-1].startswith("  (no BENCH")
 
 
-def test_report_sentinel_flags_drops_and_unparsable(tmp_path):
-    from dct_tpu.observability import report as rpt
-
-    def rec(path, value, trainer, p50, metric="m"):
-        with open(path, "w") as f:
-            json.dump({"parsed": {
-                "metric": metric, "value": value,
-                "trainer_loop_samples_per_sec_per_chip": trainer,
-                "serving": {"single_row": {"numpy_p50_ms": p50}},
-            }}, f)
-
-    rec(tmp_path / "BENCH_r01.json", 1000.0, 900.0, 0.02)
-    rec(tmp_path / "BENCH_r02.json", 800.0, 910.0, 0.03)  # -20% + p50 +50%
-    with open(tmp_path / "BENCH_r03.json", "w") as f:
-        json.dump({"parsed": None}, f)
-    rounds = [
-        rpt.load_round(str(tmp_path / f"BENCH_r0{i}.json"))
-        for i in (1, 2, 3)
-    ]
-    findings = rpt.compare_rounds(rounds)
-    kinds = {(f["kind"], f.get("series")) for f in findings}
-    assert ("regression", "headline") in kinds
-    assert ("regression", "serving_p50_ms") in kinds
-    assert ("unparsable", None) in kinds
-    # Headline metric renamed between rounds -> not comparable.
-    rec(tmp_path / "BENCH_r04.json", 100.0, 910.0, 0.03, metric="other")
-    rounds = [
-        rpt.load_round(str(tmp_path / "BENCH_r02.json")),
-        rpt.load_round(str(tmp_path / "BENCH_r04.json")),
-    ]
-    findings = rpt.compare_rounds(rounds)
-    assert not any(
-        f.get("series") == "headline" for f in findings
-    )
-    # CLI: strict exits 1 on regressions, default exits 0.
-    argv = [str(tmp_path / "BENCH_r01.json"), str(tmp_path / "BENCH_r02.json")]
-    assert rpt.main(argv) == 0
-    assert rpt.main(argv + ["--strict"]) == 1
-
-
 # ======================================================================
 # env-contract sanity
 

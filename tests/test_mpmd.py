@@ -192,21 +192,19 @@ def test_measured_bubble_recovers_analytic_on_ideal_walls():
         mpmd.measured_bubble(1.0, 2.0, 8, 8)
 
 
-def test_gpipe_measured_vs_analytic_bubble_over_ledger():
-    """Satellite 2: the documented ``(P-1)/(M+P-1)`` fraction, asserted
-    against a MEASUREMENT of the real GPipe program over the goodput
-    ledger — step wall is affine in M at fixed microbatch size, and the
-    intercept fraction (slope method) must recover the analytic bubble.
-    Chunky stage compute so scheduling noise stays inside the band."""
-    import time
-
+def _gpipe_walls_over_ledger(p: int, m: int):
+    """Run the real GPipe program at ``m`` and ``2 m`` microbatches of a
+    fixed size under a goodput ledger: one compile dispatch and three
+    timed ones each. Returns ``(ledger, t_small, t_large)``, the best
+    timed wall of each. Chunky stage compute so scheduling noise stays
+    small beside it."""
     from dct_tpu.observability.goodput import GoodputLedger
     from dct_tpu.parallel.pipeline import (
         gpipe_tick_apply,
         stack_stage_params,
     )
 
-    d, p = 256, 4
+    d = 256
     rng = np.random.default_rng(0)
     stacked = stack_stage_params([
         {"w": jnp.asarray(rng.standard_normal((d, d)) * 0.1, jnp.float32)}
@@ -223,34 +221,59 @@ def test_gpipe_measured_vs_analytic_bubble_over_ledger():
     ledger = GoodputLedger()
     ledger.start()
 
-    def timed(m: int) -> float:
+    def timed(n_mb: int) -> float:
         x = jnp.asarray(
-            rng.standard_normal((mb_rows * m, d)), jnp.float32
+            rng.standard_normal((mb_rows * n_mb, d)), jnp.float32
         )
         f = jax.jit(
             lambda pp, xx: gpipe_tick_apply(
-                stage_fn, pp, xx, n_microbatches=m
+                stage_fn, pp, xx, n_microbatches=n_mb
             )
         )
-        with ledger.dispatch("train_step", key=f"gpipe_m{m}"):
+        with ledger.dispatch("train_step", key=f"gpipe_m{n_mb}"):
             jax.block_until_ready(f(stacked, x))  # compile window
         best = None
         for _ in range(3):
             t0 = ledger.clock()
-            with ledger.dispatch("train_step", key=f"gpipe_m{m}"):
+            with ledger.dispatch("train_step", key=f"gpipe_m{n_mb}"):
                 jax.block_until_ready(f(stacked, x))
             dt = ledger.clock() - t0
             best = dt if best is None or dt < best else best
         return best
 
-    m = 4
-    t1, t2 = timed(m), timed(2 * m)
-    measured = mpmd.measured_bubble(t1, t2, m, 2 * m)
-    analytic = mpmd.analytic_bubble(p, m)  # 0.429
-    # The compile dispatches billed to `compile`, the timed ones to
-    # train_step — the ledger carries the windows the measurement used.
+    return ledger, timed(m), timed(2 * m)
+
+
+def test_gpipe_dispatches_bill_the_ledger():
+    """Satellite 2, the half that does not depend on how fast the
+    machine is: each program's first dispatch bills to ``compile``, the
+    timed ones to ``train_step`` — the ledger carries the windows the
+    bubble measurement uses — and the slope method stays a fraction
+    whatever walls it is given."""
+    p, m = 4, 4
+    ledger, t1, t2 = _gpipe_walls_over_ledger(p, m)
     assert ledger.seconds["compile"] > 0
     assert ledger.seconds["train_step"] >= t1 + t2
+    assert set(ledger.dispatch_stats) == {"gpipe_m4", "gpipe_m8"}
+    assert 0.0 <= mpmd.measured_bubble(t1, t2, m, 2 * m) <= 1.0
+    # On the ideal pipeline's walls it is the documented fraction.
+    assert mpmd.measured_bubble(
+        m + p - 1, 2 * m + p - 1, m, 2 * m
+    ) == pytest.approx(mpmd.analytic_bubble(p, m))
+
+
+@pytest.mark.slow
+def test_gpipe_measured_vs_analytic_bubble_over_ledger():
+    """The documented ``(P-1)/(M+P-1)`` fraction against a MEASUREMENT
+    of the real GPipe program: step wall is affine in M at fixed
+    microbatch size, and the intercept fraction (slope method) must
+    recover the analytic bubble. A ratio of two wall-clock timings, so
+    not for a loaded machine: slow-marked (ROADMAP D12), out of tier-1's
+    six-worker run where it failed in the runs of PRs 25 and 28."""
+    p, m = 4, 4
+    _, t1, t2 = _gpipe_walls_over_ledger(p, m)
+    measured = mpmd.measured_bubble(t1, t2, m, 2 * m)
+    analytic = mpmd.analytic_bubble(p, m)  # 0.429
     assert measured == pytest.approx(analytic, abs=0.15)
 
 

@@ -476,40 +476,3 @@ def test_pp_ppermute_across_processes(processed_dir, tmp_path):
     m_pp = run(2, 2, "m_pp", "r_pp")
     m_ref = run(1, 1, "m_pp_ref", "r_pp_ref")
     assert abs(m_pp["val_loss"] - m_ref["val_loss"]) < 1e-3, (m_pp, m_ref)
-
-
-@pytest.mark.slow
-def test_epoch_chunk_across_processes(processed_dir, tmp_path):
-    """Multi-epoch-per-dispatch training (DCT_EPOCH_CHUNK) SPANNING
-    processes: the [K, S, B, ...] chunk stacks assemble through
-    make_array_from_process_local_data across 2 real jax.distributed
-    procs, the K-epoch scan-of-scans program runs its collectives over
-    the process boundary, and the trajectory matches the per-epoch
-    dispatch bitwise-for-metrics (chunking is staging, not math).
-    Resume then continues from the span-boundary snapshot."""
-
-    def run(chunk, models_sub, runs_sub, *, epochs=4, resume=False):
-        return launch_training(
-            processed_dir, tmp_path, world_size=2, port=29561,
-            models_sub=models_sub, runs_sub=runs_sub,
-            env_overrides={
-                "DCT_MODEL": "weather_mlp",
-                "DCT_MESH_DATA": "-1",
-                "DCT_EPOCH_CHUNK": str(chunk),
-                "DCT_EPOCHS": str(epochs),
-                "DCT_RESUME": "1" if resume else "0",
-                "DCT_BATCH_SIZE": "8",  # global 16 across 2 procs
-            },
-        )
-
-    m_chunk = run(3, "m_ec", "r_ec")       # spans 3+1 (remainder span)
-    m_ref = run(1, "m_ec_ref", "r_ec_ref")
-    assert abs(m_chunk["val_loss"] - m_ref["val_loss"]) < 1e-6, (
-        m_chunk, m_ref,
-    )
-    assert abs(m_chunk["train_loss_epoch"] - m_ref["train_loss_epoch"]) < 1e-6
-
-    # Resume from the span-boundary snapshot extends the trajectory.
-    m_resume = run(3, "m_ec", "r_ec", epochs=2, resume=True)
-    assert np.isfinite(m_resume["val_loss"]), m_resume
-    assert m_resume["val_loss"] < m_chunk["val_loss"] + 0.1

@@ -584,32 +584,6 @@ def test_transfer_histograms_record_bytes_and_latency():
 # Trajectory sentinel.
 
 
-def _round(tmp_path, name: str, parsed: dict) -> str:
-    p = str(tmp_path / name)
-    with open(p, "w") as f:
-        json.dump({"parsed": parsed}, f)
-    return p
-
-
-def test_sentinel_program_mfu_and_transfer_series(tmp_path):
-    from dct_tpu.observability.report import compare_rounds, load_round
-
-    r1 = _round(tmp_path, "BENCH_r01.json", {
-        "metric": "m", "value": 100.0, "mfu": 0.2,
-        "roofline": {"mfu": 0.2},
-        "mpmd_pipeline": {"mpmd_transfer_wait_frac": 0.10},
-    })
-    r2 = _round(tmp_path, "BENCH_r02.json", {
-        "metric": "m", "value": 100.0, "mfu": 0.15,
-        "roofline": {"mfu": 0.15},
-        "mpmd_pipeline": {"mpmd_transfer_wait_frac": 0.20},
-    })
-    findings = compare_rounds([load_round(r1), load_round(r2)])
-    series = {f["series"] for f in findings if f["kind"] == "regression"}
-    assert "program_mfu" in series          # 25% drop > 10% threshold
-    assert "transfer_wait_frac" in series   # 2x rise > 25% threshold
-
-
 def test_inspector_roofline_section(tmp_path):
     from dct_tpu.observability.inspect import build_report
 

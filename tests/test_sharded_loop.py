@@ -213,7 +213,7 @@ def _fit(tmp_path, tag, *, mesh, processed_dir, epochs=2, resume=False,
         train=TrainConfig(
             epochs=epochs, batch_size=batch_size, lr=1e-3,
             bf16_compute=False, resume=resume, shard_opt_state=shard_opt,
-            shard_params=shard_params, epoch_chunk=1,
+            shard_params=shard_params,
         ),
         mesh=mesh,
         obs=ObservabilityConfig(
@@ -747,7 +747,7 @@ def test_sharded_resume_after_cross_process_save(
         model=ModelConfig(**TRANSFORMER),
         train=TrainConfig(
             epochs=1, batch_size=16, lr=1e-3, bf16_compute=False,
-            resume=True, epoch_chunk=1,
+            resume=True,
         ),
         mesh=MeshConfig(data=8),
         obs=ObservabilityConfig(

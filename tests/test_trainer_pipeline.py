@@ -112,11 +112,11 @@ def test_pipelined_loop_holds_two_states_at_a_dispatch(
     import gc
     import weakref
 
-    from dct_tpu.train import trainer as trainer_mod
+    from dct_tpu.train import fit_setup as setup_mod
 
     order: list = []
     states: list = []
-    real = trainer_mod.make_epoch_train_eval_step
+    real = setup_mod.make_epoch_train_eval_step
 
     def counting(**kw):
         fused = real(**kw)
@@ -132,7 +132,7 @@ def test_pipelined_loop_holds_two_states_at_a_dispatch(
 
         return epoch_fused
 
-    monkeypatch.setattr(trainer_mod, "make_epoch_train_eval_step", counting)
+    monkeypatch.setattr(setup_mod, "make_epoch_train_eval_step", counting)
 
     class Recording(LocalTracking):
         def log_metrics(self, metrics, step=None):
@@ -165,16 +165,6 @@ def test_pipelined_loop_holds_two_states_at_a_dispatch(
         if e:
             assert kinds.index(("bookkeep", e - 1)) < kinds.index(
                 ("dispatch", e + 1))
-
-
-def test_pipelined_matches_serial_with_epoch_chunk(processed_dir, tmp_path):
-    _, r1 = _fit(
-        processed_dir, tmp_path, "ec_pf1", epoch_chunk=2, prefetch_spans=1
-    )
-    _, r0 = _fit(
-        processed_dir, tmp_path, "ec_pf0", epoch_chunk=2, prefetch_spans=0
-    )
-    assert r1.history == r0.history
 
 
 def test_early_stop_same_epoch_pipelined(processed_dir, tmp_path):
