@@ -54,7 +54,8 @@ BF16_EPS = 2.0 ** -8
 @dataclasses.dataclass(frozen=True)
 class Size:
     """Everything a run is sized by. ``transformer`` holds ModelConfig
-    fields; ``kernels`` rows are (name, B, H, H_kv, T, D, window);
+    fields; ``kernels`` rows are (name, B, H, H_kv, T, D, window[, value
+    width where it is not D]);
     ``fit_env`` joins every fit's environment; ``dryrun_legs`` runs
     ``__graft_entry__.dryrun_multichip`` among the multi-device legs."""
 
@@ -97,6 +98,9 @@ FULL = Size(
         # cell's whole grid; the comparator takes it 8 query heads at a
         # time (_comparator_kv_heads).
         ("lfm2_seq8192", 1, 32, 8, 8192, 64, None),
+        # moonlight_16b_ep8.fit_seq8192: latent attention, queries and
+        # keys 192 wide against values of 128 (the eighth field).
+        ("moonlight_seq8192", 1, 16, 16, 8192, 192, None, 128),
     ),
     ring_t=2048,
     ring_d=128,
@@ -119,6 +123,7 @@ TOY = Size(
         ("d64_T256_window128", 1, 2, 2, 256, 64, 128),
         ("d64_T256_window256", 1, 2, 2, 256, 64, 256),
         ("gqa_4q_2kv_d64_T256", 1, 4, 2, 256, 64, None),
+        ("d48_values32_T256", 1, 2, 2, 256, 48, None, 32),
     ),
     ring_t=512,
     ring_d=64,
@@ -568,11 +573,13 @@ def phase_kernels(cases) -> None:
 
     interpret = flash_interpret_mode()
     check(interpret is not None, "flash is off on this backend")
-    for name, b, h, h_kv, t, d, window in cases:
+    for name, b, h, h_kv, t, d, window, *rest in cases:
+        d_v = rest[0] if rest else d
         rng = np.random.default_rng(0)
         q, k, v, g = (
-            jnp.asarray(rng.standard_normal((b, heads, t, d)), jnp.bfloat16)
-            for heads in (h, h_kv, h_kv, h)
+            jnp.asarray(
+                rng.standard_normal((b, heads, t, width)), jnp.bfloat16)
+            for heads, width in ((h, d), (h_kv, d), (h_kv, d_v), (h, d_v))
         )
 
         def flash(q, k, v):
@@ -626,8 +633,8 @@ def phase_kernels(cases) -> None:
             )
         passed(
             f"kernel {name}",
-            shape=(b, h, h_kv, t, d), window=window,
-            tiles=flash_tiles(t, t, d, jnp.bfloat16),
+            shape=(b, h, h_kv, t, d, *rest), window=window,
+            tiles=flash_tiles(t, t, d, jnp.bfloat16, d_v),
             interpret=bool(interpret), comparator_heads=cq,
             rel_err={n: f"{e:.2g}" for n, e in errs.items()},
             tol=f"{KERNEL_TOL:.3g}",

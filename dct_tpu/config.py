@@ -144,12 +144,31 @@ class ModelConfig:
     # plus a selection bias. ``experts_held`` (0 = all) and
     # ``first_expert`` name the share of the experts this job holds of
     # an expert-parallel layer: the router stays ``n_experts`` wide.
+    # The routed weights are the chosen scores over (their sum +
+    # ``router_gate_eps``) times ``routed_scaling``; ``moe_shared_d_ff``
+    # > 0 adds one shared gated MLP of that width every token passes;
+    # ``bias_update_speed`` u > 0 runs the selection bias's balancing
+    # update after every optimizer step (b += u * sign(mean load - load),
+    # DeepSeek-V3's auxiliary-loss-free rule; 0 leaves the bias put).
+    # "latent_attention" layers take their widths from ``kv_lora_rank``
+    # (the compressed key/value latent), ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` (a head's queries and keys: the part without
+    # position and the rotated part, one rotated key shared by all heads)
+    # and ``v_head_dim``.
     layer_types: str = ""
     num_dense_layers: int = 0
     conv_kernel: int = 3
     moe_d_ff: int = 0
     experts_held: int = 0
     first_expert: int = 0
+    routed_scaling: float = 1.0
+    router_gate_eps: float = 1e-6
+    moe_shared_d_ff: int = 0
+    bias_update_speed: float = 0.0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @classmethod
     def from_env(cls) -> "ModelConfig":
@@ -197,6 +216,24 @@ class ModelConfig:
         c.moe_d_ff = _env("DCT_MOE_D_FF", c.moe_d_ff, int)
         c.experts_held = _env("DCT_EXPERTS_HELD", c.experts_held, int)
         c.first_expert = _env("DCT_FIRST_EXPERT", c.first_expert, int)
+        c.routed_scaling = _env("DCT_ROUTED_SCALING", c.routed_scaling, float)
+        c.router_gate_eps = _env(
+            "DCT_ROUTER_GATE_EPS", c.router_gate_eps, float
+        )
+        c.moe_shared_d_ff = _env(
+            "DCT_MOE_SHARED_D_FF", c.moe_shared_d_ff, int
+        )
+        c.bias_update_speed = _env(
+            "DCT_BIAS_UPDATE_SPEED", c.bias_update_speed, float
+        )
+        c.kv_lora_rank = _env("DCT_KV_LORA_RANK", c.kv_lora_rank, int)
+        c.qk_nope_head_dim = _env(
+            "DCT_QK_NOPE_HEAD_DIM", c.qk_nope_head_dim, int
+        )
+        c.qk_rope_head_dim = _env(
+            "DCT_QK_ROPE_HEAD_DIM", c.qk_rope_head_dim, int
+        )
+        c.v_head_dim = _env("DCT_V_HEAD_DIM", c.v_head_dim, int)
         return c
 
 
@@ -1253,12 +1290,20 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_MLP": "dense MLP: gelu | swiglu (gated, three matrices)",
     "DCT_USE_BIAS": "biases on the block's projections (default 1)",
     "DCT_QK_NORM": "RMS norm of q and k per head before the rotation",
-    "DCT_LAYER_TYPES": "hybrid family: operator per layer, comma list of full_attention | conv",
+    "DCT_LAYER_TYPES": "hybrid family: operator per layer, comma list of full_attention | latent_attention | conv",
     "DCT_NUM_DENSE_LAYERS": "hybrid family: leading layers with the dense MLP",
     "DCT_CONV_KERNEL": "gated short convolution taps (default 3)",
     "DCT_MOE_D_FF": "hybrid family: routed experts' width (0 = d_ff)",
     "DCT_EXPERTS_HELD": "experts this job holds of each layer (0 = all)",
     "DCT_FIRST_EXPERT": "first expert of the held share",
+    "DCT_ROUTED_SCALING": "hybrid family: factor on the routed experts' weights (default 1)",
+    "DCT_ROUTER_GATE_EPS": "hybrid family: term beside the chosen scores' sum (default 1e-6)",
+    "DCT_MOE_SHARED_D_FF": "hybrid family: width of the shared expert every token passes (0 = none)",
+    "DCT_BIAS_UPDATE_SPEED": "hybrid family: step of the selection bias's balancing update (0 = off)",
+    "DCT_KV_LORA_RANK": "latent attention: width of the compressed key/value latent",
+    "DCT_QK_NOPE_HEAD_DIM": "latent attention: a head's query/key width without position",
+    "DCT_QK_ROPE_HEAD_DIM": "latent attention: the rotated query/key width (one key shared by all heads)",
+    "DCT_V_HEAD_DIM": "latent attention: a head's value width",
     # --- optimization loop -----------------------------------------
     "DCT_EPOCHS": "epoch budget per cycle (reference 10)",
     "DCT_BATCH_SIZE": "per-device batch size (reference 4 per rank)",

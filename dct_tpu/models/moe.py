@@ -377,9 +377,16 @@ class MoEFFN(nn.Module):
     top_k: int = 1
     auto_threshold: int = 1 << 21
     # The 'grouped' layer (:meth:`_grouped`): the share of the experts
-    # this layer holds (0 = all of them).
+    # this layer holds (0 = all of them); what the routed weights are
+    # multiplied by and the term beside their sum; the width of the shared
+    # expert every token passes beside its routed ones (0 = none); the size
+    # of the selection bias's balancing step (0 = the bias stays put).
     experts_held: int = 0
     first_expert: int = 0
+    routed_scale: float = 1.0
+    gate_eps: float = 1e-6
+    shared_d_ff: int = 0
+    bias_update_speed: float = 0.0
 
     @nn.compact
     def __call__(self, x):  # [B, S, D] -> [B, S, D]
@@ -561,9 +568,19 @@ class MoEFFN(nn.Module):
 
         Scores: a sigmoid an expert, with a per-expert bias added for
         the SELECTION only (a parameter under ``stop_gradient``: the
-        optimizer sees a zero gradient and leaves it where it is; its
-        balancing update is not run). The weights are the chosen
-        experts' scores, without the bias, over their sum. The grouped
+        optimizer sees a zero gradient and leaves it where it is). The
+        weights are the chosen experts' scores, without the bias, over
+        their sum (+ ``gate_eps``), times ``routed_scale``. With
+        ``bias_update_speed`` u > 0 the layer sows the bias's balancing
+        step into ``param_steps`` under the parameter's name, and the
+        train step adds it after the optimizer's: ``u x sign(mean(c) -
+        c)``, ``c`` the rows this batch routed to each of the
+        ``n_experts`` (the auxiliary-loss-free rule of DeepSeek-V3: an
+        overloaded expert's bias falls, an underloaded one's rises),
+        and ``moe_bias_abs_max`` into ``counters``. With ``shared_d_ff``
+        every token also passes ONE bias-free SwiGLU of that width (the
+        model's shared experts side by side), computed whole on every
+        share: summing shares counts it once a share. The grouped
         engine runs as many chunks of sorted rows as hold what the router
         sent here this step (:func:`_chunked_moe`; a chunk is twice the
         even share ``N * top_k * held / n_experts``), so no row is ever left
@@ -594,7 +611,20 @@ class MoEFFN(nn.Module):
             )
             _, topi = lax.top_k(scores + lax.stop_gradient(bias), k)
             chosen = jnp.take_along_axis(scores, topi, axis=-1)
-            gates = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-6)
+            gates = chosen / (
+                chosen.sum(axis=-1, keepdims=True) + self.gate_eps)
+            if self.routed_scale != 1.0:
+                gates = gates * self.routed_scale
+            if self.bias_update_speed:
+                load = jnp.bincount(topi.reshape(-1), length=e).astype(
+                    jnp.float32)
+                self.sow(
+                    "param_steps", "expert_bias",
+                    self.bias_update_speed * jnp.sign(load.mean() - load),
+                    reduce_fn=lambda _, step: step, init_fn=lambda: None,
+                )
+                self.sow(
+                    "counters", "moe_bias_abs_max", jnp.abs(bias).max())
         self.sow("intermediates", "topk", topi)
 
         def stack(name, fan_in, shape):
@@ -623,7 +653,17 @@ class MoEFFN(nn.Module):
         self.sow("counters", "moe_rows_bound", bound)
         self.sow("counters", "moe_rows_overflowed", overflow)
         self.sow("counters", "moe_rows_uniform", jnp.float32(n * k / e))
-        return jnp.asarray(out, self.dtype).reshape(b, s, d)
+        out = jnp.asarray(out, self.dtype)
+        if self.shared_d_ff:
+            with jax.named_scope("moe.shared"):
+                dense = functools.partial(
+                    TorchStyleDense, dtype=self.dtype, use_bias=False)
+                t = jnp.asarray(tokens, self.dtype)
+                hidden = nn.silu(
+                    dense(self.shared_d_ff, name="shared_gate")(t)
+                ) * dense(self.shared_d_ff, name="shared_in")(t)
+                out = out + dense(d, name="shared_out")(hidden)
+        return out.reshape(b, s, d)
 
     def _sorted_sharded(self, x, expert_choice, gate_choice, wi, bi, wo,
                         bo, *, mesh, dp: int, sp: int, ep: int):

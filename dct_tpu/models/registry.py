@@ -195,16 +195,23 @@ def _build_hybrid_moe_causal(
     mesh=None,
 ):
     """The causal per-position family with a per-layer operator
-    (``layer_types``: attention or the gated short convolution) and routed
-    experts after ``num_dense_layers`` dense layers: the block of
-    ``weather_transformer_causal`` by its fields, one chip's share of the
-    experts (``experts_held``, ``first_expert``) computed without an
-    exchange."""
+    (``layer_types``: attention, latent attention or the gated short
+    convolution) and routed experts after ``num_dense_layers`` dense
+    layers: the block of ``weather_transformer_causal`` by its fields, one
+    chip's share of the experts (``experts_held``, ``first_expert``)
+    computed without an exchange."""
     del attn_fn
     moe = dict(
         d_ff=cfg.moe_d_ff or cfg.d_ff, n_experts=cfg.n_experts,
         aux_weight=0.0, dispatch="grouped", top_k=cfg.router_top_k,
         experts_held=cfg.experts_held, first_expert=cfg.first_expert,
+        routed_scale=cfg.routed_scaling, gate_eps=cfg.router_gate_eps,
+        shared_d_ff=cfg.moe_shared_d_ff,
+        bias_update_speed=cfg.bias_update_speed,
+    )
+    latent = dict(
+        kv_lora_rank=cfg.kv_lora_rank, qk_nope_dim=cfg.qk_nope_head_dim,
+        qk_rope_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
     )
     return _causal_transformer(
         cfg, input_dim=input_dim, compute_dtype=compute_dtype, mesh=mesh,
@@ -212,6 +219,7 @@ def _build_hybrid_moe_causal(
             t.strip() for t in cfg.layer_types.split(",") if t.strip()
         ),
         conv_kernel=cfg.conv_kernel,
+        latent=tuple(sorted(latent.items())),
         moe=tuple(sorted(moe.items())),
         num_dense_layers=cfg.num_dense_layers,
     )

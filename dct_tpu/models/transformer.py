@@ -151,6 +151,73 @@ class MultiHeadAttention(nn.Module):
         )(o)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention: keys and values come out of ONE
+    compressed latent a position, and a head's queries and keys are wider
+    than its values.
+
+    ``q = x W_q`` gives each head ``[q_nope | q_rope]`` (``qk_nope_dim`` +
+    ``qk_rope_dim`` wide; no query compression). ``x W_kva`` gives
+    ``[c_kv | k_rope]``: the latent, ``kv_lora_rank`` wide, and ONE rotated
+    key part of ``qk_rope_dim`` that every head shares. ``RMSnorm(c_kv)
+    W_kvb`` gives each head ``[k_nope | v]`` (``qk_nope_dim`` +
+    ``v_head_dim``). The rotation (rotate-half pairing, as
+    :func:`apply_rope`) acts on ``q_rope`` and ``k_rope`` only; ``k =
+    [k_nope | k_rope]``, and the scores are scaled by one over the square
+    root of the whole query width. ``attn_fn`` takes q and k at that width
+    and v at its own (``[B, H, T, 192]`` against ``[B, H, T, 128]`` in the
+    published layer); the output projection reads ``n_heads x
+    v_head_dim``."""
+
+    d_model: int
+    n_heads: int
+    attn_fn: object
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    dtype: jnp.dtype = jnp.float32
+    rope_theta: float = 10000.0
+    use_bias: bool = False
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, rank, d_v = self.n_heads, self.kv_lora_rank, self.v_head_dim
+        d_nope, d_rope = self.qk_nope_dim, self.qk_rope_dim
+        if d_rope % 2:
+            raise ValueError(f"rope needs an even width (got {d_rope})")
+        dense = functools.partial(
+            TorchStyleDense, dtype=self.dtype, use_bias=self.use_bias
+        )
+        with jax.named_scope("mla.project"):
+            q = dense(h * (d_nope + d_rope), name="q_proj")(x)
+            q = jnp.swapaxes(q.reshape(b, t, h, d_nope + d_rope), 1, 2)
+            latent = dense(rank + d_rope, name="kv_a_proj")(x)
+            c_kv = make_norm("rmsnorm", self.norm_eps, self.dtype, "kv_norm")(
+                latent[..., :rank]
+            )
+            kv = dense(h * (d_nope + d_v), name="kv_b_proj")(c_kv)
+            kv = jnp.swapaxes(kv.reshape(b, t, h, d_nope + d_v), 1, 2)
+            cos, sin = rope_tables(t, d_rope, self.rope_theta)
+            q = jnp.concatenate(
+                [q[..., :d_nope], apply_rope(q[..., d_nope:], cos, sin)], -1
+            )  # [B, H, T, d_nope + d_rope]
+            # One rotated key part a position, the same for every head.
+            k_rope = apply_rope(latent[:, None, :, rank:], cos, sin)
+            k = jnp.concatenate(
+                [kv[..., :d_nope],
+                 jnp.broadcast_to(k_rope, (b, h, t, d_rope))], -1
+            )
+            v = kv[..., d_nope:]  # [B, H, T, d_v]
+        with jax.named_scope("mla.attend"):
+            o = self.attn_fn(q, k, v)  # [B, H, T, d_v]
+        with jax.named_scope("mla.out"):
+            o = jnp.moveaxis(o, 1, 2).reshape(b, t, h * d_v)
+            return dense(self.d_model, name="o_proj")(o)
+
+
 class GatedShortConv(nn.Module):
     """The gated short convolution operator
     (:func:`dct_tpu.ops.shortconv.gated_short_conv`) between its two
@@ -195,7 +262,9 @@ class TransformerBlock(nn.Module):
     projections, softmax attention, gelu MLP). The fields select the
     others: ``norm`` / ``norm_eps``, ``use_bias``, ``qk_norm`` and
     ``rope_theta`` for the attention operator, ``op="conv"`` for the gated
-    short convolution in attention's place, ``mlp="swiglu"`` for the gated
+    short convolution in attention's place, ``op="latent_attention"`` for
+    :class:`LatentAttention` at the widths ``latent`` gives (its keyword
+    arguments as a tuple of pairs), ``mlp="swiglu"`` for the gated
     MLP, and ``moe`` (the keyword arguments of
     :class:`dct_tpu.models.moe.MoEFFN` as a tuple of pairs) for routed
     experts in the dense MLP's place."""
@@ -216,6 +285,7 @@ class TransformerBlock(nn.Module):
     rope_theta: float = 10000.0
     op: str = "full_attention"
     conv_kernel: int = 3
+    latent: tuple = ()
     moe: tuple | None = None
 
     @nn.compact
@@ -235,9 +305,16 @@ class TransformerBlock(nn.Module):
                 rope_theta=self.rope_theta, use_bias=self.use_bias,
                 qk_norm=self.qk_norm, norm_eps=self.norm_eps, name="attn",
             )(h)
+        elif self.op == "latent_attention":
+            h = LatentAttention(
+                self.d_model, self.n_heads, self.attn_fn, dtype=self.dtype,
+                rope_theta=self.rope_theta, use_bias=self.use_bias,
+                norm_eps=self.norm_eps, name="attn", **dict(self.latent),
+            )(h)
         else:
             raise ValueError(
-                f"layer type {self.op!r} must be 'full_attention' or 'conv'"
+                f"layer type {self.op!r} must be 'full_attention', "
+                "'latent_attention' or 'conv'"
             )
         h = nn.Dropout(rate=self.dropout, deterministic=not train)(h)
         x = x + h
@@ -442,10 +519,12 @@ class WeatherTransformer(nn.Module):
     use_bias: bool = True
     qk_norm: bool = False
     rope_theta: float = 10000.0
-    # Per-layer operator, one entry a layer ("full_attention" | "conv");
-    # empty = attention everywhere.
+    # Per-layer operator, one entry a layer ("full_attention" |
+    # "latent_attention" | "conv"); empty = attention everywhere. ``latent``
+    # holds LatentAttention's widths as a tuple of pairs.
     layer_types: tuple = ()
     conv_kernel: int = 3
+    latent: tuple = ()
     # Routed experts (MoEFFN's keyword arguments as a tuple of pairs) in
     # every layer from ``num_dense_layers`` on; the leading layers keep
     # the dense MLP of width ``d_ff``.
@@ -506,6 +585,7 @@ class WeatherTransformer(nn.Module):
                 rope_theta=self.rope_theta,
                 op=self.layer_types[i] if self.layer_types else "full_attention",
                 conv_kernel=self.conv_kernel,
+                latent=self.latent,
                 moe=self.moe if i >= self.num_dense_layers else None,
                 name=f"block_{i}",
             )(h, train)

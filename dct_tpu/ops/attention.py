@@ -191,7 +191,7 @@ def _blockwise_stats(q, k, v, *, block_size: int, causal: bool,
 
     m0 = jnp.full(q.shape[:-1], _NEG, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1], jnp.float32)
-    o0 = jnp.zeros(q.shape, jnp.float32)
+    o0 = jnp.zeros((*q.shape[:-1], v.shape[-1]), jnp.float32)
     (m, l, o), _ = lax.scan(body, (m0, l0, o0), (ks, vs, jnp.arange(n_blocks)))
     return m, l, o
 
@@ -634,6 +634,14 @@ def ring_attention(
     ring_size = mesh.shape[seq_axis]
     b, h, t, _ = q.shape
     _check_window(window, causal)
+    if v.shape[-1] != q.shape[-1]:
+        # The ring bodies size their accumulators and the skipped steps'
+        # zeros from q; the single-shard paths and the a2a engine take
+        # values of another width than the queries and keys.
+        raise ValueError(
+            f"ring attention needs values as wide as the queries "
+            f"({q.shape[-1]}), got {v.shape[-1]}; use DCT_SP_ENGINE=a2a"
+        )
     if striped and not causal:
         # Validate BEFORE any fallback: a non-causal layer misconfigured
         # with striped=True must fail at trace time, not pass the batch-1

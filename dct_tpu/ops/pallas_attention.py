@@ -110,28 +110,45 @@ def _largest_tile(n: int, cap: int) -> int:
     return max(c for c in range(128, min(n, cap) + 1, 128) if n % c == 0)
 
 
-def flash_tiles(t: int, tk: int, d: int, dtype) -> tuple[int, int]:
+def _lanes(width: int) -> int:
+    """The lanes a row of ``width`` elements takes in VMEM once it is
+    wider than one 128-lane tile: whole tiles (192 -> 256)."""
+    return width if width <= 128 else -(-width // 128) * 128
+
+
+def flash_tiles(t: int, tk: int, d: int, dtype,
+                d_v: int | None = None) -> tuple[int, int]:
     """``(block_q, block_k)`` of the three kernels from what they can see:
     the two sequence extents, the head size and the operand dtype. Pure,
     static per shape, no knob: the largest multiple of 128 that divides
     the extent, up to the measured cap; the cap halves for every doubling
-    (rounded up) of the operand row's bytes over the measured 256: the
-    streamed tiles and the f32 accumulators grow with d, the scoped VMEM
-    does not, and at the measured row 1024 x 1024 is the last pair that
-    fits."""
-    row_bytes = d * jnp.dtype(dtype).itemsize
+    of the operand row's bytes over the measured 256: the streamed tiles
+    and the f32 accumulators grow with d, the scoped VMEM does not, and
+    at the measured row 1024 x 1024 is the last pair that fits.
+
+    Where the values are not as wide as the queries and keys (``d_v``;
+    latent attention), the row is the mean of the two widths, each in
+    the whole lane tiles it takes in VMEM once over 128 (192 -> 256): a
+    kernel streams and accumulates as many value-wide operands as
+    query-wide ones. Measured at (1, 16, 8192) in bf16 (PERF.md section
+    6, PR 32): 192 and 256 against values of 128 (a row of 1.5 x the
+    measured one) compile and run fastest at 1024 x 1024 in all three
+    kernels; 256 against 256 (2 x) is refused there by 312 KB of scoped
+    VMEM in dK/dV, hence the halving at the doubling and not before it."""
+    row_bytes = (_lanes(d) + _lanes(d if d_v is None else d_v)) / 2 \
+        * jnp.dtype(dtype).itemsize
     cap = _TILE_CAP
-    while cap > 128 and _CAP_ROW_BYTES * (_TILE_CAP // cap) < row_bytes:
+    while cap > 128 and row_bytes >= 2 * _CAP_ROW_BYTES * (_TILE_CAP // cap):
         cap //= 2
     return _largest_tile(t, cap), _largest_tile(tk, cap)
 
 
-def _resolve_tiles(block_q, block_k, t: int, tk: int, d: int,
-                   dtype) -> tuple[int, int]:
+def _resolve_tiles(block_q, block_k, t: int, tk: int, d: int, dtype,
+                   d_v: int | None = None) -> tuple[int, int]:
     """The tiles a kernel runs on: explicit ``block_q`` / ``block_k``
     (tests, sweeps) win over :func:`flash_tiles`, clamped to the extents;
     a tile that does not divide its extent is refused."""
-    rule_q, rule_k = flash_tiles(t, tk, d, dtype)
+    rule_q, rule_k = flash_tiles(t, tk, d, dtype, d_v)
     block_q = rule_q if block_q is None else min(block_q, t)
     block_k = rule_k if block_k is None else min(block_k, tk)
     if t % block_q or tk % block_k:
@@ -269,6 +286,7 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
     b, h, t, d = q.shape
     h_kv = k.shape[1]
     tk = k.shape[2]  # rectangular Tq != Tk supported (striped ring blocks)
+    d_v = v.shape[3]  # values (and o) may be narrower or wider than q/k
     if causal and tk != t:
         raise ValueError(
             f"causal flash needs square Tq==Tk, got {t} vs {tk}"
@@ -289,11 +307,12 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
     # H/h_kv times through memory.
     group = h // h_kv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q, block_k = _resolve_tiles(block_q, block_k, t, tk, d, q.dtype)
+    block_q, block_k = _resolve_tiles(
+        block_q, block_k, t, tk, d, q.dtype, d_v)
     n_kv = tk // block_k
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h_kv, tk, d)
-    vf = v.reshape(b * h_kv, tk, d)
+    vf = v.reshape(b * h_kv, tk, d_v)
     kernel = functools.partial(
         _flash_fwd_kernel, block_k=block_k, n_kv=n_kv, causal=causal,
         scale=scale, with_lse=with_lse, window=window, q_offset=q_offset,
@@ -326,9 +345,9 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
     vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
     vma_kw = {"vma": vma} if vma else {}
     out_specs = [
-        pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
+        pl.BlockSpec((None, block_q, d_v), lambda bh, i, j: (bh, i, 0)),
     ]
-    out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype, **vma_kw)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype, **vma_kw)]
     if with_lse:
         out_specs.append(
             pl.BlockSpec(
@@ -346,22 +365,22 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, block_k, d), kv_index),
-            pl.BlockSpec((None, block_k, d), kv_index),
+            pl.BlockSpec((None, block_k, d_v), kv_index),
         ],
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shape if with_lse else out_shape[0],
         scratch_shapes=[
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),  # l
-            pltpu.VMEM((block_q, d), jnp.float32),  # acc
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # acc
         ],
         compiler_params=compiler_params,
         interpret=interpret,
     )(qf, kf, vf)
     if with_lse:
         o, lse = out
-        return o.reshape(b, h, t, d), lse[:, :, 0].reshape(b, h, t)
-    return out.reshape(b, h, t, d)
+        return o.reshape(b, h, t, d_v), lse[:, :, 0].reshape(b, h, t)
+    return out.reshape(b, h, t, d_v)
 
 
 def _bwd_block(q, k, v, do, lse, delta, scale, keep):
@@ -477,20 +496,21 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _bwd_operands(q, k, v, o, lse, do):
-    """Flat [bh, T, d] views of the backward's operands, the forward lse
+    """Flat [bh, T, width] views of the backward's operands (q and k at
+    the query/key width, v, o and do at the value width), the forward lse
     [B,H,T] lane-broadcast to [bh, T, LANES] (Mosaic wants >=2-D vector
     tiles; lane 0 is read back in-kernel), and the vma declaration the
     outputs need under a checked shard_map."""
     b, h, t, d = q.shape
     h_kv = k.shape[1]
-    flat = lambda a: a.reshape(b * h, t, d)
+    flat = lambda a: a.reshape(b * h, t, a.shape[3])
     lsef = jnp.broadcast_to(
         lse.reshape(b * h, t, 1), (b * h, t, _STATS_LANES)
     )
     vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v)))
     return (
-        flat(q), k.reshape(b * h_kv, t, d), v.reshape(b * h_kv, t, d),
-        flat(o), flat(do), lsef,
+        flat(q), k.reshape(b * h_kv, t, d),
+        v.reshape(b * h_kv, t, v.shape[3]), flat(o), flat(do), lsef,
     ), ({"vma": vma} if vma else {})
 
 
@@ -504,9 +524,11 @@ def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
     head count."""
     b, h, t, d = q.shape
     h_kv = k.shape[1]
+    d_v = v.shape[3]
     group = h // h_kv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q, block_k = _resolve_tiles(block_q, block_k, t, t, d, q.dtype)
+    block_q, block_k = _resolve_tiles(
+        block_q, block_k, t, t, d, q.dtype, d_v)
     n_q = t // block_q
     operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
 
@@ -530,7 +552,9 @@ def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
         return (q_row(bh, i), qi, 0)
 
     q_spec = pl.BlockSpec((None, block_q, d), q_index)
-    kv_spec = pl.BlockSpec((None, block_k, d), lambda bh, j, i: (bh, j, 0))
+    o_spec = pl.BlockSpec((None, block_q, d_v), q_index)
+    k_spec = pl.BlockSpec((None, block_k, d), lambda bh, j, i: (bh, j, 0))
+    v_spec = pl.BlockSpec((None, block_k, d_v), lambda bh, j, i: (bh, j, 0))
     lse_spec = pl.BlockSpec((None, block_q, _STATS_LANES), q_index)
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -538,20 +562,20 @@ def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
             causal=causal, scale=scale, window=window, group=group,
         ),
         grid=(b * h_kv, t // block_k, group * n_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h_kv, t, d), k.dtype, **vma_kw),
-            jax.ShapeDtypeStruct((b * h_kv, t, d), v.dtype, **vma_kw),
+            jax.ShapeDtypeStruct((b * h_kv, t, d_v), v.dtype, **vma_kw),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),  # dk accumulator
-            pltpu.VMEM((block_k, d), jnp.float32),  # dv accumulator
+            pltpu.VMEM((block_k, d_v), jnp.float32),  # dv accumulator
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
     )(*operands)
-    return dk.reshape(b, h_kv, t, d), dv.reshape(b, h_kv, t, d)
+    return dk.reshape(b, h_kv, t, d), dv.reshape(b, h_kv, t, d_v)
 
 
 def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
@@ -562,8 +586,10 @@ def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
     KV are read through divided index maps, like the forward."""
     b, h, t, d = q.shape
     h_kv = k.shape[1]
+    d_v = v.shape[3]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q, block_k = _resolve_tiles(block_q, block_k, t, t, d, q.dtype)
+    block_q, block_k = _resolve_tiles(
+        block_q, block_k, t, t, d, q.dtype, d_v)
     n_kv = t // block_k
     operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
     kv_row = lambda bh: _kv_flat_row(bh, h, h_kv)
@@ -583,7 +609,9 @@ def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
         def kv_index(bh, i, j):
             return (kv_row(bh), j, 0)
     q_spec = pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    o_spec = pl.BlockSpec((None, block_q, d_v), lambda bh, i, j: (bh, i, 0))
+    k_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    v_spec = pl.BlockSpec((None, block_k, d_v), kv_index)
     lse_spec = pl.BlockSpec(
         (None, block_q, _STATS_LANES), lambda bh, i, j: (bh, i, 0)
     )
@@ -593,7 +621,7 @@ def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
             causal=causal, scale=scale, window=window,
         ),
         grid=(b * h, t // block_q, n_kv),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, **vma_kw),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -618,7 +646,8 @@ def _flash_bwd(q, k, v, o, lse, do, **kw):
 )
 def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
                     scale=None, interpret=False, window=None):
-    """Flash attention; q,k,v [B, H, T, D] -> [B, H, T, D].
+    """Flash attention; q,k [B, H, T, D], v [B, H, T, Dv] -> [B, H, T, Dv]
+    (Dv = D in the classic layers; the scale defaults to 1/sqrt(D)).
 
     ``block_q`` / ``block_k`` None (the product path): the three kernels
     take their tiles from :func:`flash_tiles`. Explicit values win

@@ -57,14 +57,60 @@ def _position_weight(logits, y, weight):
     return weight
 
 
+#: What a train step lets the model write: objective terms, what it
+#: counts while it runs, and steps of its own parameters that are no
+#: gradient steps (:func:`_stepped`).
+_SOWN = ["aux_loss", "counters", "param_steps"]
+
+
 def _objective(updates, loss, aux_share: int = 1):
     """The CE plus every sown ``aux_loss`` leaf (over ``aux_share``, the
-    microbatches an accumulated step averages them over), and the step's
-    sown ``counters`` (what the model counts while it runs: the MoE
-    layers' routed rows; empty for a model that counts nothing)."""
+    microbatches an accumulated step averages them over), and what else
+    the step sowed: its ``counters`` (what the model counts while it runs:
+    the MoE layers' routed rows; empty for a model that counts nothing)
+    and its ``param_steps``."""
     for leaf in jax.tree.leaves(updates.get("aux_loss", {})):
         loss = loss + (leaf / aux_share if aux_share > 1 else leaf)
-    return loss, updates.get("counters", {})
+    return loss, (
+        updates.get("counters", {}), updates.get("param_steps", {}))
+
+
+def _sown_name(path) -> str:
+    """The name a leaf of a sown collection was sown under."""
+    return [k.key for k in path if hasattr(k, "key")][-1]
+
+
+def _reduce_counters(counters, axis: int = 0):
+    """Counters stacked along ``axis`` (an epoch's steps, a step's
+    microbatches) as one value each: the sum, and for a counter whose name
+    ends in ``_max`` the largest."""
+    def reduce(path, c):
+        return c.max(axis) if _sown_name(path).endswith("_max") \
+            else c.sum(axis)
+
+    return jax.tree_util.tree_map_with_path(reduce, counters)
+
+
+def _stepped(state: TrainState, steps) -> TrainState:
+    """``state`` after the steps the model sowed for its own parameters
+    into ``param_steps``, each under its module's path and the parameter's
+    name: added to that parameter as they are, after the optimizer's
+    update (whose gradient for such a parameter is zero). The selection
+    bias of the routed experts moves so
+    (:meth:`dct_tpu.models.moe.MoEFFN._grouped`). Nothing sown: the same
+    state."""
+    if not steps:
+        return state
+
+    def add(params, steps):
+        out = dict(params)
+        for k, step in steps.items():
+            out[k] = (add(params[k], step) if isinstance(step, dict)
+                      else params[k] + step)
+        return out
+
+    return state.replace(
+        params={**state.params, "params": add(state.params["params"], steps)})
 
 
 def counter_metrics(counters) -> dict:
@@ -73,15 +119,20 @@ def counter_metrics(counters) -> dict:
     sowed it: a scalar as ``name``; a vector as ``name`` (its total) and
     ``name_<j>``; and where a vector ``name`` comes with a scalar
     ``name_uniform`` (what each entry would hold under an even spread), the
-    worst entry of any module over that mean as ``name_max_over_mean``."""
+    worst entry of any module over that mean as ``name_max_over_mean``. A
+    counter sown under a name that ends in ``_max`` is the largest over
+    steps and modules, not their sum."""
     import numpy as np
 
     by_name: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(counters):
-        name = [k.key for k in path if hasattr(k, "key")][-1]
-        by_name.setdefault(name, []).append(np.asarray(leaf, np.float64))
+        by_name.setdefault(_sown_name(path), []).append(
+            np.asarray(leaf, np.float64))
     out: dict = {}
     for name, leaves in by_name.items():
+        if name.endswith("_max"):  # the largest, not the sum
+            out[name] = float(np.max(leaves))
+            continue
         total = np.sum(leaves, axis=0)
         out[name] = float(total.sum())
         if total.ndim == 1:
@@ -114,21 +165,20 @@ def _train_body_counted(state: TrainState, x, y, weight):
     def loss_fn(params):
         logits, updates = state.apply_fn(
             cast_params_by_rules(params), x, train=True,
-            rngs={"dropout": step_rng}, mutable=["aux_loss", "counters"],
+            rngs={"dropout": step_rng}, mutable=_SOWN,
         )
         w = _position_weight(logits, y, weight)
         loss_sum, count = masked_cross_entropy(logits, y, w)
         return _objective(updates, loss_sum / jnp.maximum(count, 1.0))
 
-    (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-        state.params
-    )
+    (loss, (counters, steps)), grads = jax.value_and_grad(
+        loss_fn, has_aux=True)(state.params)
     # Gradient global norm: the health monitor's drift signal. One fused
     # reduction over leaves XLA already has resident — and dead-code
     # eliminated entirely by factories that do not emit it.
     return (
-        state.apply_gradients(grads), loss, optax.global_norm(grads),
-        counters,
+        _stepped(state.apply_gradients(grads), steps), loss,
+        optax.global_norm(grads), counters,
     )
 
 
@@ -172,7 +222,7 @@ def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
     def chunk_loss(params, cx, cy, cw, rng):
         logits, updates = state.apply_fn(
             cast_params_by_rules(params), cx, train=True,
-            rngs={"dropout": rng}, mutable=["aux_loss", "counters"],
+            rngs={"dropout": rng}, mutable=_SOWN,
         )
         loss_sum, _ = masked_cross_entropy(
             logits, cy, _position_weight(logits, cy, cw)
@@ -184,21 +234,22 @@ def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
     def body(carry, chunk):
         gacc, lacc, i = carry
         cx, cy, cw = chunk
-        (loss_i, counters), g = grad_fn(
+        (loss_i, sown), g = grad_fn(
             state.params, cx, cy, cw, jax.random.fold_in(step_rng, i)
         )
-        return (
-            (jax.tree.map(jnp.add, gacc, g), lacc + loss_i, i + 1), counters
-        )
+        return (jax.tree.map(jnp.add, gacc, g), lacc + loss_i, i + 1), sown
 
     zeros = jax.tree.map(jnp.zeros_like, state.params)
-    (grads, loss, _), counters = jax.lax.scan(
+    (grads, loss, _), (counters, steps) = jax.lax.scan(
         body, (zeros, jnp.zeros(()), jnp.zeros((), jnp.int32)), (xs, ys, ws)
     )
     # Norm of the ACCUMULATED gradient — the update the optimizer sees.
+    # Sown parameter steps average over the microbatches, as aux losses do.
     return (
-        state.apply_gradients(grads), loss, optax.global_norm(grads),
-        jax.tree.map(lambda c: c.sum(axis=0), counters),
+        _stepped(
+            state.apply_gradients(grads),
+            jax.tree.map(lambda c: c.mean(axis=0), steps)),
+        loss, optax.global_norm(grads), _reduce_counters(counters),
     )
 
 
@@ -251,10 +302,7 @@ def _epoch_train_scan(state: TrainState, xs, ys, ws, accum_steps: int):
     state, (losses, gnorms, counters) = jax.lax.scan(
         body, state, (xs, ys, ws)
     )
-    return (
-        state, losses, gnorms,
-        jax.tree.map(lambda c: c.sum(axis=0), counters),
-    )
+    return state, losses, gnorms, _reduce_counters(counters)
 
 
 def _epoch_eval_scan(state: TrainState, xs, ys, ws):

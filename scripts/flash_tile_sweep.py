@@ -32,11 +32,21 @@ import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-#: (b, q heads, kv heads, T, d, window): the benchmark's 4k and 512 cells.
+#: (b, q heads, kv heads, T, d, window[, value width]): the benchmark's 4k
+#: and 512 cells (the default), the lfm2 cell's attention, and latent
+#: attention at 8,192 positions (PERF.md section 6, PR 32): queries and
+#: keys 192 wide against values of 128 as the kernels take them, the same
+#: operands zero-padded to the next 128 (256 / 128) and to one width of 256
+#: for all three, which is what a kernel with one width would have to run.
 SHAPES = {
     "seq4096": (2, 24, 2, 4096, 128, 4096),
     "seq512": (16, 24, 2, 512, 128, 4096),
+    "lfm2_seq8192": (1, 32, 8, 8192, 64, None),
+    "mla_192_128": (1, 16, 16, 8192, 192, None, 128),
+    "mla_256_128": (1, 16, 16, 8192, 256, None, 128),
+    "mla_256_256": (1, 16, 16, 8192, 256, None, 256),
 }
+DEFAULT_SHAPES = ("seq4096", "seq512")
 TILES = (128, 256, 512, 1024)
 
 
@@ -86,11 +96,13 @@ def sweep(shape_name: str, tiles, iters: int, compile_only: bool,
     if always_mask:
         _mask_every_tile(pa)
 
-    b, h, h_kv, t, d, window = SHAPES[shape_name]
+    b, h, h_kv, t, d, window, *rest = SHAPES[shape_name]
+    d_v = rest[0] if rest else d
     shapes = {
         "q": ((b, h, t, d), jnp.bfloat16), "k": ((b, h_kv, t, d), jnp.bfloat16),
-        "v": ((b, h_kv, t, d), jnp.bfloat16), "o": ((b, h, t, d), jnp.bfloat16),
-        "do": ((b, h, t, d), jnp.bfloat16), "lse": ((b, h, t), jnp.float32),
+        "v": ((b, h_kv, t, d_v), jnp.bfloat16),
+        "o": ((b, h, t, d_v), jnp.bfloat16),
+        "do": ((b, h, t, d_v), jnp.bfloat16), "lse": ((b, h, t), jnp.float32),
     }
     if compile_only:
         from jax.experimental import topologies
@@ -151,7 +163,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=None,
                     help="import dct_tpu from this checkout instead")
-    ap.add_argument("--shapes", nargs="+", default=list(SHAPES))
+    ap.add_argument("--shapes", nargs="+", default=list(DEFAULT_SHAPES),
+                    choices=list(SHAPES))
     ap.add_argument("--tiles", type=int, nargs="+", default=list(TILES))
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--compile-only", action="store_true")

@@ -20,14 +20,16 @@ import pytest
 
 from dct_tpu.ops import pallas_attention as pa
 
-#: (b, q heads, kv heads, T, d, window): BENCHMARK.json's sc2_3b cells at
-#: 4,096 and 512 positions and its lfm2 cell (head size 64, 8,192
-#: positions, full causal), then a length whose one dividing tile is no
-#: power of two (640 = 5 x 128: the rule picks the 640-row tile).
+#: (b, q heads, kv heads, T, d, window[, value width]): BENCHMARK.json's
+#: sc2_3b cells at 4,096 and 512 positions, its lfm2 cell (head size 64,
+#: 8,192 positions, full causal) and its moonlight cell (latent attention:
+#: queries and keys 192 wide, values 128), then a length whose one dividing
+#: tile is no power of two (640 = 5 x 128: the rule picks the 640-row tile).
 SHAPES = {
     "sc2_3b_seq4096": (2, 24, 2, 4096, 128, 4096),
     "sc2_3b_seq512": (16, 24, 2, 512, 128, 4096),
     "lfm2_24b_seq8192": (1, 32, 8, 8192, 64, None),
+    "moonlight_16b_seq8192": (1, 16, 16, 8192, 192, None, 128),
     "odd_seq640": (1, 4, 2, 640, 128, None),
 }
 
@@ -61,14 +63,16 @@ def no_compile_cache():
 
 
 def _operands(shape, sharding):
-    b, h, h_kv, t, d, _ = shape
+    b, h, h_kv, t, d, _, *rest = shape
+    d_v = rest[0] if rest else d
     bf16 = jnp.bfloat16
 
     def arg(dims, dtype=bf16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
-    q, kv = arg((b, h, t, d)), arg((b, h_kv, t, d))
-    return q, kv, kv, q, arg((b, h, t), jnp.float32), q  # q k v o lse do
+    q, k, v = arg((b, h, t, d)), arg((b, h_kv, t, d)), arg((b, h_kv, t, d_v))
+    o = arg((b, h, t, d_v))
+    return q, k, v, o, arg((b, h, t), jnp.float32), o  # q k v o lse do
 
 
 @pytest.mark.parametrize("kernel", ["fwd", "fwd_no_lse", "dkdv", "dq"])
@@ -87,7 +91,7 @@ def test_kernel_compiles_for_v5e_on_the_rules_tiles(
         entry = pa._flash_bwd_dkdv if kernel == "dkdv" else pa._flash_bwd_dq
         fn = lambda *a: entry(*a, **kw)
         args = (q, k, v, o, lse, do)
-    tiles = pa.flash_tiles(t, t, d, jnp.bfloat16)
+    tiles = pa.flash_tiles(t, t, d, jnp.bfloat16, *dims[6:])
     assert all(tile % 128 == 0 and t % tile == 0 for tile in tiles)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
