@@ -101,9 +101,9 @@ def test_the_seven_entries_name_their_readers_and_lint():
         mod, entry = mf.load_layer_metric(name), entries[name]
         assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
             entry["layer"], entry["unit"], entry["source"], entry["moves"])
+        # Where the seven stand in the list is not pinned: later PRs
+        # append their entries after them.
         assert entry["workloads"] == cells
-    assert [m["name"] for m in manifest["per_layer"][-7:]] == list(
-        HOST_METRICS)
 
 
 def test_a_traced_cpu_run_reports_every_reader_that_needs_no_device(traced):

@@ -1,7 +1,10 @@
 """What PR 27 added for ``lfm2_24b_a2b_ep8``: the plain reference against the
 program at a small size, the operation counts against a hand count of the
 5-layer cut, the scope reducer and the four readers against a few hand-made
-events, and the routed driver's two-part reference check on the CPU."""
+events, and the routed driver's two-part reference check on the CPU. Since
+PR 33 the configuration runs the expert bias's balancing update: the cell
+under its new name, the speed in the environment, one update against the
+plain rule, and the held experts' load with and without it."""
 
 import types
 
@@ -18,6 +21,36 @@ CFG = mf.load_json(f"{mf.BENCH_DIR}/configs/lfm2_24b_a2b_ep8.json")
 REF = mf.load_module(
     f"{mf.BENCH_DIR}/reference/lfm2_moe.py", "bench_reference_lfm2_test")
 flops = mf.load_flops(CFG)
+CELL = "lfm2_24b_ep8.fit_seq8192_balanced"
+SPEED = 0.01
+#: The balancing rule in plain numpy. The update is the program's one rule
+#: for both routed configurations, and ``lfm2_moe.py`` has a byte-identical
+#: copy under ``tests/`` that PR 33 could not touch: the rule is read from
+#: the other reference.
+RULE = mf.load_module(
+    f"{mf.BENCH_DIR}/reference/moonlight_moe.py",
+    "bench_reference_bias_rule_test").bias_step
+
+
+def test_the_cell_runs_under_its_new_name_only():
+    manifest = mf.load_manifest()
+    cell, config, traffic = mf.load_cell(manifest, CELL)
+    assert (cell["chips"], cell["config"], cell["traffic"]) == (
+        1, "lfm2_24b_a2b_ep8", "fit_moe_balanced_seq8192")
+    assert config == CFG and traffic["driver"] == "fit_routed"
+    assert (traffic["seq_len"], traffic["batch_per_chip"],
+            traffic["val_batches"]) == (8192, 1, 2)
+    # The cell that ran without the update is retired: no entry names it,
+    # so no line of the ledger compares the two.
+    retired = "lfm2_24b_ep8.fit_seq8192"
+    assert retired not in [c["name"] for c in manifest["workloads"]]
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert retired not in m.get("workloads", ())
+    layer = {m["name"] for m in mf.metrics_of(manifest, "per_layer", CELL)}
+    assert {"moe.ffn_share", "moe.expert_roofline", "shortconv.share",
+            "moe.load_max_over_mean", "moe.bound_over_routed", "step.mfu",
+            "trainer.host_epoch_s", "checkpoint.section_s"} <= layer
+    assert not {"mla.share", "mla.flash_roofline", "moe.shared_share"} & layer
 
 
 def test_configuration_keeps_the_published_widths_and_states_the_cut():
@@ -39,6 +72,12 @@ def test_configuration_keeps_the_published_widths_and_states_the_cut():
         env["DCT_LAYER_TYPES"].split(",")
     assert (env["DCT_N_EXPERTS"], env["DCT_EXPERTS_HELD"],
             env["DCT_ROUTER_TOP_K"]) == (64, 8, 4)
+    # The published file carries the bias and no speed: the one chosen on
+    # the chip is stated once and handed to the program as it is.
+    assert CFG["use_expert_bias"] is True
+    assert env["DCT_BIAS_UPDATE_SPEED"] == CFG["bias_update_speed"] == SPEED
+    assert not any("update is not run" in d for d in CFG["departures"])
+    assert any("bias_update_speed" in a for a in CFG["assumed"])
 
 
 def test_operation_counts_of_the_five_layer_cut_by_hand():
@@ -62,19 +101,24 @@ def test_operation_counts_of_the_five_layer_cut_by_hand():
         3 * 3 * 2 * 2048 * 1536 * 16384)
 
 
-def test_reference_matches_the_program_in_float32():
+def _small_model(seq_len, *, held, first, speed):
+    """The configuration's five layers at toy widths, 16 experts."""
     from dct_tpu.config import ModelConfig
     from dct_tpu.models.registry import get_model
 
     cfg = ModelConfig(
         name="weather_hybrid_moe_causal", d_model=32, n_heads=4,
-        n_kv_heads=2, n_layers=5, d_ff=96, seq_len=40, pos_embed="rope",
+        n_kv_heads=2, n_layers=5, d_ff=96, seq_len=seq_len, pos_embed="rope",
         rope_theta=1e6, dropout=0.0, norm="rmsnorm", norm_eps=1e-5,
         mlp="swiglu", use_bias=False, qk_norm=True,
         layer_types="conv,full_attention,conv,conv,conv",
         num_dense_layers=1, n_experts=16, router_top_k=4, moe_d_ff=24,
-        experts_held=2, first_expert=6)
-    model = get_model(cfg, input_dim=5, compute_dtype=jnp.float32)
+        experts_held=held, first_expert=first, bias_update_speed=speed)
+    return get_model(cfg, input_dim=5, compute_dtype=jnp.float32)
+
+
+def test_reference_matches_the_program_in_float32():
+    model = _small_model(40, held=2, first=6, speed=SPEED)
     rng = np.random.default_rng(0)
     x = rng.standard_normal((2, 40, 5)).astype(np.float32)
     y = rng.integers(0, 2, (2, 40)).astype(np.int32)
@@ -90,7 +134,8 @@ def test_reference_matches_the_program_in_float32():
         "num_experts": 2, "first_expert": 6}
     with jax.default_matmul_precision("highest"):
         got, sown = model.apply(
-            {"params": params}, x, train=False, mutable=["intermediates"])
+            {"params": params}, x, train=False,
+            mutable=["intermediates", "param_steps"])
     want, loss = REF.forward_and_loss(params, x, y, config)
     np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=2e-5)
     assert np.isfinite(loss)
@@ -107,8 +152,55 @@ def test_reference_matches_the_program_in_float32():
     np.testing.assert_array_equal(
         np.sort(out["topk"], -1), np.sort(chosen, -1))
     np.testing.assert_allclose(out["logits"], want, rtol=0, atol=2e-5)
+    # One balancing update: the step the program sows is the plain rule's,
+    # over all 16 experts from the routing the reference itself chose.
+    for i in range(1, 5):
+        bias = params[f"block_{i}"]["moe"]["expert_bias"]
+        step = np.asarray(
+            sown["param_steps"][f"block_{i}"]["moe"]["expert_bias"])
+        assert set(np.unique(np.abs(step))) <= {0.0, np.float32(SPEED)}
+        assert np.abs(step).max() > 0
+        np.testing.assert_allclose(
+            bias + step, RULE(bias, out["topk"][:, i - 1], SPEED),
+            rtol=0, atol=1e-7)
     with pytest.raises(ValueError, match="experts a layer"):
         REF.forward(params, x, {**config, "num_experts": 8})
+
+
+def _held_share_after_training(speed, steps=50):
+    """Rows the four MoE layers route to the held quarter of 16 experts,
+    over the even share, after ``steps`` Adam steps at toy widths."""
+    from dct_tpu.train.state import create_train_state
+    from dct_tpu.train.steps import make_train_step
+
+    batch, seq = 4, 64
+    model = _small_model(seq, held=4, first=0, speed=speed)
+    state = create_train_state(
+        model, input_dim=5, lr=3e-3, seed=3, example_shape=(1, seq, 5),
+        grad_clip_norm=1.0)
+    step = make_train_step(donate=False)
+    rng = np.random.default_rng(0)
+    for _ in range(steps):
+        x = rng.standard_normal((batch, seq, 5)).astype(np.float32)
+        y = (x[..., 0] + 0.5 * x[..., 1] > 0).astype(np.int32)
+        state, _ = step(state, jnp.asarray(x), jnp.asarray(y), jnp.ones(batch))
+    _, sown = jax.jit(lambda p, bx: model.apply(
+        p, bx, train=False, mutable=["counters"]))(state.params, x)
+    even = batch * seq * 4 * 4 / 16
+    return np.array([
+        float(np.sum(sown["counters"][f"block_{i}"]["moe"]["moe_rows"][0]))
+        for i in range(1, 5)]) / even
+
+
+def test_the_update_holds_the_held_experts_near_the_even_share():
+    """What the cell's new name stands for, at toy widths: the 5-feature
+    rows make the router drift, and without the update some layer's held
+    experts end far from their even share (below it or above, by seed: on
+    the chip below, PERF.md section 6); with it every layer stays near."""
+    drifted = _held_share_after_training(0.0)
+    held = _held_share_after_training(SPEED)
+    assert np.abs(drifted - 1).max() > 0.3, drifted
+    assert np.abs(held - 1).max() < 0.2, held
 
 
 HLO = '''
@@ -290,3 +382,15 @@ def test_routed_cell_traced_reports_the_counter_metric(checkout):
         checkout, "build/benchmark/tiny.routed/trace/epoch_program.hlo.txt"
     )).read()
     assert "moe.experts" in text and "shortconv" in text
+    # The balancing update ran inside the epoch program: the epochs' events
+    # carry its counter, and the bias left zero by whole steps of the
+    # configuration's speed.
+    import json
+
+    with open(os.path.join(
+            checkout, "build/benchmark/tiny.routed/events/events.jsonl")) as f:
+        ends = [e for e in map(json.loads, f)
+                if e.get("event") == "epoch_end"]
+    peaks = [e["moe_bias_abs_max"] for e in ends]
+    assert ends and all(0 <= p <= SPEED * 3 * len(ends) + 1e-9 for p in peaks)
+    assert peaks[-1] >= SPEED - 1e-9
