@@ -93,8 +93,8 @@ FULL = Size(
         # at 4,096 and at 512 positions), on the tiles the cells run.
         ("sc2_3b_seq4096", 2, 24, 2, 4096, 128, 4096),
         ("sc2_3b_seq512", 16, 24, 2, 512, 128, 4096),
-        # lfm2_24b_ep8.fit_seq8192: head size 64, 8,192 positions, full
-        # causal, 4 query heads a key-value head. The kernels run the
+        # lfm2_24b_ep8.fit_seq8192_balanced: head size 64, 8,192 positions,
+        # full causal, 4 query heads a key-value head. The kernels run the
         # cell's whole grid; the comparator takes it 8 query heads at a
         # time (_comparator_kv_heads).
         ("lfm2_seq8192", 1, 32, 8, 8192, 64, None),

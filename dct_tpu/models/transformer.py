@@ -29,6 +29,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax import lax
 
 from dct_tpu.models.mlp import TorchStyleDense, torch_linear_init
 
@@ -255,6 +256,47 @@ class GatedShortConv(nn.Module):
             )(y)
 
 
+class ResidualDropout(nn.Module):
+    """Dropout on a residual branch, its keep-mask an array of its own.
+
+    ``nn.Dropout`` leaves the mask an expression: XLA fuses the threefry
+    bit generation into whatever consumes it, the GEMMs on either side of
+    the branch among them, forward and backward, and a GEMM evaluates a
+    fused producer again for every tile pass of the product (6-10 ms a
+    fusion and step at 8,192 x 3072, PERF.md section 6, PR 34). Here the
+    mask is drawn once a call, the same threefry Bernoulli at the same
+    rate from the same ``dropout`` rng collection, and pinned by an
+    optimization barrier: one boolean array in HBM that the forward
+    applies and the backward reads as a saved residual. Under ``nn.remat``
+    the block's backward draws it once more, from the same key.
+
+    Static values alone decide the path: at ``rate`` 0 or outside training
+    the call returns its input and traces no rng and no barrier. A training
+    call sows what it kept and what it saw into ``counters``
+    (``dropout_kept``, ``dropout_total``; train/steps.py sums them over an
+    epoch) and the mask itself into ``intermediates`` for whoever asks."""
+
+    rate: float
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        if not train or self.rate == 0.0:
+            return x
+        keep_prob = 1.0 - self.rate
+        keep = lax.optimization_barrier(
+            jax.random.bernoulli(self.make_rng("dropout"), keep_prob, x.shape)
+        )
+        self.sow("intermediates", "keep", keep)
+        # Exact a call (25M elements pass float32's integers); float32
+        # from there on, as an epoch's sum passes int32.
+        self.sow(
+            "counters", "dropout_kept",
+            jnp.asarray(keep.sum(dtype=jnp.int32), jnp.float32),
+        )
+        self.sow("counters", "dropout_total", jnp.float32(x.size))
+        return jnp.where(keep, x / keep_prob, jnp.zeros_like(x))
+
+
 class TransformerBlock(nn.Module):
     """One pre-norm block: ``x + Op(norm(x))`` then ``x + FFN(norm(x))``.
 
@@ -316,7 +358,7 @@ class TransformerBlock(nn.Module):
                 f"layer type {self.op!r} must be 'full_attention', "
                 "'latent_attention' or 'conv'"
             )
-        h = nn.Dropout(rate=self.dropout, deterministic=not train)(h)
+        h = ResidualDropout(self.dropout, name="drop_attn")(h, train)
         x = x + h
         h = make_norm(self.norm, self.norm_eps, self.dtype, "ln_ffn")(x)
         dense = functools.partial(
@@ -340,7 +382,7 @@ class TransformerBlock(nn.Module):
             h = dense(self.d_model, name="ffn_out")(h)
         else:
             raise ValueError(f"mlp={self.mlp!r} must be 'gelu' or 'swiglu'")
-        h = nn.Dropout(rate=self.dropout, deterministic=not train)(h)
+        h = ResidualDropout(self.dropout, name="drop_ffn")(h, train)
         return x + h
 
 

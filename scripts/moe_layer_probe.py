@@ -4,7 +4,7 @@
     chiprun -- python scripts/moe_layer_probe.py [--bound 2 4 8] [--chunk 1 2]
 
 One process on one TPU chip: (1) ``chip_smoke.py``'s kernel check at the
-attention shape of ``lfm2_24b_ep8.fit_seq8192`` (head size 64, 8,192
+attention shape of ``lfm2_24b_ep8.fit_seq8192_balanced`` (head size 64, 8,192
 positions, full causal); (2) the grouped engine of one ``MoEFFN`` layer of
 the cell (8 of 64 sigmoid-routed top-4 SwiGLU experts of 2048 x 1536 held,
 8,192 tokens, bf16, the layer's own routing), forward and backward, host

@@ -1,5 +1,7 @@
-"""The three Mosaic flash kernels, compiled for a described v5e at the
-benchmark's real shapes on the tiles the shape rule picks.
+"""What only the TPU's compiler can say, at no chip time: the three Mosaic
+flash kernels compiled for a described v5e at the benchmark's real shapes
+on the tiles the shape rule picks, and (last in the file) where the
+compiler puts residual dropout's bit generation in a block's training step.
 
 Interpret mode accepts any tile; Mosaic refuses one that is misaligned or
 needs more scoped VMEM than a kernel may use. The TPU compiler is installed
@@ -35,16 +37,21 @@ SHAPES = {
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler, no test
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -95,3 +102,78 @@ def test_kernel_compiles_for_v5e_on_the_rules_tiles(
     assert all(tile % 128 == 0 and t % tile == 0 for tile in tiles)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _computations(text):
+    """The optimized module's computations, by name: the lines of each."""
+    out, name = {}, None
+    for line in text.splitlines():
+        if name is None and line.endswith("{") and ") -> " in line:
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            out[name] = []
+        elif name is not None and line == "}":
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "data4"])
+def test_dropout_bits_are_drawn_outside_the_gemm_fusions(
+        topo, no_compile_cache, chips):
+    """A block's training step at rate 0.1 (PERF.md section 6, PR 34):
+    no fused computation holds a threefry round (its ``xor``s) beside a
+    convolution, so no GEMM generates bits again for every tile pass (with
+    ``nn.Dropout`` in the block's place two of this step's do); on
+    ``data=4`` each chip draws its own shard of a mask and no collective
+    carries one."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dct_tpu.models.transformer import TransformerBlock
+    from dct_tpu.ops.attention import make_attention_fn
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("data",))
+    batch, replicated = (
+        NamedSharding(mesh, P("data")), NamedSharding(mesh, P()))
+    b, t, d = 8, 256, 256
+    block = TransformerBlock(
+        d, 4, 4 * d, 0.1, make_attention_fn(None), dtype=jnp.bfloat16)
+
+    def loss(params, x, key):
+        out = block.apply(params, x, True, rngs={"dropout": key})
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((b, t, d), jnp.bfloat16, sharding=batch)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated),
+        jax.eval_shape(
+            lambda: block.init(jax.random.PRNGKey(0), jnp.zeros((1, t, d)))))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, x, key).compile().as_text()
+    fused = {
+        name: lines for name, lines in _computations(text).items()
+        if "fused_computation" in name}
+    drawing = {
+        name for name, lines in fused.items()
+        if any(" xor(" in line for line in lines)}
+    assert drawing, "no threefry round in any fusion: the probe is blind"
+    assert not {
+        name for name in drawing
+        if any(" convolution(" in line for line in fused[name])}
+    # The masks the step holds are one chip's rows of them.
+    masks = {
+        line.split(" = ")[1].split("{")[0] for lines in fused.values()
+        for line in lines if " = pred[" in line and line.count(",") >= 2}
+    assert f"pred[{b // chips},{t},{d}]" in masks
+    assert (f"pred[{b},{t},{d}]" in masks) == (chips == 1)
+    collectives = [
+        line for line in text.splitlines()
+        if any(f" {op}(" in line or f" {op}-start(" in line for op in (
+            "all-reduce", "all-gather", "all-to-all", "collective-permute",
+            "reduce-scatter"))]
+    assert bool(collectives) == (chips > 1)
+    assert not [
+        line for line in collectives
+        if "pred[" in line or f"u32[{b // chips},{t}" in line]
