@@ -601,7 +601,7 @@ class MoEFFN(nn.Module):
         model's shared experts side by side), computed whole on every
         share: summing shares counts it once a share. The grouped
         engine runs as many chunks of sorted rows as hold what the router
-        sent here this step (:func:`_chunked_moe`; a chunk is twice the
+        sent here this step (:func:`_chunked_moe`; a chunk is 1.25 x the
         even share ``N * top_k * held / n_experts``), so no row is ever left
         out (``capacity_factor`` belongs to the engines that drop). Sows
         ``counters`` (rows per held expert, the rows of the chunks the
@@ -688,10 +688,13 @@ class MoEFFN(nn.Module):
         out, rows, bound, overflow = _chunked_moe(
             jnp.asarray(tokens, self.dtype), topi, gates, w_gate, w_in,
             w_out, first_expert=self.first_expert,
-            # Twice what an even router sends the held experts: the step
-            # waits for the heaviest share of the layer, which is above
-            # the even one, and up to twice it goes through in one chunk.
-            chunk=-(-2 * n * k * held // e),
+            # A quarter over what an even router sends the held experts:
+            # the balancing (the bias's update, the balance loss) holds a
+            # share within a few percent of the even one, so a step takes
+            # one chunk, and every row of a chunk past the routed ones is
+            # gathered, masked and scattered all the same. A heavier
+            # share takes a further chunk.
+            chunk=-(-5 * n * k * held // (4 * e)),
         )
         self.sow("counters", "moe_rows", rows)
         self.sow("counters", "moe_rows_bound", bound)

@@ -2,7 +2,7 @@
 """What the grouped expert layer costs on the chip, at a cell's shape.
 
     chiprun -- python scripts/moe_layer_probe.py [--cell lfm2|mellum2] \
-        [--bound 2 4 8] [--chunk 1 2]
+        [--bound 2 4 8] [--chunk 1.25 2] [--root build/parent]
 
 One process on one TPU chip: (1) ``chip_smoke.py``'s kernel check at the
 attention shapes of the cell (``lfm2_24b_ep8.fit_seq8192_balanced``: head
@@ -15,17 +15,28 @@ softmax-routed top-8 experts of 2304 x 896;
 clock around ``block_until_ready``, for each ``--bound`` (ONE static row
 bound as a multiple of the uniform expectation; 8 = every row that can
 come), with the three grouped products and the sort timed apart; (3) the
-layer AS SHIPPED, which runs as many chunks of 8,192 sorted rows as hold
+layer AS SHIPPED, which runs as many chunks of 5,120 sorted rows (1.25 x
+the even share; 20,480 in mellum2) as hold
 what was routed (``models/moe.py:_chunked_moe``), at the layer's own
-routing and at synthetic loads of 1, 2 and all 4 chunks (a selection bias
-that sends every token to some held experts, or keeps them off some; a
-softmax router has no such bias and runs at its own routing alone), with
-the rows it ran, the device's own time of the program (a profile's ``XLA
-Modules`` line) and its temporary memory, and the same
+routing and at synthetic loads of one, two and every chunk (``CELLS``: a
+constant input feature and a router row that sends every token to some
+held experts, or keeps them off some), with the rows it ran, the device's
+own time of the program (a profile's ``XLA Modules`` line) and its
+temporary memory, and the same
 engine at the same loads for each ``--chunk`` (the chunk as a multiple of
-the uniform expectation; the layer runs 2); (4) whether an
+the uniform expectation; the layer runs 1.25, and ran 2 until PR 36); (4)
+the combine alone at the layer's own routing and each ``--chunk``, device
+time: the chunk's rows scatter-added by token (what the layer runs)
+against each token's k rows gathered through the sort's inverse and
+summed (k takes of [N, D], or one take of [N x k, D]; PR 36 measured this
+form and withdrew it, PERF.md section 6), forward (gates, f32)
+and as the tokens' cotangent, the gates' cotangent both ways, and the ways
+to that inverse (a second ``argsort``, the stable rank by counting, an
+integer scatter) beside the sort itself; (5) whether an
 executable that went through ``serialize`` / ``deserialize_and_load`` still
-gives its HLO text with ``op_name``. Times are host-clock medians of single
+gives its HLO text with ``op_name``. ``--root DIR`` runs (3) alone on the
+tree unpacked at DIR (the parent's ``git archive``), for parent against
+change in one call. Times are host-clock medians of single
 calls unless the key says ``device``: for sizing a choice, not results.
 """
 
@@ -57,12 +68,19 @@ def median_ms(fn, *args, reps: int = 10) -> float:
 
 #: One routed layer of a cell, 64 experts wide: (width, experts' width,
 #: experts a token, experts held, scoring, the cell's kernel cases in
-#: ``chip_smoke.FULL.kernels``).
+#: ``chip_smoke.FULL.kernels``, and the synthetic loads: held experts the
+#: router keeps every token off / sends every token to. lfm2: 1, 1, 2, 3
+#: and all 7 chunks of 5,120 (the parent: 1, 1, 2, 2 and 4 of 8,192);
+#: mellum2: 1, 1, 2 and all 4 chunks of 20,480 (1, 1, 2 and 2 of 32,768).
 CELLS = {
     "lfm2": dict(d=2048, f=1536, k=4, held=8, scoring="sigmoid",
-                 kernels=("lfm2_seq8192",)),
+                 kernels=("lfm2_seq8192",),
+                 loads={"few": (6, 0), "own": (0, 0), "two": (4, 1),
+                        "one_takes_all": (0, 1), "all": (0, 4)}),
     "mellum2": dict(d=2304, f=896, k=8, held=16, scoring="softmax",
-                    kernels=("mellum2_swa_seq8192", "mellum2_full_seq8192")),
+                    kernels=("mellum2_swa_seq8192", "mellum2_full_seq8192"),
+                    loads={"few": (12, 0), "own": (0, 0), "half": (0, 4),
+                           "all": (0, 8)}),
 }
 CELL = CELLS["lfm2"]  # main() sets it from --cell
 
@@ -81,9 +99,11 @@ def cell_layer(n: int):
         dtype=jnp.bfloat16, dispatch="grouped", top_k=CELL["k"],
         experts_held=CELL["held"], first_expert=0, scoring=CELL["scoring"])
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((1, n, CELL["d"])), jnp.bfloat16)
+    x = rng.standard_normal((1, n, CELL["d"]))
+    x[..., 0] = 8.0  # what :func:`steered` pulls the router by
+    x = jnp.asarray(x, jnp.bfloat16)
     params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
-    return layer, rng, x, params
+    return layer, rng, x, steered(params, 0, 0)
 
 
 def probe_layer(bound: float, n: int = 8192) -> dict:
@@ -153,31 +173,21 @@ def probe_layer(bound: float, n: int = 8192) -> dict:
     return out
 
 
-#: Synthetic loads: held experts (of lfm2's 8) the selection bias keeps
-#: every token off / sends every token to. 1, 1, 1, 2 and all 4 chunks of
-#: 8,192.
-LOADS = {"few": (6, 0), "most": (2, 0), "own": (0, 0),
-         "one_takes_all": (0, 1), "all": (0, 4)}
-
-
-def loads() -> dict:
-    """The loads the cell's router can be steered to: a softmax router has
-    no selection bias, so its own routing alone."""
-    return LOADS if CELL["scoring"] == "sigmoid" else {"own": (0, 0)}
-
-
-def biased(params, off: int, on: int) -> dict:
+def steered(params, off: int, on: int) -> dict:
+    """``params`` with a router that keeps every token off the last
+    ``off`` held experts and sends every token to the first ``on``: the
+    row of the router's kernel that meets the inputs' constant feature,
+    zero elsewhere, so (0, 0) is the router's own load."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    if "expert_bias" not in params:
-        return params
     held = CELL["held"]
-    bias = np.zeros(64, np.float32)
-    bias[held - off:held] = -10.0
-    bias[:on] = 10.0
-    return jax.device_put({**params, "expert_bias": jnp.asarray(bias)})
+    pull = np.zeros(64, np.float32)
+    pull[held - off:held] = -4.0
+    pull[:on] = 4.0
+    kernel = jnp.asarray(params["router"]["kernel"]).at[0].set(pull)
+    return jax.device_put({**params, "router": {"kernel": kernel}})
 
 
 def device_ms(fn, calls, reps: int = 3) -> list:
@@ -223,8 +233,8 @@ def probe_shipped(n: int = 8192) -> list[dict]:
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
     compiled = step.lower(params, x).compile()
-    by_load = {load: biased(params, off, on)
-               for load, (off, on) in loads().items()}
+    by_load = {load: steered(params, off, on)
+               for load, (off, on) in CELL["loads"].items()}
     found = []
     for load, p in by_load.items():
         _, counters = jax.block_until_ready(compiled(p, x))
@@ -274,14 +284,125 @@ def probe_chunks(multiple: float, n: int = 8192) -> list[dict]:
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
     found = []
-    for load, (off, on) in loads().items():
-        topi = routing(biased(params, off, on), x)["topk"][0]
+    for load, (off, on) in CELL["loads"].items():
+        topi = routing(steered(params, off, on), x)["topk"][0]
         _, (rows, bound) = step(params, x, topi)
         found.append({
             "chunk": chunk, "load": load, "rows": int(rows),
             "rows_bound": int(bound),
             "engine_fwd_bwd_ms": median_ms(step, params, x, topi),
         })
+    return found
+
+
+def probe_combine(multiple: float, n: int = 8192) -> list[dict]:
+    """The combine alone, at the layer's own routing and one chunk of
+    ``multiple`` x the even share, device time: the row scatter-adds the
+    layer runs against gathers through the sort's inverse (measured and
+    withdrawn in PR 36), and the ways to that inverse. Written out here,
+    not imported, so the forms stay comparable whatever the layer ships."""
+    import jax
+    import jax.numpy as jnp
+
+    layer, rng, x, params = cell_layer(n)
+    topi = jax.jit(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=["intermediates"])[1]["intermediates"])(
+            params, x)["topk"][0]
+    k, held, d = CELL["k"], CELL["held"], CELL["d"]
+    chunk = int(multiple * n * k * held / 64)
+    flat = jnp.where(topi < held, topi, held).reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True)
+    place = jnp.argsort(order).reshape(n, k)
+    routed = int((flat < held).sum())
+    row = order[:chunk]
+    valid = (jnp.arange(chunk) < routed)[:, None]
+    y = jnp.where(valid, jnp.asarray(
+        rng.standard_normal((chunk, d)), jnp.bfloat16), 0)
+    gates = jnp.asarray(rng.random((n, k)), jnp.float32)
+    d_gate = jnp.where(valid[:, 0], jnp.asarray(
+        rng.standard_normal(chunk), jnp.float32), 0)
+
+    def slots(place):
+        here = place < chunk
+        return here, jnp.clip(place, 0, chunk - 1)
+
+    def scatter_out(y, row, gates):
+        return jnp.zeros((n, d), jnp.float32).at[row // k].add(
+            y.astype(jnp.float32) * gates.reshape(-1)[row][:, None])
+
+    def k_takes(a, place, gates=1.0):
+        here, at = slots(place)
+        w = jnp.where(here, gates, 0)
+        return sum(w[:, j, None] * a[at[:, j]].astype(jnp.float32)
+                   for j in range(k))
+
+    def one_take(a, place, gates=1.0):
+        here, at = slots(place)
+        rows = a[at.reshape(-1)].reshape(n, k, d).astype(jnp.float32)
+        return (rows * jnp.where(here, gates, 0)[:, :, None]).sum(1)
+
+    def scatter_tokens(dx, row):
+        return jnp.zeros((n, d), dx.dtype).at[row // k].add(dx)
+
+    def scatter_gates(d_gate, row):
+        return jnp.zeros(n * k, jnp.float32).at[row].add(d_gate)
+
+    def take_gates(d_gate, place):
+        here, at = slots(place)
+        return jnp.where(here, d_gate[at], 0)
+
+    def counted(flat, axis):
+        # The stable rank by counting: a row's place is its expert's first
+        # row plus the rows of that expert before it. No sort, no scatter.
+        experts = jnp.arange(held + 1, dtype=jnp.int32)
+        hot = (jnp.expand_dims(flat, 1 - axis)
+               == jnp.expand_dims(experts, axis)).astype(jnp.int32)
+        before = jnp.cumsum(hot, axis=axis) - hot
+        counts = hot.sum(axis=axis, keepdims=True)
+        starts = jnp.cumsum(counts, axis=1 - axis) - counts
+        return ((before + starts) * hot).sum(axis=1 - axis)
+
+    forms = {
+        "out_scatter_add": (scatter_out, (y, row, gates)),
+        "out_k_takes": (k_takes, (y, place, gates)),
+        "out_one_take": (one_take, (y, place, gates)),
+        "tokens_scatter_add": (scatter_tokens, (y, row)),
+        "tokens_k_takes": (
+            lambda dx, place: k_takes(dx, place).astype(dx.dtype),
+            (y, place)),
+        "tokens_one_take": (
+            lambda dx, place: one_take(dx, place).astype(dx.dtype),
+            (y, place)),
+        "gates_scatter_add": (scatter_gates, (d_gate, row)),
+        "gates_take": (take_gates, (d_gate, place)),
+        "sort": (lambda f: jnp.argsort(f, stable=True), (flat,)),
+        "inverse_argsort": (jnp.argsort, (order,)),
+        "inverse_counted_rows": (lambda f: counted(f, 0), (flat,)),
+        "inverse_counted_lanes": (lambda f: counted(f, 1), (flat,)),
+        "inverse_scatter": (
+            lambda o: jnp.zeros(n * k, jnp.int32).at[o].set(
+                jnp.arange(n * k, dtype=jnp.int32)), (order,)),
+    }
+    found = [{"n": n, "k": k, "d": d, "chunk": chunk, "routed": routed}]
+    results = {}
+    for name, (fn, args) in forms.items():
+        compiled = jax.jit(fn).lower(*args).compile()
+        results[name] = jax.block_until_ready(compiled(*args))
+        found.append({
+            "form": name,
+            "device_ms": device_ms(compiled, [args], reps=5)[0],
+            "temp_mb": compiled.memory_analysis().temp_size_in_bytes / 1e6,
+        })
+    # Every form of one quantity gives the same thing.
+    for same in (("out_scatter_add", "out_k_takes", "out_one_take"),
+                 ("tokens_scatter_add", "tokens_k_takes", "tokens_one_take"),
+                 ("gates_scatter_add", "gates_take"),
+                 ("inverse_argsort", "inverse_counted_rows",
+                  "inverse_counted_lanes", "inverse_scatter")):
+        base, *others = (
+            results[name].astype(jnp.float32).reshape(-1) for name in same)
+        found.append({"agree": same, "max_abs_difference": [
+            float(jnp.abs(other - base).max()) for other in others]})
     return found
 
 
@@ -307,9 +428,10 @@ def probe_text() -> dict:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--bound", type=float, nargs="*", default=[2, 4, 8])
-    ap.add_argument("--chunk", type=float, nargs="*", default=[1, 2])
+    ap.add_argument("--chunk", type=float, nargs="*", default=[1.25, 2])
     ap.add_argument("--skip-kernel", action="store_true")
     ap.add_argument("--cell", choices=list(CELLS), default="lfm2")
+    ap.add_argument("--root", help="a tree to probe as shipped, alone")
     args = ap.parse_args()
     global CELL
     CELL = CELLS[args.cell]
@@ -318,6 +440,11 @@ def main() -> int:
     if jax.devices()[0].platform != "tpu":
         print("moe_layer_probe: needs a TPU", file=sys.stderr)
         return 2
+    if args.root:
+        sys.path.insert(0, os.path.abspath(args.root))
+        for found in probe_shipped():
+            print(json.dumps({"root": args.root, **found}), flush=True)
+        return 0
     if not args.skip_kernel:
         import chip_smoke
 
@@ -331,7 +458,7 @@ def main() -> int:
         finite &= found.get("grads_finite", True)
         print(json.dumps(found), flush=True)
     for c in args.chunk:
-        for found in probe_chunks(c):
+        for found in probe_combine(c) + probe_chunks(c):
             print(json.dumps(found), flush=True)
     for c in args.bound:
         found = probe_layer(c)

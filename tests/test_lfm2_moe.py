@@ -303,37 +303,25 @@ def _reference_loss(first, cot):
     return loss
 
 
-@pytest.mark.parametrize("held, favoured, low, high, bound", [
-    (4, 0, 40, 96, 192),     # the router's own load: under the even share
-    (4, 1, 97, 192, 192),    # one held expert takes every token
-    (4, 2, 193, 288, 384),
-    (4, 3, 289, 383, 384),   # every chunk, the last not full
-    (4, 4, 384, 384, 384),   # every row that can come
-    (5, 4, 384, 384, 480),   # chunks of 240: the last passes the 384 rows
-    (2, 0, 10, 96, 96),      # chunks of 96, 192 rows can come
-    (2, 2, 192, 192, 192),
-])
-def test_each_load_gives_the_reference_and_drops_no_row(
-        whole_layer, held, favoured, low, high, bound):
-    p, x = whole_layer
-    share = _pulled(p, 4, favoured, held)
-    layer = _layer(held, 4)
-    cot = np.random.default_rng(9).standard_normal(x.shape).astype(np.float32)
-    out, sown = _apply(layer, share, x)
-    counters = sown["counters"]
-    routed = int(counters["moe_rows"][0].sum())
-    chunk = -(-2 * 96 * 4 * held // 16)
-    assert low <= routed <= high
-    assert int(counters["moe_rows_bound"][0]) == bound == (
-        -(-routed // chunk) * chunk)
-    assert int(counters["moe_rows_overflowed"][0]) == 0
-    want, _, _ = REF._moe(
-        jnp.asarray(x.reshape(-1, 32)), share, top_k=4, first=4, scaling=1.0,
-        routing=None)
-    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=0, atol=TOL)
+def _equations(jaxpr, in_loop=False):
+    """Every equation of ``jaxpr`` and of what it calls, with whether a
+    ``while`` holds it."""
+    for eqn in jaxpr.eqns:
+        yield in_loop, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(
+                sub, in_loop or eqn.primitive.name == "while")
+
+
+def _gradients_against_the_reference(layer, share, x, first, seed):
+    """The layer's gradients for parameters and tokens under a seeded
+    cotangent, held to the reference's; returns the tokens' [N, 32]."""
+    cot = np.random.default_rng(seed).standard_normal(x.shape).astype(
+        np.float32)
     with jax.default_matmul_precision("highest"):
         got = jax.grad(_layer_loss(layer, x, cot), (0, 1))(share, x)
-        ref = jax.grad(_reference_loss(4, cot), (0, 1))(share, jnp.asarray(x))
+        ref = jax.grad(_reference_loss(first, cot), (0, 1))(
+            share, jnp.asarray(x))
     for name in ("experts_gate_kernel", "experts_in_kernel",
                  "experts_out_kernel"):
         np.testing.assert_allclose(
@@ -344,7 +332,41 @@ def test_each_load_gives_the_reference_and_drops_no_row(
         got[0]["router"]["kernel"], ref[0]["router"]["kernel"], rtol=0,
         atol=TOL)
     np.testing.assert_allclose(got[1], ref[1], rtol=0, atol=TOL)
-    assert not np.asarray(got[0]["expert_bias"]).any()
+    if "expert_bias" in share:
+        assert not np.asarray(got[0]["expert_bias"]).any()
+    return np.asarray(got[1]).reshape(-1, 32)
+
+
+@pytest.mark.parametrize("held, favoured, low, high, bound", [
+    (4, 0, 40, 96, 120),     # the router's own load: under the even share
+    (4, 1, 121, 192, 240),   # one held expert takes every token: two chunks
+    (4, 2, 193, 240, 240),   # two, full but for a few rows
+    (4, 3, 241, 360, 360),   # three
+    (4, 4, 384, 384, 480),   # every row that can come: the last passes them
+    (5, 4, 384, 384, 450),   # chunks of 150: the last passes the 384 rows
+    (2, 0, 10, 60, 60),      # chunks of 60, 192 rows can come
+    (2, 2, 192, 192, 240),
+    (3, 1, 97, 180, 180),    # chunks of 90
+    (3, 3, 288, 288, 360),
+])
+def test_each_load_gives_the_reference_and_drops_no_row(
+        whole_layer, held, favoured, low, high, bound):
+    p, x = whole_layer
+    share = _pulled(p, 4, favoured, held)
+    layer = _layer(held, 4)
+    out, sown = _apply(layer, share, x)
+    counters = sown["counters"]
+    routed = int(counters["moe_rows"][0].sum())
+    chunk = -(-5 * 96 * 4 * held // (4 * 16))
+    assert low <= routed <= high
+    assert int(counters["moe_rows_bound"][0]) == bound == (
+        -(-routed // chunk) * chunk)
+    assert int(counters["moe_rows_overflowed"][0]) == 0
+    want, _, _ = REF._moe(
+        jnp.asarray(x.reshape(-1, 32)), share, top_k=4, first=4, scaling=1.0,
+        routing=None)
+    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=0, atol=TOL)
+    _gradients_against_the_reference(layer, share, x, 4, 9)
 
 
 @pytest.mark.parametrize("chunk, favoured, bound", [
@@ -396,12 +418,71 @@ def test_the_chunks_equal_the_engine_under_one_bound(
         np.testing.assert_allclose(a, b, rtol=0, atol=TOL, err_msg=name)
 
 
+def test_a_token_with_every_choice_held_beside_one_with_none(whole_layer):
+    """Held experts 4-7 score ~1 on one side of a hyperplane and ~0 on the
+    other: a token far from it sends all its four choices here or none of
+    them, so the combine adds four rows to the one and no row to the
+    other, and the routed rows take a second chunk."""
+    p, x = whole_layer
+    tokens = x.reshape(-1, 32)
+    u = np.random.default_rng(12).standard_normal(32).astype(np.float32)
+    kernel = np.array(p["router"]["kernel"])
+    kernel[:, 4:8] = 4.0 * u[:, None]
+    share = {**_held_share(p, 4, 4), "router": {"kernel": kernel}}
+    layer = _layer(4, 4)
+    out, sown = _apply(layer, share, x)
+    here = ((sown["intermediates"]["topk"][0] >= 4)
+            & (sown["intermediates"]["topk"][0] < 8)).sum(-1)
+    assert int((here == 4).sum()) >= 24 and int((here == 0).sum()) >= 24
+    counters = sown["counters"]
+    assert int(counters["moe_rows"][0].sum()) == int(here.sum()) > 120
+    assert int(counters["moe_rows_bound"][0]) == 240
+    assert int(counters["moe_rows_overflowed"][0]) == 0
+    assert not out.reshape(-1, 32)[here == 0].any()
+    want, _, _ = REF._moe(
+        jnp.asarray(tokens), share, top_k=4, first=4, scaling=1.0,
+        routing=None)
+    np.testing.assert_allclose(out.reshape(-1, 32), want, rtol=0, atol=TOL)
+    # Nothing this share computes depends on a token with no choice here.
+    d_tokens = _gradients_against_the_reference(layer, share, x, 4, 13)
+    assert not d_tokens[here == 0].any()
+    assert (np.abs(d_tokens[here == 4]).max(-1) > 10 * TOL).all()
+
+
+@pytest.mark.parametrize("d, f, k, held, chunk, top", [
+    (2048, 1536, 4, 8, 5120, 35840),     # lfm2_24b_a2b_ep8
+    (2048, 1408, 6, 8, 7680, 53760),     # moonlight_16b_a3b_ep8
+    (2304, 896, 8, 16, 20480, 81920),    # mellum2_12b_cut
+])
+def test_the_cells_chunk_is_a_quarter_over_the_even_share(
+        d, f, k, held, chunk, top):
+    """The three routed cells' layers at their published shapes, traced on
+    shapes alone: a chunk of the loop is 1.25 x ``8192 k held / 64`` rows,
+    and the stacks' gradients run over the whole chunks that hold every
+    row that can come."""
+    layer = MoEFFN(
+        d_model=d, d_ff=f, n_experts=64, aux_weight=0.0,
+        dtype=jnp.bfloat16, dispatch="grouped", top_k=k, experts_held=held)
+    x = jax.ShapeDtypeStruct((1, 8192, d), jnp.bfloat16)
+    params = jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), jnp.zeros((1, 8, d)))["params"]
+    assert chunk == 5 * 8192 * k * held // (4 * 64)
+
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, x: layer.apply({"params": p}, x).astype(
+            jnp.float32).sum()))(params, x).jaxpr
+    assert {(in_loop, eqn.invars[0].aval.shape[0])
+            for in_loop, eqn in _equations(jaxpr)
+            if eqn.primitive.name == "ragged_dot_general"} == {
+                (True, chunk), (False, top)}
+
+
 def test_the_backward_keeps_no_chunk_of_rows(whole_layer):
     """What the layer saves for its backward: its inputs, and nothing
     with a chunk's rows (it computes each chunk again)."""
     p, x = whole_layer
     held = _pulled(p, 4, 1, held=3)
-    chunks = {72, 144, 216, 288}  # none is a token or a weight extent
+    chunks = {90, 180, 270, 360}  # none is a token or a weight extent
 
     def saved_rows(fn, *args):
         _, vjp = jax.vjp(fn, *args)
@@ -417,9 +498,9 @@ def test_the_backward_keeps_no_chunk_of_rows(whole_layer):
     assert saved_rows(
         lambda t, *w: _grouped_moe(
             t, topi, jnp.full((96, 4), 0.25), *w, first_expert=4,
-            row_bound=288)[0],
+            row_bound=270)[0],
         jnp.asarray(x.reshape(-1, 32)), held["experts_gate_kernel"],
-        held["experts_in_kernel"], held["experts_out_kernel"]) == {288}
+        held["experts_in_kernel"], held["experts_out_kernel"]) == {270}
 
 
 def test_each_stack_has_one_gradient_product_after_the_chunks(whole_layer):
@@ -430,25 +511,18 @@ def test_each_stack_has_one_gradient_product_after_the_chunks(whole_layer):
     held = _pulled(p, 4, 1, held=3)
     stacks = {(3, 32, 24), (3, 24, 32)}
 
-    def walk(jaxpr, in_loop, found):
-        for eqn in jaxpr.eqns:
-            shapes = {getattr(v.aval, "shape", None) for v in eqn.outvars}
-            if shapes & stacks:
-                found.append((in_loop, eqn.primitive.name, tuple(
-                    v.aval.shape for v in eqn.invars)))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, in_loop or eqn.primitive.name == "while", found)
-        return found
-
     layer = _layer(3, 4)
-    found = walk(jax.make_jaxpr(jax.grad(
-        lambda p, x: layer.apply({"params": p}, x).sum()))(held, x).jaxpr,
-        False, [])
+    found = [
+        (in_loop, eqn.primitive.name, tuple(
+            v.aval.shape for v in eqn.invars))
+        for in_loop, eqn in _equations(jax.make_jaxpr(jax.grad(
+            lambda p, x: layer.apply({"params": p}, x).sum()))(held, x).jaxpr)
+        if {getattr(v.aval, "shape", None) for v in eqn.outvars} & stacks]
     assert not [f for f in found if f[0]]
     products = [f for f in found if f[1] == "ragged_dot_general"]
     assert sorted(shapes[:2] for _, _, shapes in products) == [
-        ((288, 24), (288, 32)), ((288, 32), (288, 24)),
-        ((288, 32), (288, 24))]
+        ((360, 24), (360, 32)), ((360, 32), (360, 24)),
+        ((360, 32), (360, 24))]
 
 
 def test_the_bound_is_counted_with_the_rows(whole_layer):
@@ -464,7 +538,7 @@ def test_the_bound_is_counted_with_the_rows(whole_layer):
             is_leaf=lambda leaf: isinstance(leaf, tuple))}
         for i, favoured in enumerate((1, 4))}
     flat = counter_metrics(sown)
-    assert flat["moe_rows_bound"] == 192 + 384
+    assert flat["moe_rows_bound"] == 240 + 480
     assert flat["moe_rows_overflowed"] == 0
     assert 97 + 384 <= flat["moe_rows"] <= 192 + 384
 

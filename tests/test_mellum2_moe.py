@@ -362,9 +362,13 @@ def test_the_accepted_cells_tiles_are_what_they_were():
 
 
 #: The accepted configurations' ``program.env`` with the widths divided so
-#: the CPU builds them at once, and what the parent commit (9b9390d) built
-#: from each: the parameter tree's shapes and the StableHLO text of the
-#: objective's value and gradient, as digests recorded there.
+#: the CPU builds them at once, and what each builds: the parameter tree's
+#: shapes and the StableHLO text of the objective's value and gradient, as
+#: digests. The trees and ``sc2_3b_block``'s text are what 9b9390d built
+#: (PR 35's parent; ``mellum2_12b_cut``'s tree what a54b777 built); the
+#: three routed configurations' texts were recorded anew in PR 36, whose
+#: chunk of 1.25 x the even share is a shape in them (at a54b777:
+#: ed07330872198a5f, dddf8cb6c2fd7f4d, fec8e302eba026ce).
 PRESETS = {
     "sc2_3b_block": (
         dict(DCT_D_MODEL="96", DCT_D_FF="384", DCT_N_HEADS="24",
@@ -372,13 +376,18 @@ PRESETS = {
         "ee8cc5ee3bbd5641", "9e2c314abf323051"),
     "lfm2_24b_a2b_ep8": (
         dict(DCT_D_MODEL="64", DCT_D_FF="368", DCT_MOE_D_FF="48"),
-        "29bdf51521177ce8", "ed07330872198a5f"),
+        "29bdf51521177ce8", "326831293ee7ba1a"),
     "moonlight_16b_a3b_ep8": (
         dict(DCT_D_MODEL="64", DCT_D_FF="96", DCT_N_HEADS="4",
              DCT_N_KV_HEADS="4", DCT_MOE_D_FF="24", DCT_MOE_SHARED_D_FF="48",
              DCT_KV_LORA_RANK="16", DCT_QK_NOPE_HEAD_DIM="8",
              DCT_QK_ROPE_HEAD_DIM="4", DCT_V_HEAD_DIM="8"),
-        "5eae93a7bbcc20cc", "dddf8cb6c2fd7f4d"),
+        "5eae93a7bbcc20cc", "275ec9154ced88b4"),
+    "mellum2_12b_cut": (
+        dict(DCT_D_MODEL="64", DCT_D_FF="96", DCT_N_HEADS="8",
+             DCT_N_KV_HEADS="2", DCT_HEAD_DIM="16", DCT_MOE_D_FF="24",
+             DCT_ATTN_WINDOW="16", DCT_ROPE_ORIGINAL_LEN="32"),
+        "92ba8f2ae08a3289", "445cc51cfb480be0"),
 }
 
 
