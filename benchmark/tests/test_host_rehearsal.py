@@ -72,11 +72,12 @@ def traced(tmp_path_factory):
     manifest["configs"].append({
         "name": "tiny_host_block", "source": "test", "reduced": [],
         "file": "benchmark/configs/tiny_host_block.json", "why": "test"})
+    cells = [c["name"] for c in manifest["workloads"]]
     manifest["workloads"].append({
         "name": "tiny.host", "config": "tiny_host_block",
         "traffic": "tiny_host", "chips": 1, "why": "test"})
     for m in manifest["per_layer"]:
-        if m["name"] in HOST_METRICS:
+        if m["workloads"] == cells:
             m["workloads"].append("tiny.host")
     with open(root + "/BENCHMARK.json", "w") as f:
         json.dump(manifest, f)

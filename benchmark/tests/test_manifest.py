@@ -39,6 +39,25 @@ def test_configuration_file_and_its_program_settings_agree():
         assert cfg["departures"]
 
 
+def test_every_list_names_cells_and_every_fit_cell_is_on_its_lists():
+    m = mf.load_manifest()
+    cells = [c["name"] for c in m["workloads"]]
+    for entry in m["per_layer"]:
+        assert set(entry.get("workloads", [])) <= set(cells), entry["name"]
+    # A metric without a list would bind every cell a later PR adds; each
+    # one that moves the fit rate names its cells, and each fit cell is on
+    # the lists of the readers of the trainer's host spans.
+    fit = [c for c in cells if mf.load_cell(m, c)[2]["driver"].startswith(
+        "fit")]
+    entries = {e["name"]: e for e in m["per_layer"]}
+    for entry in entries.values():
+        if entry["moves"] == "fit_tokens_per_s":
+            assert "workloads" in entry, entry["name"]
+    for name in ("trainer.host_epoch_s", "trainer.join_wait_share",
+                 "checkpoint.section_s", "device.idle_unexplained_share"):
+        assert set(fit) <= set(entries[name]["workloads"]), name
+
+
 def test_the_lint_bites():
     good = mf.load_manifest()
 

@@ -44,11 +44,13 @@ def test_the_cell_is_in_the_manifest_with_its_metrics():
             "moe.bound_over_routed", "step.mfu", "kernels.flash_share",
             "trainer.host_epoch_s", "checkpoint.section_s"} <= layer
     assert "shortconv.share" not in layer
-    # New entries stand at the end of their lists.
-    assert [m["name"] for m in manifest["per_layer"]][-3:] == [
-        "mla.share", "mla.flash_roofline", "moe.shared_share"]
-    assert manifest["workloads"][-1]["name"] == CELL
-    for m in manifest["per_layer"][-3:]:
+    # Its own three entries name it alone; where they stand is not pinned,
+    # since later PRs add cells and metrics around them.
+    own = {m["name"]: m for m in manifest["per_layer"]
+           if m["name"] in ("mla.share", "mla.flash_roofline",
+                            "moe.shared_share")}
+    assert len(own) == 3
+    for m in own.values():
         assert m["workloads"] == [CELL]
 
 

@@ -1,7 +1,8 @@
 """End to end at tiny size on the CPU, through a throw-away cell added the
 way a later PR adds one: one configuration file with its reference and its
-operation counts beside it, one traffic file, one reader file, and one entry
-for each in BENCHMARK.json. Nothing that is there is edited. The result says
+operation counts beside it, one traffic file, one reader file, one entry
+for each in BENCHMARK.json, and the new cell's name on the per-layer lists
+that name every cell. No file that is there is edited. The result says
 ``"platform": "cpu"``, carries no device metric, and is never printed by
 ``run.py`` itself (its ``main`` exits 2)."""
 
@@ -97,6 +98,11 @@ def checkout(tmp_path_factory):
     manifest["configs"].append({
         "name": "tiny_block", "source": "test", "reduced": [],
         "file": "benchmark/configs/tiny_block.json", "why": "test"})
+    # As any later fit cell does, it joins every list that names every cell.
+    cells = [c["name"] for c in manifest["workloads"]]
+    for m in manifest["per_layer"]:
+        if m["workloads"] == cells:
+            m["workloads"].append("tiny.fit")
     manifest["workloads"].append({
         "name": "tiny.fit", "config": "tiny_block", "traffic": "tiny_fit",
         "chips": 1, "why": "test"})
@@ -143,11 +149,14 @@ def test_added_cell_runs_traced_without_device_metrics(checkout):
                "--seconds", "2", "--trace", "1")
     out = got["out"]
     assert out["correct"], got["notes"]
-    # The CPU has no device plane: every trace reader returned nothing and
+    # The CPU has no device plane: every reader of it returned nothing and
     # was left out; the counters and spans of the program are there.
     assert "busy_s" not in out["device"] and "breakdown" not in out
     assert set(out["metrics"]) == {
-        "trainer.goodput_share", "checkpoint.stall_share", "tiny.epochs"}
+        "trainer.goodput_share", "checkpoint.stall_share", "tiny.epochs",
+        "trainer.host_epoch_s", "trainer.join_wait_share",
+        "checkpoint.section_s", "checkpoint.disk_share", "trainer.startup_s",
+        "trainer.first_dispatch_s"}
     assert out["metrics"]["tiny.epochs"]["value"] == out["attempted"]
 
 

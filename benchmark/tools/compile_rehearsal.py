@@ -2,7 +2,7 @@
 """Compile a fit cell's epoch program for a described v5e:2x2, without a chip.
 
     JAX_PLATFORMS=cpu python benchmark/tools/compile_rehearsal.py \
-        [--workload sc2_3b.fit_seq4096] [--layers 4 3] [--chips 1 4]
+        [--workload sc2_3b.fit_seq4096_e40] [--layers 4 3] [--chips 1 4]
 
 Lowers ``make_epoch_train_eval_step`` exactly as the pipelined trainer
 builds it (state not donated, stacks donated, grad norms on) on shapes only,
@@ -118,7 +118,7 @@ def rehearse(workload: str, layers: int, chips: int) -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", default="sc2_3b.fit_seq4096")
+    ap.add_argument("--workload", default="sc2_3b.fit_seq4096_e40")
     ap.add_argument("--layers", type=int, nargs="+", default=[4, 3])
     ap.add_argument("--chips", type=int, nargs="+", default=[1, 4])
     args = ap.parse_args()
