@@ -461,8 +461,9 @@ class TransformerBlock(nn.Module):
                 )(h)
                 h = dense(self.d_model, name="ffn_out")(h)
         elif self.mlp == "gelu":
-            h = nn.gelu(dense(self.d_ff, name="ffn_in")(h))
-            h = dense(self.d_model, name="ffn_out")(h)
+            with jax.named_scope("dense_mlp"):
+                h = nn.gelu(dense(self.d_ff, name="ffn_in")(h))
+                h = dense(self.d_model, name="ffn_out")(h)
         else:
             raise ValueError(f"mlp={self.mlp!r} must be 'gelu' or 'swiglu'")
         h = ResidualDropout(self.dropout, name="drop_ffn")(h, train)

@@ -113,6 +113,18 @@ def _stepped(state: TrainState, steps) -> TrainState:
         params={**state.params, "params": add(state.params["params"], steps)})
 
 
+def _optimizer_step(state: TrainState, grads, steps) -> TrainState:
+    """The optimizer's update (the clip's global norm, Adam, the f32
+    masters), then :func:`_stepped`, under the device scope
+    ``step.optimizer``. With ``step.loss`` around the objective's forward
+    (whose backward the optimized HLO text names
+    ``transpose(jvp(step.loss))``) and ``step.eval`` around the validation
+    pass, the scopes name the step's passes in the program's text; a scope
+    leaves the program as it was."""
+    with jax.named_scope("step.optimizer"):
+        return _stepped(state.apply_gradients(grads), steps)
+
+
 def counter_metrics(counters) -> dict:
     """An epoch's counters (host arrays, summed over its steps) as flat
     metrics, by the name each was sown under, summed over the modules that
@@ -163,13 +175,14 @@ def _train_body_counted(state: TrainState, x, y, weight):
     step_rng = jax.random.fold_in(state.rng, state.step)
 
     def loss_fn(params):
-        logits, updates = state.apply_fn(
-            cast_params_by_rules(params), x, train=True,
-            rngs={"dropout": step_rng}, mutable=_SOWN,
-        )
-        w = _position_weight(logits, y, weight)
-        loss_sum, count = masked_cross_entropy(logits, y, w)
-        return _objective(updates, loss_sum / jnp.maximum(count, 1.0))
+        with jax.named_scope("step.loss"):
+            logits, updates = state.apply_fn(
+                cast_params_by_rules(params), x, train=True,
+                rngs={"dropout": step_rng}, mutable=_SOWN,
+            )
+            w = _position_weight(logits, y, weight)
+            loss_sum, count = masked_cross_entropy(logits, y, w)
+            return _objective(updates, loss_sum / jnp.maximum(count, 1.0))
 
     (loss, (counters, steps)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
@@ -177,7 +190,7 @@ def _train_body_counted(state: TrainState, x, y, weight):
     # reduction over leaves XLA already has resident — and dead-code
     # eliminated entirely by factories that do not emit it.
     return (
-        _stepped(state.apply_gradients(grads), steps), loss,
+        _optimizer_step(state, grads, steps), loss,
         optax.global_norm(grads), counters,
     )
 
@@ -189,15 +202,16 @@ def _eval_body(state: TrainState, x, y, weight):
     behind precision/recall/F1 — a metric surface the reference's rain
     classifier lacks). Sown aux losses are training regularizers only;
     val_loss stays pure CE."""
-    logits, _ = state.apply_fn(
-        cast_params_by_rules(state.params), x, train=False,
-        mutable=["aux_loss"],
-    )
-    w = _position_weight(logits, y, weight)
-    loss_sum, count = masked_cross_entropy(logits, y, w)
-    acc_sum, _ = masked_accuracy(logits, y, w)
-    tp, fp, fn = masked_binary_counts(logits, y, w)
-    return loss_sum, acc_sum, count, tp, fp, fn
+    with jax.named_scope("step.eval"):
+        logits, _ = state.apply_fn(
+            cast_params_by_rules(state.params), x, train=False,
+            mutable=["aux_loss"],
+        )
+        w = _position_weight(logits, y, weight)
+        loss_sum, count = masked_cross_entropy(logits, y, w)
+        acc_sum, _ = masked_accuracy(logits, y, w)
+        tp, fp, fn = masked_binary_counts(logits, y, w)
+        return loss_sum, acc_sum, count, tp, fp, fn
 
 
 def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
@@ -220,14 +234,15 @@ def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
     total = jnp.maximum(weight.sum() * positions, 1.0)
 
     def chunk_loss(params, cx, cy, cw, rng):
-        logits, updates = state.apply_fn(
-            cast_params_by_rules(params), cx, train=True,
-            rngs={"dropout": rng}, mutable=_SOWN,
-        )
-        loss_sum, _ = masked_cross_entropy(
-            logits, cy, _position_weight(logits, cy, cw)
-        )
-        return _objective(updates, loss_sum / total, accum_steps)
+        with jax.named_scope("step.loss"):
+            logits, updates = state.apply_fn(
+                cast_params_by_rules(params), cx, train=True,
+                rngs={"dropout": rng}, mutable=_SOWN,
+            )
+            loss_sum, _ = masked_cross_entropy(
+                logits, cy, _position_weight(logits, cy, cw)
+            )
+            return _objective(updates, loss_sum / total, accum_steps)
 
     grad_fn = jax.value_and_grad(chunk_loss, has_aux=True)
 
@@ -246,9 +261,8 @@ def _train_accum_body(state: TrainState, x, y, weight, accum_steps: int):
     # Norm of the ACCUMULATED gradient — the update the optimizer sees.
     # Sown parameter steps average over the microbatches, as aux losses do.
     return (
-        _stepped(
-            state.apply_gradients(grads),
-            jax.tree.map(lambda c: c.mean(axis=0), steps)),
+        _optimizer_step(
+            state, grads, jax.tree.map(lambda c: c.mean(axis=0), steps)),
         loss, optax.global_norm(grads), _reduce_counters(counters),
     )
 

@@ -5,12 +5,15 @@ to (event log, span recorder, goodput ledger, heartbeat, health monitor,
 live metrics, fault plan, preemption guard) in the order the run needs them,
 ``arm_loop`` adds the three that depend on what the setup resolved
 (throughput timer, planned profiler window, flight recorder), and
-``end_loop`` is the hot loop's last word on every exit path. ``_Timed`` is
+``end_loop`` is the hot loop's last word on every exit path, after which
+``write_programs`` leaves the text of the programs that ran. ``_Timed`` is
 the one bracket that feeds the ledger and the span timeline from one pair
 of clock reads. Nothing here knows the setup, the loop or ``trainer.py``.
 """
 
 from __future__ import annotations
+
+import os
 
 import jax
 
@@ -243,3 +246,34 @@ class RunTelemetry:
         # read-after-emit visibility back.
         self.events.set_write_through()
         self.tracer.set_write_through()
+
+    def write_programs(self, executables: dict, *, coordinator) -> None:
+        """The optimized HLO text of each program the run ran (loaded or
+        compiled: the AOT store's ``executables``) as
+        ``<events dir>/programs/<key>.hlo.txt``, on rank 0 with the event
+        log on. Every instruction there carries its ``op_name`` (the
+        device scopes, the backward as ``transpose(jvp(..))``) under the
+        name a device profile prints it by, so the file joins any profile
+        of the run to its scopes and kernels. After the loop, outside
+        every timed window; the span ``trainer.program_text`` says what it
+        cost. A failed write loses the file, never the run."""
+        if not (executables and self.events.enabled):
+            return
+        if coordinator:
+            out = os.path.join(os.path.dirname(self.events.path), "programs")
+            timed = self.timed(
+                None, "trainer.program_text",
+                parent_id=self.fit_span.span_id,
+            ).begin()
+            written = 0
+            try:
+                os.makedirs(out, exist_ok=True)
+                for key, program in executables.items():
+                    text = program.as_text()
+                    with open(os.path.join(out, f"{key}.hlo.txt"), "w") as f:
+                        f.write(text)
+                    written += len(text)
+            except Exception as e:  # noqa: BLE001 — never fails a run
+                timed.end(bytes=written, error=type(e).__name__)
+            else:
+                timed.end(bytes=written)

@@ -120,8 +120,9 @@ class Trainer:
         compilecache.enable_from_env()
         tel = RunTelemetry.open(cfg, preempt_guard=self._preempt_guard)
         setup = prepare_fit(cfg, self.mesh, data, tel, tracker=self.tracker)
-        # Kept for whoever drives the trainer (the benchmark reads the
-        # epoch program's HLO text off ``aot_store.executables``).
+        # Kept for whoever drives the trainer (the epoch program's HLO
+        # text is ``aot_store.executables``; ``fit`` writes it beside the
+        # event log on every exit: ``RunTelemetry.write_programs``).
         self.aot_store = setup.aot_store
         tel.arm_loop(
             cfg, n_chips=self.mesh.size,
@@ -169,6 +170,10 @@ class Trainer:
                             completed=completed, preempted=preempted,
                             history=loop.history,
                             in_flight=loop.in_flight_spans(),
+                        )
+                        tel.write_programs(
+                            setup.aot_store.executables,
+                            coordinator=self.coordinator,
                         )
         return self._close(setup, loop, tel)
 

@@ -15,6 +15,7 @@ test file. Keep these tests in this one file for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -181,3 +182,71 @@ def test_dropout_bits_are_drawn_outside_the_gemm_fusions(
     assert not [
         line for line in collectives
         if "pred[" in line or f"u32[{b // chips},{t}" in line]
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "data4"])
+def test_the_epoch_program_tells_the_flash_kernels_apart(
+        topo, no_compile_cache, monkeypatch, chips):
+    """A one-layer causal model's fused epoch program at 256 positions,
+    compiled as the trainer builds it: the benchmark's reader of the
+    program text (``benchmark/reduce/program_text.py``) finds each of the
+    three kernels once a step and the validation pass's forward, and the
+    calls keep the names the trace prints them by (the attention module's
+    ``attn.<n>``, or ``shard_map.<n>`` inside the per-shard region of
+    ``data=4``). A Pallas ``name=`` would push a scope of its own and
+    rename them (``flash_fwd.<n>``), and the optimized text would still
+    not carry the kernel's name: so the kernels take none, and the text
+    tells them apart by the name stack and the result."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from benchmark.reduce import program_text
+    from dct_tpu.config import RunConfig
+    from dct_tpu.models.registry import get_model
+    from dct_tpu.ops import attention
+    from dct_tpu.parallel.mesh import AXES, stacked_batch_sharding
+    from dct_tpu.parallel.sharding_rules import state_shardings
+    from dct_tpu.train.state import create_train_state
+    from dct_tpu.train.steps import make_epoch_train_eval_step
+
+    monkeypatch.setattr(attention, "flash_interpret_mode", lambda: False)
+    for k, v in dict(DCT_MODEL="weather_transformer_causal", DCT_D_MODEL=256,
+                     DCT_N_HEADS=2, DCT_N_KV_HEADS=1, DCT_D_FF=512,
+                     DCT_N_LAYERS=1, DCT_SEQ_LEN=256).items():
+        monkeypatch.setenv(k, str(v))
+    cfg = RunConfig.from_env()
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(chips, 1, 1, 1), AXES)
+    model = get_model(cfg.model, input_dim=5, compute_dtype=jnp.bfloat16,
+                      mesh=mesh)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, input_dim=5, lr=1e-4, seed=0, example_shape=(1, 256, 5),
+        grad_clip_norm=1.0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        state, state_shardings(state, mesh, family=cfg.model.name))
+    stack = stacked_batch_sharding(mesh)
+
+    def stacks(n):
+        g = 2 * chips
+        return (
+            jax.ShapeDtypeStruct((n, g, 256, 5), jnp.float32, sharding=stack),
+            jax.ShapeDtypeStruct((n, g, 256), jnp.int32, sharding=stack),
+            jax.ShapeDtypeStruct((n, g), jnp.float32, sharding=stack))
+
+    fused = make_epoch_train_eval_step(
+        donate=False, donate_stacks=True, with_grad_norms=True)
+    text = fused.lower(state, *stacks(2), *stacks(1)).compile().as_text()
+    kernels = program_text.flash_kernels(text)
+    assert sorted(kernels.values()) == [
+        "flash_bwd_dkdv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    prefix = "attn." if chips == 1 else "shard_map."
+    assert all(name.startswith(prefix) for name in kernels)
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    # The training forward under the objective's scope, the validation
+    # forward under the validation pass's.
+    names = re.findall(
+        r'%(\S+) = .*?op_name="([^"]*)"', text)
+    fwd = {n: o for n, o in names if kernels.get(n) == "flash_fwd"}
+    assert sorted("step.eval" in o for o in fwd.values()) == [False, True]
+    assert all("jvp(step.loss)" in o or "step.eval" in o
+               for o in fwd.values())
