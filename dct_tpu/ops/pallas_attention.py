@@ -2,18 +2,34 @@
 
 The JAX-level paths in :mod:`dct_tpu.ops.attention` rely on XLA fusion;
 this kernel takes manual control of the memory hierarchy per the Pallas TPU
-playbook. The grid is ``(batch*heads, q_blocks, kv_blocks)`` with the KV
-block as the innermost (sequential) dimension, so VMEM residency per grid
-step is one ``[block_q, D]`` Q tile plus one ``[block_k, D]`` K/V tile —
-O(block) regardless of sequence length — while the online-softmax running
-stats (m, l, acc) persist in VMEM scratch across the KV sweep. The score
-matrix never exists in HBM, so memory is O(T·D) instead of O(T²); with
-``causal=True`` KV blocks entirely above the diagonal skip their MXU work,
-and with ``window`` set the sliding-window band also skips every block
-entirely behind the band (compute AND DMA, in forward and both backward
-kernels) — O(T·window) FLOPs instead of the causal O(T²/2). ``q_offset``
-statically shifts the q positions so the windowed ring's partial-band
-shards (q-k distance = step·T_local) reuse the same kernel.
+playbook. The forward's grid is ``(batch*heads, q_blocks, kv_steps)`` with
+the KV sweep as the innermost (sequential) dimension, so VMEM residency per
+grid step is one ``[block_q, D]`` Q tile plus one ``[block_k, D]`` K/V tile
+— O(block) regardless of sequence length — while the online-softmax
+running stats (m, l, acc) persist in VMEM scratch across the KV sweep. The
+score matrix never exists in HBM, so memory is O(T·D) instead of O(T²);
+with ``causal=True`` KV blocks entirely above the diagonal skip their MXU
+work and their DMA. ``q_offset`` statically shifts the q positions so the
+windowed ring's partial-band shards (q-k distance = step·T_local) reuse
+the same kernel.
+
+With ``window`` set the sweep runs over the band's tiles only (the band
+grid), O(T·window) grid steps as well as FLOPs: ``kv_steps`` is the most
+key tiles any query tile's rows ``[q_first - window + 1, q_last]`` touch,
+static from the tiles and the window (2 of 8 at 8,192 positions under a
+1,024 window on 1024 x 1024 tiles), and step ``s`` of query tile ``i``
+runs key tile ``start(i) + s``, ``start`` being the band's first tile,
+moved back where the sweep would run past the last one. The dK/dV
+kernel's Q sweep runs likewise over the query tiles whose band reaches its
+key tile. Each step still decides by the visibility test between no work,
+masked work and unmasked work, and a step that reaches no visible key
+re-addresses a resident block, so nothing is fetched for it; the tiles
+with work run in the order the full sweep runs them, so the results are
+the full sweep's to the bit. Where the band would cut no step (no window,
+or one that reaches every key tile, as a window of the sequence's length
+does) or a ``q_offset`` shifts it, the sweep runs over every key tile and
+the blocks behind the band skip compute and DMA.
+:func:`flash_grid_steps` counts the steps of either grid.
 
 The tiles are a function of the shape (:func:`flash_tiles`): the largest
 multiple of 128 that divides the sequence extent, up to a cap
@@ -159,6 +175,113 @@ def _resolve_tiles(block_q, block_k, t: int, tk: int, d: int, dtype,
     return block_q, block_k
 
 
+def flash_grid_steps(t: int, window: int | None, block_q: int, block_k: int,
+                     h: int, h_kv: int,
+                     b: int = 1) -> dict[str, tuple[int, int]]:
+    """``{kernel: (grid steps, steps with work)}`` for the three causal
+    kernels (``fwd``, ``dkdv``, ``dq``) on ``b`` rows of ``h`` query and
+    ``h_kv`` key-value heads at ``t`` positions on the given tiles: the
+    size of the grid each ``pallas_call`` is given (the band grid's where
+    it engages) and how many of its steps the visibility test sends to
+    the MXU. Static, from the shapes alone."""
+    n_q, n_kv = t // block_q, t // block_k
+    tiles = sum(
+        bool(_tile_visibility(
+            i * block_q, block_q, j * block_k, block_k, window)[0])
+        for i in range(n_q) for j in range(n_kv))
+    kv_steps = _kv_band(t, window, block_q, block_k) or n_kv
+    q_steps = _q_band(t, window, block_q, block_k) or n_q
+    work = b * h * tiles
+    return {
+        "fwd": (b * h * n_q * kv_steps, work),
+        "dkdv": (b * h_kv * n_kv * (h // h_kv) * q_steps, work),
+        "dq": (b * h * n_q * kv_steps, work),
+    }
+
+
+def _first_key_tile(i, block_q: int, block_k: int, window: int):
+    """The first key tile the band of query tile ``i`` reaches (its first
+    row's oldest visible key, ``i*bq - window + 1``), clamped to tile 0.
+    ``i`` is a Python int or a grid index."""
+    first = (i * block_q - window + 1) // block_k
+    return max(first, 0) if isinstance(first, int) else jnp.maximum(first, 0)
+
+
+def _last_key_tile(i, block_q: int, block_k: int):
+    """The last key tile query tile ``i`` sees: its last row's diagonal."""
+    return ((i + 1) * block_q - 1) // block_k
+
+
+def _first_query_tile(j, block_q: int, block_k: int):
+    """The first query tile that sees key tile ``j``: its first key's
+    diagonal."""
+    return (j * block_k) // block_q
+
+
+def _last_query_tile(j, block_q: int, block_k: int, window: int, n_q: int):
+    """The last query tile the band of key tile ``j`` reaches (its last
+    key's newest viewer, ``j*bk + bk - 1 + window - 1``), clamped to the
+    last query tile. ``j`` is a Python int or a grid index."""
+    last = ((j + 1) * block_k + window - 2) // block_q
+    return min(last, n_q - 1) if isinstance(last, int) else jnp.minimum(
+        last, n_q - 1)
+
+
+def _kv_band(t: int, window: int | None, block_q: int, block_k: int,
+             q_offset: int = 0) -> int | None:
+    """Steps of the forward's and dQ's key sweep under the band grid: the
+    most key tiles any query tile's band touches. None where the band
+    grid would cut no step (no window, or a band that touches every key
+    tile) or a ``q_offset`` shifts the band: the sweep then runs over
+    every key tile."""
+    if window is None or q_offset:
+        return None
+    steps = max(
+        _last_key_tile(i, block_q, block_k)
+        - _first_key_tile(i, block_q, block_k, window) + 1
+        for i in range(t // block_q))
+    return steps if steps < t // block_k else None
+
+
+def _q_band(t: int, window: int | None, block_q: int,
+            block_k: int) -> int | None:
+    """Steps of the dK/dV kernel's query sweep (for each q head of the
+    group) under the band grid: the most query tiles whose band reaches
+    one key tile; None as :func:`_kv_band`."""
+    if window is None:
+        return None
+    n_q = t // block_q
+    steps = max(
+        _last_query_tile(j, block_q, block_k, window, n_q)
+        - _first_query_tile(j, block_q, block_k) + 1
+        for j in range(t // block_k))
+    return steps if steps < n_q else None
+
+
+def _band_tile(first, steps: int, n_tiles: int, s):
+    """The tile of step ``s`` of a band sweep of ``steps`` steps: the
+    sweep starts at the band's ``first`` tile, moved back as far as it
+    takes to end on the last of ``n_tiles`` at the latest, so every step
+    addresses a tile that exists; the steps it moves back have no
+    visible key (causality or the band's edge)."""
+    return jnp.minimum(first, n_tiles - steps) + s
+
+
+def _band_kv_index(kv_row, block_q: int, block_k: int, window: int,
+                   n_kv: int, band: int):
+    """The K/V index map of the forward and dQ under the band grid: step
+    ``s`` of query tile ``i`` addresses the key tile it runs, held to the
+    band's tiles, so a step before or past them addresses the block its
+    neighbour step needs (fetched once: the repeat fetch is elided)."""
+    def kv_index(bh, i, s):
+        first = _first_key_tile(i, block_q, block_k, window)
+        tile = _band_tile(first, band, n_kv, s)
+        tile = jnp.minimum(jnp.maximum(tile, first),
+                           _last_key_tile(i, block_q, block_k))
+        return (kv_row(bh), tile, 0)
+    return kv_index
+
+
 def _tile_visibility(q_first, bq: int, k_first, bk: int, window):
     """For the causal (banded) tile of q rows ``[q_first, q_first+bq)``
     against keys ``[k_first, k_first+bk)``: ``(work, cut)``. ``work``:
@@ -207,7 +330,9 @@ def _run_tile(block, causal: bool, q_first, bq: int, k_first, bk: int,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
                       n_kv: int, causal: bool, scale: float,
                       with_lse: bool, window: int | None = None,
-                      q_offset: int = 0):
+                      q_offset: int = 0, band: int | None = None):
+    """``band``: the key sweep's steps under the band grid
+    (:func:`_kv_band`), None for the sweep over all ``n_kv`` key tiles."""
     if with_lse:
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -264,10 +389,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_k: int,
     # recovers O(T*window) FLOPs from the O(T^2) causal sweep.
     # ``q_offset`` shifts the q positions (the windowed ring's static
     # inter-shard distance); k positions stay 0-based.
-    _run_tile(_block, causal, q_offset + qi * bq, bq, j * block_k, block_k,
+    kv = j if band is None else _band_tile(
+        _first_key_tile(qi, bq, block_k, window), band, n_kv, j)
+    _run_tile(_block, causal, q_offset + qi * bq, bq, kv * block_k, block_k,
               window)
 
-    @pl.when(j == n_kv - 1)
+    @pl.when(j == (n_kv if band is None else band) - 1)
     def _finalize():
         l = l_ref[:, :1]
         o_ref[...] = (acc_ref[...] / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
@@ -310,17 +437,22 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
     block_q, block_k = _resolve_tiles(
         block_q, block_k, t, tk, d, q.dtype, d_v)
     n_kv = tk // block_k
+    band = _kv_band(t, window, block_q, block_k, q_offset)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h_kv, tk, d)
     vf = v.reshape(b * h_kv, tk, d_v)
     kernel = functools.partial(
         _flash_fwd_kernel, block_k=block_k, n_kv=n_kv, causal=causal,
         scale=scale, with_lse=with_lse, window=window, q_offset=q_offset,
+        band=band,
     )
     def kv_bh(bh):
         return _kv_flat_row(bh, h, h_kv)
 
-    if causal:
+    if band is not None:
+        kv_index = _band_kv_index(kv_bh, block_q, block_k, window, n_kv,
+                                  band)
+    elif causal:
         # Skipped blocks would otherwise still be DMA'd: clamp the index
         # map so they re-address a needed block (already resident -> the
         # fetch is elided). Above-diagonal blocks clamp down (~half the
@@ -361,7 +493,7 @@ def _flash_fwd(q, k, v, *, block_q: int | None = None,
         )
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, t // block_q, n_kv),
+        grid=(b * h, t // block_q, n_kv if band is None else band),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, block_k, d), kv_index),
@@ -406,16 +538,21 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                            dk_ref, dv_ref, dk_acc, dv_acc, *,
                            block_q: int, n_q: int, causal: bool,
                            scale: float, window: int | None = None,
-                           group: int = 1):
-    """dK/dV: grid (b*h_kv, kv blocks, group*n_q). The innermost sweep
-    runs the GROUP's q heads back to back (i = member*n_q + qi) into one
-    sequential accumulator — that is how GQA stays kernel-resident here:
-    a q-head-parallel grid would race grouped dk/dv. With group == 1 this
-    is exactly the classic per-head sweep."""
+                           group: int = 1, band: int | None = None):
+    """dK/dV: grid (b*h_kv, kv blocks, group*steps), ``steps`` being
+    ``n_q`` or, under the band grid, ``band`` (:func:`_q_band`). The
+    innermost sweep runs the GROUP's q heads back to back (i =
+    member*steps + step) into one sequential accumulator — that is how
+    GQA stays kernel-resident here: a q-head-parallel grid would race
+    grouped dk/dv. With group == 1 this is exactly the classic per-head
+    sweep."""
     j = pl.program_id(1)
     i = pl.program_id(2)
-    qi = i % n_q  # q block WITHIN the current group member's sweep
+    steps = n_q if band is None else band
+    qi = i % steps  # q block WITHIN the current group member's sweep
     bk = k_ref.shape[0]
+    if band is not None:
+        qi = _band_tile(_first_query_tile(j, block_q, bk), band, n_q, qi)
 
     @pl.when(i == 0)
     def _init():
@@ -452,7 +589,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     # first query is still inside the band of the block's last key).
     _run_tile(_block, causal, qi * block_q, block_q, j * bk, bk, window)
 
-    @pl.when(i == group * n_q - 1)
+    @pl.when(i == group * steps - 1)
     def _finalize():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -461,7 +598,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                          dq_ref, dq_acc, *, block_k: int, n_kv: int,
                          causal: bool, scale: float,
-                         window: int | None = None):
+                         window: int | None = None, band: int | None = None):
+    """dQ: the forward's grid and key sweep (``band`` as there)."""
     i = pl.program_id(1)
     j = pl.program_id(2)
     bq = q_ref.shape[0]
@@ -488,9 +626,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )
 
-    _run_tile(_block, causal, i * bq, bq, j * block_k, block_k, window)
+    kv = j if band is None else _band_tile(
+        _first_key_tile(i, bq, block_k, window), band, n_kv, j)
+    _run_tile(_block, causal, i * bq, bq, kv * block_k, block_k, window)
 
-    @pl.when(j == n_kv - 1)
+    @pl.when(j == (n_kv if band is None else band) - 1)
     def _finalize():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -530,26 +670,36 @@ def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
     block_q, block_k = _resolve_tiles(
         block_q, block_k, t, t, d, q.dtype, d_v)
     n_q = t // block_q
+    band = _q_band(t, window, block_q, block_k)
+    steps = n_q if band is None else band
     operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
 
     # Same DMA-elision trick as the forward: clamp skipped blocks'
     # addresses onto a needed (resident) block so their fetch is elided.
-    # The sweep is i = member*n_q + qi per kv block j (grid row is a KV
-    # head): causal needs qi >= j*bk/bq, a window needs
+    # The sweep is i = member*steps + step per kv block j (grid row is a
+    # KV head): causal needs qi >= j*bk/bq, a window needs
     # qi*bq <= window + (j+1)*bk - 2; the flat q row is the member's head.
     def q_row(bh, i):
         if group == 1:
             return bh
-        return (bh // h_kv) * h + (bh % h_kv) * group + i // n_q
+        return (bh // h_kv) * h + (bh % h_kv) * group + i // steps
 
-    def q_index(bh, j, i):
-        qi = i % n_q
-        if causal:
-            qi = jnp.maximum(qi, (j * block_k) // block_q)
-            if window is not None:
-                i_last = (window + (j + 1) * block_k - 2) // block_q
-                qi = jnp.minimum(qi, jnp.maximum(i_last, 0))
-        return (q_row(bh, i), qi, 0)
+    if band is not None:
+        def q_index(bh, j, i):
+            first = _first_query_tile(j, block_q, block_k)
+            qi = _band_tile(first, band, n_q, i % band)
+            qi = jnp.minimum(jnp.maximum(qi, first), _last_query_tile(
+                j, block_q, block_k, window, n_q))
+            return (q_row(bh, i), qi, 0)
+    else:
+        def q_index(bh, j, i):
+            qi = i % n_q
+            if causal:
+                qi = jnp.maximum(qi, (j * block_k) // block_q)
+                if window is not None:
+                    i_last = (window + (j + 1) * block_k - 2) // block_q
+                    qi = jnp.minimum(qi, jnp.maximum(i_last, 0))
+            return (q_row(bh, i), qi, 0)
 
     q_spec = pl.BlockSpec((None, block_q, d), q_index)
     o_spec = pl.BlockSpec((None, block_q, d_v), q_index)
@@ -560,8 +710,9 @@ def _flash_bwd_dkdv(q, k, v, o, lse, do, *, block_q: int | None = None,
         functools.partial(
             _flash_bwd_dkdv_kernel, block_q=block_q, n_q=n_q,
             causal=causal, scale=scale, window=window, group=group,
+            band=band,
         ),
-        grid=(b * h_kv, t // block_k, group * n_q),
+        grid=(b * h_kv, t // block_k, group * steps),
         in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec],
         out_specs=[k_spec, v_spec],
         out_shape=[
@@ -591,12 +742,16 @@ def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
     block_q, block_k = _resolve_tiles(
         block_q, block_k, t, t, d, q.dtype, d_v)
     n_kv = t // block_k
+    band = _kv_band(t, window, block_q, block_k)
     operands, vma_kw = _bwd_operands(q, k, v, o, lse, do)
     kv_row = lambda bh: _kv_flat_row(bh, h, h_kv)
 
     # Same clamp as the forward's kv_index (above-diagonal down,
     # behind-the-band up), KV rows divided to the grouped head.
-    if causal:
+    if band is not None:
+        kv_index = _band_kv_index(kv_row, block_q, block_k, window, n_kv,
+                                  band)
+    elif causal:
         def kv_index(bh, i, j):
             jj = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
             if window is not None:
@@ -618,9 +773,9 @@ def _flash_bwd_dq(q, k, v, o, lse, do, *, block_q: int | None = None,
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, block_k=block_k, n_kv=n_kv,
-            causal=causal, scale=scale, window=window,
+            causal=causal, scale=scale, window=window, band=band,
         ),
-        grid=(b * h, t // block_q, n_kv),
+        grid=(b * h, t // block_q, n_kv if band is None else band),
         in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, lse_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype, **vma_kw),
@@ -654,8 +809,12 @@ def flash_attention(q, k, v, block_q=None, block_k=None, causal=False,
     (tests, sweeps).
 
     ``window`` (causal-only sliding window): the band mask lives in the
-    kernel and fully-out-of-band KV tiles skip compute AND DMA — the
-    causal O(T^2/2) sweep becomes O(T*window)."""
+    kernel, and where the band leaves some key tile out of every query
+    tile's reach the three kernels run the band grid: their inner sweep
+    covers the band's tiles only, so grid steps as well as FLOPs and DMA
+    go from the causal O(T^2/2) to O(T*window), with results equal to
+    the full sweep's to the bit. A window that reaches every tile keeps
+    the full sweep, where out-of-band tiles skip compute and DMA."""
     return _flash_fwd(
         q, k, v, block_q=block_q, block_k=block_k, causal=causal,
         scale=scale, interpret=interpret, window=window,

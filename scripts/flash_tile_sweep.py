@@ -2,6 +2,7 @@
 """Time the three Mosaic flash kernels over a grid of tiles, on the chip.
 
     chiprun -- python scripts/flash_tile_sweep.py [--root build/parent]
+        [--against build/parent]
     JAX_PLATFORMS=cpu python scripts/flash_tile_sweep.py --compile-only
 
 The sweep behind ``pallas_attention.flash_tiles`` (PERF.md section 6, PR
@@ -11,9 +12,23 @@ the dQ kernel, runs each ``--iters`` times and prints one JSON line per
 shape with the median milliseconds per kernel. A tile the compiler refuses
 (alignment, scoped VMEM) is recorded as the refusal, not as a time.
 
+Under a window the kernels run the band grid (``pallas_attention``'s
+module docstring): the inner sweep covers the band's tiles only, so a
+smaller tile costs no more empty grid steps than a larger one, and each
+line's ``grid_steps`` (``flash_grid_steps``: a call's grid steps and the
+steps with work, per kernel and tile pair) says which grid ran. A tree
+without the band grid runs every tile of the sequence whatever the band
+skips, which charges small tiles 4x to 16x more empty steps at 8,192
+positions: compare tile sizes under a band only on a tree that has it.
+
 ``--root`` imports ``dct_tpu`` from another checkout (the parent commit,
 unpacked with ``git archive``): a tree whose backward is one entry point
 (``_flash_bwd``, both kernels on one tile) is timed as ``bwd``.
+``--against <checkout>`` runs that checkout's three kernels beside these
+on the same inputs at every tile pair and records whether the outputs,
+the lse and the three gradients are equal to the bit (``same_bits``) and
+the largest difference of each: the check that a change to the grid
+leaves the numbers alone.
 ``--always-mask`` times the change's kernels with the mask built on every
 working tile, as the parent builds it: the A/B for the interior-tile skip.
 ``--compile-only`` compiles for a described v5e without a chip and times
@@ -89,8 +104,53 @@ def _mask_every_tile(pa) -> None:
     pa._run_tile = run_tile
 
 
+def _load_kernels(checkout: str):
+    """Another checkout's ``pallas_attention``, imported beside this
+    tree's under a name of its own."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "against_pallas_attention",
+        os.path.join(checkout, "dct_tpu", "ops", "pallas_attention.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _outputs(pa, arrays, window, bq, bk) -> dict:
+    """The forward's output and lse and the three gradients of one tree's
+    kernels on the tile pair, as host arrays."""
+    import jax
+    import numpy as np
+
+    kw = dict(block_q=bq, block_k=bk, causal=True, scale=None,
+              interpret=False, window=window)
+
+    def run(q, k, v, do):
+        o, lse = pa._flash_fwd(q, k, v, with_lse=True, **kw)
+        dk, dv = pa._flash_bwd_dkdv(q, k, v, o, lse, do, **kw)
+        dq = pa._flash_bwd_dq(q, k, v, o, lse, do, **kw)
+        return {"o": o, "lse": lse, "dq": dq, "dk": dk, "dv": dv}
+
+    out = jax.jit(run)(*(arrays[n] for n in ("q", "k", "v", "do")))
+    return {n: np.asarray(a, np.float32) for n, a in out.items()}
+
+
+def _same_bits(pa, other, arrays, window, bq, bk) -> dict:
+    import numpy as np
+
+    mine = _outputs(pa, arrays, window, bq, bk)
+    theirs = _outputs(other, arrays, window, bq, bk)
+    return {
+        "same_bits": all(
+            np.array_equal(mine[n], theirs[n]) for n in mine),
+        "max_abs_diff": {
+            n: float(np.max(np.abs(mine[n] - theirs[n]))) for n in mine},
+    }
+
+
 def sweep(shape_name: str, tiles, iters: int, compile_only: bool,
-          always_mask: bool = False) -> dict:
+          always_mask: bool = False, against: str | None = None) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -99,6 +159,7 @@ def sweep(shape_name: str, tiles, iters: int, compile_only: bool,
 
     if always_mask:
         _mask_every_tile(pa)
+    other = _load_kernels(against) if against else None
 
     b, h, h_kv, t, d, window, *rest = SHAPES[shape_name]
     d_v = rest[0] if rest else d
@@ -136,6 +197,9 @@ def sweep(shape_name: str, tiles, iters: int, compile_only: bool,
             if t % bq or t % bk:
                 continue
             row = {}
+            if hasattr(pa, "flash_grid_steps"):
+                row["grid_steps"] = pa.flash_grid_steps(
+                    t, window, bq, bk, h, h_kv, b=b)
             for name, (fn, names) in _kernels(pa, window, bq, bk).items():
                 args = [arrays[n] for n in names]
                 try:
@@ -157,6 +221,8 @@ def sweep(shape_name: str, tiles, iters: int, compile_only: bool,
                     jax.block_until_ready(compiled(*args))
                     times.append((time.perf_counter() - t0) * 1e3)
                 row[name] = round(statistics.median(times), 3)
+            if other is not None and not compile_only:
+                row.update(_same_bits(pa, other, arrays, window, bq, bk))
             rows[f"{bq}x{bk}"] = row
             print(f"[sweep] {shape_name} {bq}x{bk} {row}",
                   file=sys.stderr, flush=True)
@@ -173,6 +239,8 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--always-mask", action="store_true")
+    ap.add_argument("--against", default=None,
+                    help="compare outputs with this checkout's kernels")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
     root = os.path.abspath(args.root or os.path.join(
@@ -192,9 +260,9 @@ def main() -> int:
     lines = []
     for name in args.shapes:
         res = sweep(name, args.tiles, args.iters, args.compile_only,
-                    args.always_mask)
+                    args.always_mask, args.against)
         res.update(device=device, root=root, compile_only=args.compile_only,
-                   always_mask=args.always_mask)
+                   always_mask=args.always_mask, against=args.against)
         lines.append(json.dumps(res))
         print(lines[-1], flush=True)
     if args.out:

@@ -1,6 +1,8 @@
 """Pallas flash-attention kernel vs the dense oracle (interpret mode on the
 CPU rig; the same kernel compiles via Mosaic on TPU)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ import pytest
 
 from dct_tpu.ops.attention import dense_attention
 from dct_tpu.ops.pallas_attention import flash_attention
+from tests.test_flash_compile_v5e import SHAPES
 
 B, H, T, D = 2, 2, 128, 16
 
@@ -309,3 +312,141 @@ def test_flash_lse_q_offset_matches_blockwise(rng, t, block, window,
         np.testing.assert_allclose(
             np.asarray(lse_k)[finite], np.asarray(lse_b)[finite], atol=1e-5
         )
+
+
+# --- the band grid: the sweep over the band's tiles only -----------------
+
+
+#: (T, window, block_q, block_k, q heads, kv heads), each a window under
+#: which the band grid engages (some key tile is out of every query tile's
+#: reach): windows that are no multiple of the tile, one narrower than a
+#: tile, a band wider than a tile and narrower than T, rectangular tile
+#: pairs either way round (the last two also move a sweep's start back
+#: from the band's first tile, in the forward and in dK/dV), and GQA at
+#: group 1, 2 and 4. In every case the first query tiles' band reaches
+#: before position 0.
+BAND_CASES = [
+    (1024, 384, 128, 128, 2, 2),
+    (1024, 100, 128, 128, 2, 1),
+    (2048, 512, 256, 256, 2, 2),
+    (1024, 256, 256, 128, 4, 2),
+    (1024, 300, 128, 256, 2, 2),
+    (1024, 300, 256, 128, 4, 1),
+]
+
+
+def _band_qkv(rng, t, h, h_kv):
+    shape = lambda heads: (1, heads, t, BIG_D)
+    return tuple(
+        jnp.asarray(rng.standard_normal(shape(n)), jnp.float32)
+        for n in (h, h_kv, h_kv)
+    )
+
+
+def _dense_lse(q, k, window):
+    """log-sum-exp of the band-masked scores, from the full matrix."""
+    from dct_tpu.ops.attention import expand_kv
+
+    k, _ = expand_kv(q, k, k)
+    s = jnp.einsum("...qd,...kd->...qk", q, k) / np.sqrt(q.shape[-1])
+    pos = jnp.arange(q.shape[2])
+    diff = pos[:, None] - pos[None, :]
+    s = jnp.where((diff >= 0) & (diff < window), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+def _engages(t, window, block_q, block_k):
+    from dct_tpu.ops import pallas_attention as pa
+
+    return (pa._kv_band(t, window, block_q, block_k) is not None
+            and pa._q_band(t, window, block_q, block_k) is not None)
+
+
+@pytest.mark.parametrize("t,window,block_q,block_k,h,h_kv", BAND_CASES)
+def test_flash_band_grid_matches_dense(rng, t, window, block_q, block_k,
+                                       h, h_kv):
+    """The forward on the band grid: outputs and lse against the dense
+    oracle, every row (the first tiles' clamped bands among them)."""
+    from dct_tpu.ops.pallas_attention import flash_attention_lse
+
+    assert _engages(t, window, block_q, block_k)
+    q, k, v = _band_qkv(rng, t, h, h_kv)
+    o, lse = flash_attention_lse(
+        q, k, v, block_q, block_k, True, None, True, window)
+    ref = dense_attention(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(_dense_lse(q, k, window)), atol=1e-5)
+
+
+@pytest.mark.parametrize("t,window,block_q,block_k,h,h_kv", BAND_CASES)
+def test_flash_band_grid_grad_matches_dense(rng, t, window, block_q, block_k,
+                                            h, h_kv):
+    """dQ and dK/dV on the band grid against AD through the dense oracle,
+    under a weighted cotangent."""
+    assert _engages(t, window, block_q, block_k)
+    q, k, v = _band_qkv(rng, t, h, h_kv)
+    w = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def loss_flash(q, k, v):
+        return (flash_attention(
+            q, k, v, block_q=block_q, block_k=block_k, causal=True,
+            interpret=True, window=window,
+        ) * w).sum()
+
+    def loss_dense(q, k, v):
+        return (dense_attention(q, k, v, causal=True, window=window)
+                * w).sum()
+
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for gf, gd in zip(g_flash, g_dense):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd), atol=1e-4)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr."""
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return [
+        tuple(e.params["grid_mapping"].grid) for e in jaxpr.jaxpr.eqns
+        if e.primitive.name == "pallas_call"
+    ]
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_flash_grid_steps_at_the_cells_shapes(shape):
+    """``flash_grid_steps`` against the grids the three ``pallas_call``s
+    receive at each cell's shape on the rule's tiles (the shapes the v5e
+    compile test holds the kernels to): the band grid at mellum2's
+    sliding layer (2,048 -> 512 steps a call, 480 with work), and every
+    other call's full grid, ``(b*h, T/bq, T/bk)``."""
+    from dct_tpu.ops import pallas_attention as pa
+
+    b, h, h_kv, t, d, window, *rest = SHAPES[shape]
+    d_v = rest[0] if rest else d
+    bq, bk = pa.flash_tiles(t, t, d, jnp.bfloat16, d_v)
+    counts = pa.flash_grid_steps(t, window, bq, bk, h, h_kv, b=b)
+    full = b * h * (t // bq) * (t // bk)
+    if shape == "mellum2_12b_swa_seq8192":
+        assert counts == {k: (512, 480) for k in ("fwd", "dkdv", "dq")}
+        assert full == 2048
+    else:
+        assert {k: n for k, (n, _) in counts.items()} == {
+            "fwd": full, "dkdv": full, "dq": full}
+
+    arg = lambda heads, width, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (b, heads, t, width), dtype)
+    q, k, v, o = arg(h, d), arg(h_kv, d), arg(h_kv, d_v), arg(h, d_v)
+    lse = jax.ShapeDtypeStruct((b, h, t), jnp.float32)
+    kw = dict(causal=True, scale=None, interpret=False, window=window)
+    grids = {
+        "fwd": _pallas_grids(
+            lambda q, k, v: pa._flash_fwd(q, k, v, with_lse=True, **kw),
+            q, k, v),
+        "dkdv": _pallas_grids(
+            lambda *a: pa._flash_bwd_dkdv(*a, **kw), q, k, v, o, lse, o),
+        "dq": _pallas_grids(
+            lambda *a: pa._flash_bwd_dq(*a, **kw), q, k, v, o, lse, o),
+    }
+    for kernel, (grid,) in grids.items():
+        assert math.prod(grid) == counts[kernel][0], kernel
