@@ -146,6 +146,17 @@ class ModelConfig:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_attention_factor: float = 0.0
+    # Causal families: what one kind of attention layer does not share
+    # with the model, ";"-separated per kind, "kind:key=value,..." with
+    # keys n_heads, rope_theta and rotary_fraction (the rotated share of
+    # each head: its first dims), e.g.
+    # "sliding_attention:n_heads=64,rope_theta=10000;full_attention:
+    # rotary_fraction=0.5"; a key left out is the model's
+    # (models/transformer.py:AttnKind). ``attn_gate`` "per_head": a sigmoid
+    # gate on each attention head's output, from the layer's normed input,
+    # before the output projection ("" = none).
+    attn_kinds: str = ""
+    attn_gate: str = ""
     # The transformer block's form, each default the block the families
     # started with: normalisation ("layernorm" | "rmsnorm") and its
     # epsilon, the dense MLP ("gelu" | "swiglu": a gated MLP with three
@@ -243,6 +254,10 @@ class ModelConfig:
         c.rope_attention_factor = _env(
             "DCT_ROPE_ATTENTION_FACTOR", c.rope_attention_factor, float
         )
+        c.attn_kinds = _env("DCT_ATTN_KINDS", c.attn_kinds, str)
+        c.attn_gate = _env(
+            "DCT_ATTN_GATE", c.attn_gate, str
+        ).strip().lower()
         c.norm = _env("DCT_NORM", c.norm, str).strip().lower()
         c.norm_eps = _env("DCT_NORM_EPS", c.norm_eps, float)
         c.mlp = _env("DCT_MLP", c.mlp, str).strip().lower()
@@ -1335,6 +1350,8 @@ ENV_REGISTRY: dict[str, str] = {
     "DCT_ROPE_BETA_FAST": "yarn: turns at which a dim keeps its frequency (default 32)",
     "DCT_ROPE_BETA_SLOW": "yarn: turns at which a dim is fully interpolated (default 1)",
     "DCT_ROPE_ATTENTION_FACTOR": "yarn: factor on cos and sin (0 = 0.1 ln(factor) + 1)",
+    "DCT_ATTN_KINDS": "per kind of attention layer: kind:n_heads=..,rope_theta=..,rotary_fraction=..[;kind:...]",
+    "DCT_ATTN_GATE": "sigmoid gate on each attention head's output: '' | per_head",
     "DCT_NORM": "block normalisation: layernorm | rmsnorm",
     "DCT_NORM_EPS": "normalisation epsilon (default 1e-6)",
     "DCT_MLP": "dense MLP: gelu | swiglu (gated, three matrices)",

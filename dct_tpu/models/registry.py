@@ -138,7 +138,38 @@ def _block_form(cfg: ModelConfig) -> dict:
         norm=cfg.norm, norm_eps=cfg.norm_eps, mlp=cfg.mlp,
         use_bias=cfg.use_bias, qk_norm=cfg.qk_norm,
         rope_theta=cfg.rope_theta, head_dim=cfg.head_dim or None,
+        attn_kinds=_attn_kinds(cfg.attn_kinds), attn_gate=cfg.attn_gate,
     )
+
+
+_ATTN_KIND_KEYS = {"n_heads": int, "rope_theta": float,
+                   "rotary_fraction": float}
+
+
+def _attn_kinds(text: str) -> tuple:
+    """``attn_kinds``' grammar (config.py) as pairs of kind and
+    :class:`AttnKind`, in the order given; "" = ()."""
+    from dct_tpu.models.transformer import AttnKind
+
+    kinds = []
+    for clause in filter(None, (c.strip() for c in text.split(";"))):
+        kind, _, fields = clause.partition(":")
+        kind = kind.strip()
+        if kind not in ("full_attention", "sliding_attention"):
+            raise ValueError(
+                f"attn_kinds clause {clause!r}: the kind must be "
+                "'full_attention' or 'sliding_attention'")
+        values = {}
+        for item in filter(None, (f.strip() for f in fields.split(","))):
+            key, _, value = item.partition("=")
+            key = key.strip()
+            if key not in _ATTN_KIND_KEYS:
+                raise ValueError(
+                    f"attn_kinds clause {clause!r}: unknown key {key!r} "
+                    f"(known: {', '.join(_ATTN_KIND_KEYS)})")
+            values[key] = _ATTN_KIND_KEYS[key](value)
+        kinds.append((kind, AttnKind(**values)))
+    return tuple(kinds)
 
 
 def _rope_scaling(cfg: ModelConfig) -> tuple:

@@ -75,11 +75,15 @@ FAMILY_RULES: dict = {
     "weather_transformer_causal": _TENSOR_PARALLEL_RULES,
     "weather_transformer_pp": _TENSOR_PARALLEL_RULES,
     "weather_moe": _TENSOR_PARALLEL_RULES,
-    # The gated MLP's second column-parallel matrix and the gated
-    # experts' third stack.
+    # The gated MLP's second column-parallel matrix, the gated
+    # experts' third stack, and the attention heads' gate: its H outputs
+    # are the q heads in qkv_proj's group-major order, so a model-axis
+    # shard of them is the same whole groups qkv_proj's shard holds.
     "weather_hybrid_moe_causal": (
         (r"(^|/)experts_gate_kernel$", P("model", None, None)),
         (r"ffn_gate.*/kernel$", P(None, "model")),
+        (r"(^|/)gate_proj/kernel$", P(None, "model")),
+        (r"(^|/)gate_proj/bias$", P("model")),
     ) + _TENSOR_PARALLEL_RULES,
 }
 

@@ -418,8 +418,9 @@ def test_flash_grid_steps_at_the_cells_shapes(shape):
     """``flash_grid_steps`` against the grids the three ``pallas_call``s
     receive at each cell's shape on the rule's tiles (the shapes the v5e
     compile test holds the kernels to): the band grid at mellum2's
-    sliding layer (2,048 -> 512 steps a call, 480 with work), and every
-    other call's full grid, ``(b*h, T/bq, T/bk)``."""
+    sliding layer (2,048 -> 512 steps a call, 480 with work) and at
+    laguna's (a 512 band on 1024-row tiles: 4,096 -> 1,024 steps, 960 with
+    work), and every other call's full grid, ``(b*h, T/bq, T/bk)``."""
     from dct_tpu.ops import pallas_attention as pa
 
     b, h, h_kv, t, d, window, *rest = SHAPES[shape]
@@ -430,6 +431,10 @@ def test_flash_grid_steps_at_the_cells_shapes(shape):
     if shape == "mellum2_12b_swa_seq8192":
         assert counts == {k: (512, 480) for k in ("fwd", "dkdv", "dq")}
         assert full == 2048
+    elif shape == "laguna_xs2_swa_seq8192":
+        # Two key tiles a query tile, one where the first has no earlier.
+        assert counts == {k: (1024, 960) for k in ("fwd", "dkdv", "dq")}
+        assert full == 4096
     else:
         assert {k: n for k, (n, _) in counts.items()} == {
             "fwd": full, "dkdv": full, "dq": full}
