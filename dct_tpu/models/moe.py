@@ -38,8 +38,9 @@ a third is the dropless layer of the hybrid family
   the balance loss below and no bias), weights normalised over the
   chosen experts,
   bias-free SwiGLU experts, and NO dropping: the routed rows are sorted by
-  expert and go through three grouped products (``jax.lax.ragged_dot``, a
-  Mosaic grouped matmul on the TPU), a static chunk of rows at a time, as
+  expert and go through three grouped products
+  (:mod:`dct_tpu.ops.grouped_matmul`: a Pallas grouped matmul on the TPU,
+  ``jax.lax.ragged_dot`` elsewhere), a static chunk of rows at a time, as
   many chunks as hold the rows that were routed this step
   (``_chunked_moe``). The layer is
   told which experts it holds (``experts_held``, ``first_expert``): the
@@ -73,6 +74,7 @@ from flax import linen as nn
 
 from dct_tpu.models.mlp import TorchStyleDense, torch_linear_init
 from dct_tpu.models.transformer import MultiHeadAttention, sincos_positions
+from dct_tpu.ops import grouped_matmul
 
 
 def _expert_ffn(batch, w_in, b_in, w_out, b_out):
@@ -144,31 +146,40 @@ def _held_rows(topi, *, held: int, first_expert: int):
     return jnp.argsort(flat, stable=True), rows
 
 
+def _chunk_sizes(rows, *, chunk: int, index):
+    """Each held expert's rows among sorted rows ``index * chunk .. (index
+    + 1) * chunk - 1`` ([held]; with ``index`` of shape [c, 1], [c, held])."""
+    first = index * chunk
+    ends = jnp.cumsum(rows)
+    return (jnp.clip(ends, first, first + chunk)
+            - jnp.clip(ends - rows, first, first + chunk))
+
+
 def _chunk_rows(tokens, order, rows, *, k: int, chunk: int, index):
     """Sorted rows ``index * chunk .. (index + 1) * chunk - 1``: (x
     [chunk, D], each row's token; row [chunk], its place among the N * k;
     ``zeroed``, which zeroes a [chunk, ...] past the routed rows, as x is;
-    ``product``, the grouped product of such rows with a held stack,
-    zeroed). ``order`` must reach the chunk's end.
+    ``product``, the grouped product of such rows with a held stack, or
+    with ``transpose=True`` with its transpose, zeroed). ``order`` must
+    reach the chunk's end.
 
-    A grouped product is ``jax.lax.ragged_dot``: on the TPU a Mosaic
-    grouped matmul that visits only the tiles the group sizes cover. Past
-    the routed rows its output is whatever the buffer held, NaN included,
-    and so is the cotangent its transpose hands back: the rows are zeroed
-    going in and after EVERY product, so nothing the tail holds reaches
-    the result, a gradient, or (as 0 x NaN) the next product's backward."""
+    A grouped product (:func:`grouped_matmul.grouped_product`) visits only
+    the row tiles the group sizes cover. Past the routed rows its output
+    is whatever the buffer held, NaN included, and so is the cotangent its
+    transpose hands back: the rows are zeroed going in and after EVERY
+    product, so nothing the tail holds reaches the result, a gradient, or
+    (as 0 x NaN) the next product's backward."""
     first = index * chunk
-    ends = jnp.cumsum(rows)
-    sizes = (jnp.clip(ends, first, first + chunk)
-             - jnp.clip(ends - rows, first, first + chunk))
-    valid = (first + jnp.arange(chunk) < ends[-1])[:, None]
+    sizes = _chunk_sizes(rows, chunk=chunk, index=index)
+    valid = (first + jnp.arange(chunk) < jnp.sum(rows))[:, None]
     row = lax.dynamic_slice_in_dim(order, first, chunk)
 
     def zeroed(a):
         return jnp.where(valid, a, 0)
 
-    def product(a, w):
-        return zeroed(lax.ragged_dot(a, w, sizes))
+    def product(a, w, transpose=False):
+        return zeroed(grouped_matmul.grouped_product(
+            a, w, sizes, transpose_rhs=transpose))
 
     return zeroed(tokens[row // k]), row, zeroed, product
 
@@ -190,19 +201,18 @@ def _grouped_chunk(tokens, gates, order, rows, w_gate, w_in, w_out, *,
     return y, row // k
 
 
-def _grouped_chunk_transposed(ct, tokens, gates, order, rows, stacks,
-                              transposed, *, chunk: int, index):
+def _grouped_chunk_transposed(ct, tokens, gates, order, rows, stacks, *,
+                              chunk: int, index):
     """What ``ct`` [N, D] f32, the cotangent of the layer's output, hands
     back through one chunk: (dx [chunk, D] for its rows' tokens, d_gate
     [chunk] f32 for their gates, row [chunk], and (x, h, dg, du, dy), the
     operands of the three stacks' gradients ``x^T dg``, ``x^T du`` and
     ``h^T dy``, which are left to the caller), all zero past the routed
     rows. :func:`_grouped_chunk` computed again and transposed step for
-    step, as autodiff would, less those three products; ``transposed`` are
-    the three ``stacks`` [held, out, in], which autodiff would form here."""
+    step, as autodiff would, less those three products; the products
+    against the transposed stacks read the stacks as they are."""
     k = gates.shape[1]
     w_gate, w_in, w_out = stacks
-    w_gate_t, w_in_t, w_out_t = transposed
     with jax.named_scope("moe.dispatch"):
         x, row, zeroed, product = _chunk_rows(
             tokens, order, rows, k=k, chunk=chunk, index=index)
@@ -216,8 +226,9 @@ def _grouped_chunk_transposed(ct, tokens, gates, order, rows, stacks,
         d_gate = (d_y * y.astype(jnp.float32)).sum(-1)
         dy = zeroed((d_y * gates.reshape(-1)[row][:, None]).astype(y.dtype))
     with jax.named_scope("moe.experts"):
-        dg, du = act(product(dy, w_out_t))
-        dx = product(dg, w_gate_t) + product(du, w_in_t)
+        dg, du = act(product(dy, w_out, transpose=True))
+        dx = (product(dg, w_gate, transpose=True)
+              + product(du, w_in, transpose=True))
     return dx, d_gate, row, (x, h, dg, du, dy)
 
 
@@ -292,8 +303,8 @@ def _chunk_loop_bwd(chunk, saved, cts):
     def body(index, carry):
         d_tokens, d_gates, kept = carry
         dx, d_gate, row, operands = _grouped_chunk_transposed(
-            ct, tokens, gates, order, rows, stacks, transposed,
-            chunk=chunk, index=index)
+            ct, tokens, gates, order, rows, stacks, chunk=chunk,
+            index=index)
         with jax.named_scope("moe.combine"):
             d_tokens = d_tokens.at[row // k].add(dx)
             d_gates = d_gates.at[row].add(d_gate)
@@ -303,12 +314,7 @@ def _chunk_loop_bwd(chunk, saved, cts):
                 for b, a in zip(kept, operands))
         return d_tokens, d_gates, kept
 
-    def stack_grad(a, d, w):
-        return jax.linear_transpose(
-            lambda w: lax.ragged_dot(a, w, rows), w)(d)[0]
-
     with jax.named_scope("moe.experts"):
-        transposed = tuple(jnp.swapaxes(w, 1, 2) for w in stacks)
         d, f = w_out.shape[2], w_out.shape[1]
         kept = tuple(
             jnp.zeros((order.shape[0], width), tokens.dtype)
@@ -317,8 +323,9 @@ def _chunk_loop_bwd(chunk, saved, cts):
         0, chunks, body,
         (jnp.zeros_like(tokens), jnp.zeros(gates.size, gates.dtype), kept))
     with jax.named_scope("moe.experts"):
-        d_stacks = (stack_grad(x, dg, w_gate), stack_grad(x, du, w_in),
-                    stack_grad(h, dy, w_out))
+        d_stacks = tuple(
+            grouped_matmul.grouped_weight_grad(a, b, rows)
+            for a, b in ((x, dg), (x, du), (h, dy)))
         # Only the optimizer waits for the stacks' gradients, so the
         # scheduler would hold every layer's buffers until it runs: tied
         # to the tokens' gradient they are computed here, and the buffers
@@ -331,6 +338,17 @@ def _chunk_loop_bwd(chunk, saved, cts):
 _chunk_loop.defvjp(_chunk_loop_fwd, _chunk_loop_bwd)
 
 
+def _tiled_rows(rows, *, chunk: int, top: int, tm: int):
+    """The rows the row tiles of one forward product cover (tile ``tm``,
+    :func:`grouped_matmul.tiled_rows`), summed over the chunks of the
+    ``top`` sorted rows: the chunks the loop ran hold every routed row and
+    the others none. ``rows.sum()`` where no group boundary cuts a
+    tile."""
+    index = jnp.arange(top // chunk)[:, None]
+    sizes = _chunk_sizes(rows, chunk=chunk, index=index)
+    return jax.vmap(lambda s: grouped_matmul.tiled_rows(s, tm))(sizes).sum()
+
+
 def _chunked_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
                  first_expert: int, chunk: int):
     """:func:`_grouped_moe` on the rows that were routed: as many chunks
@@ -338,9 +356,11 @@ def _chunked_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
     the layer gathers, masks and scatters follows the router's load and no
     row can be left out. Returns (out [N, D] f32, rows [held] int32, bound
     int32 = the rows of the chunks that ran, overflow int32 = routed rows
-    past them: 0 or the loop stopped early)."""
+    past them: 0 or the loop stopped early, tiled int32 = the rows the
+    grouped kernel's row tiles cover in one forward product of those
+    chunks)."""
     n, k = topi.shape
-    held = w_in.shape[0]
+    held, d, f = w_in.shape
     with jax.named_scope("moe.dispatch"):
         order, rows = _held_rows(topi, held=held, first_expert=first_expert)
         # Whole chunks that hold every row that can come.
@@ -349,7 +369,9 @@ def _chunked_moe(tokens, topi, gates, w_gate, w_in, w_out, *,
     out, chunks = _chunk_loop(
         chunk, tokens, gates, order, rows, w_gate, w_in, w_out)
     bound = chunks * chunk
-    return out, rows, bound, jnp.maximum(rows.sum() - bound, 0)
+    tm, _, _ = grouped_matmul.grouped_tiles(chunk, d, f, w_in.dtype, "gmm")
+    tiled = _tiled_rows(rows, chunk=chunk, top=top, tm=tm)
+    return out, rows, bound, jnp.maximum(rows.sum() - bound, 0), tiled
 
 
 class MoEFFN(nn.Module):
@@ -605,9 +627,10 @@ class MoEFFN(nn.Module):
         even share ``N * top_k * held / n_experts``), so no row is ever left
         out (``capacity_factor`` belongs to the engines that drop). Sows
         ``counters`` (rows per held expert, the rows of the chunks the
-        step ran, routed rows past them, the uniform expectation per
-        expert) and, for a caller that asks, ``intermediates`` (the
-        chosen experts)."""
+        step ran, routed rows past them, the rows the grouped kernel's row
+        tiles cover in one forward product of those chunks, the uniform
+        expectation per expert) and, for a caller that asks,
+        ``intermediates`` (the chosen experts)."""
         b, s, d = x.shape
         n, e, k = b * s, self.n_experts, self.top_k
         held = self.experts_held or e
@@ -685,7 +708,7 @@ class MoEFFN(nn.Module):
         w_gate = stack("experts_gate_kernel", d, (d, self.d_ff))
         w_in = stack("experts_in_kernel", d, (d, self.d_ff))
         w_out = stack("experts_out_kernel", self.d_ff, (self.d_ff, d))
-        out, rows, bound, overflow = _chunked_moe(
+        out, rows, bound, overflow, tiled = _chunked_moe(
             jnp.asarray(tokens, self.dtype), topi, gates, w_gate, w_in,
             w_out, first_expert=self.first_expert,
             # A quarter over what an even router sends the held experts:
@@ -699,6 +722,7 @@ class MoEFFN(nn.Module):
         self.sow("counters", "moe_rows", rows)
         self.sow("counters", "moe_rows_bound", bound)
         self.sow("counters", "moe_rows_overflowed", overflow)
+        self.sow("counters", "moe_rows_tiled", tiled)
         self.sow("counters", "moe_rows_uniform", jnp.float32(n * k / e))
         out = jnp.asarray(out, self.dtype)
         if self.shared_d_ff:

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """What the grouped expert layer costs on the chip, at a cell's shape.
 
-    chiprun -- python scripts/moe_layer_probe.py [--cell lfm2|mellum2] \
-        [--bound 2 4 8] [--chunk 1.25 2] [--root build/parent]
+    python scripts/moe_layer_probe.py [--cell lfm2 ...] \
+        [--bound 2 4 8] [--chunk 1.25 2] [--root build/parent] [--forms]
 
 One process on one TPU chip: (1) ``chip_smoke.py``'s kernel check at the
 attention shapes of the cell (``lfm2_24b_ep8.fit_seq8192_balanced``: head
@@ -36,13 +36,19 @@ integer scatter) beside the sort itself; (5) whether an
 executable that went through ``serialize`` / ``deserialize_and_load`` still
 gives its HLO text with ``op_name``. ``--root DIR`` runs (3) alone on the
 tree unpacked at DIR (the parent's ``git archive``), for parent against
-change in one call. Times are host-clock medians of single
-calls unless the key says ``device``: for sizing a choice, not results.
+change in one call. ``--forms`` runs, for each ``--cell`` (``CELLS``: lfm2,
+mellum2, moonlight, laguna), each of the layer's grouped products alone at
+the cell's shapes, XLA's ``ragged_dot`` against the Pallas kernels of
+``dct_tpu/ops/grouped_matmul.py`` on the tile rule's tiles and their
+neighbours, device time (:func:`probe_forms`), then (3). Times are
+host-clock medians of single calls unless the key says ``device``: for
+sizing a choice, not results.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -81,6 +87,17 @@ CELLS = {
                     kernels=("mellum2_swa_seq8192", "mellum2_full_seq8192"),
                     loads={"few": (12, 0), "own": (0, 0), "half": (0, 4),
                            "all": (0, 8)}),
+    # moonlight: 8 of 64 sigmoid-routed top-6 experts of 2048 x 1408, a
+    # chunk of 7,680; laguna: 16 of 256 top-8 experts of 2048 x 512, a
+    # chunk of 5,120.
+    "moonlight": dict(d=2048, f=1408, k=6, held=8, scoring="sigmoid",
+                      kernels=("moonlight_seq8192",),
+                      loads={"few": (6, 0), "own": (0, 0), "two": (4, 1),
+                             "all": (0, 6)}),
+    "laguna": dict(d=2048, f=512, k=8, held=16, scoring="sigmoid", e=256,
+                   kernels=(),
+                   loads={"few": (12, 0), "own": (0, 0), "two": (0, 2),
+                          "all": (0, 8)}),
 }
 CELL = CELLS["lfm2"]  # main() sets it from --cell
 
@@ -95,7 +112,8 @@ def cell_layer(n: int):
     from dct_tpu.models.moe import MoEFFN
 
     layer = MoEFFN(
-        d_model=CELL["d"], d_ff=CELL["f"], n_experts=64, aux_weight=0.0,
+        d_model=CELL["d"], d_ff=CELL["f"], n_experts=CELL.get("e", 64),
+        aux_weight=0.0,
         dtype=jnp.bfloat16, dispatch="grouped", top_k=CELL["k"],
         experts_held=CELL["held"], first_expert=0, scoring=CELL["scoring"])
     rng = np.random.default_rng(0)
@@ -121,7 +139,8 @@ def probe_layer(bound: float, n: int = 8192) -> dict:
     topi = sown["intermediates"]["topk"][0]
     k, held = CELL["k"], CELL["held"]
     gates = jnp.full(topi.shape, 1.0 / k, jnp.float32)
-    row_bound = int(min(n * min(k, held), bound * n * k * held / 64))
+    row_bound = int(min(
+        n * min(k, held), bound * n * k * held / CELL.get("e", 64)))
 
     def loss(p, x):
         w = [jnp.asarray(p[f"experts_{k}_kernel"], jnp.bfloat16)
@@ -183,7 +202,7 @@ def steered(params, off: int, on: int) -> dict:
     import numpy as np
 
     held = CELL["held"]
-    pull = np.zeros(64, np.float32)
+    pull = np.zeros(CELL.get("e", 64), np.float32)
     pull[held - off:held] = -4.0
     pull[:on] = 4.0
     kernel = jnp.asarray(params["router"]["kernel"]).at[0].set(pull)
@@ -194,6 +213,12 @@ def device_ms(fn, calls, reps: int = 3) -> list:
     """The device's own time of ``fn`` for each of ``calls`` (argument
     tuples, each already run once): the median of ``reps`` runs on the
     profiler's ``XLA Modules`` line of the first device."""
+    return device_ms_each([(fn, args) for args in calls], reps)
+
+
+def device_ms_each(runs, reps: int = 3) -> list:
+    """:func:`device_ms` for ``runs`` of (function, arguments) pairs, each
+    its own program, in one profile."""
     import glob
     import tempfile
 
@@ -202,7 +227,7 @@ def device_ms(fn, calls, reps: int = 3) -> list:
 
     with tempfile.TemporaryDirectory() as where:
         jax.profiler.start_trace(where)
-        for args in calls:
+        for fn, args in runs:
             for _ in range(reps):
                 jax.block_until_ready(fn(*args))
         jax.profiler.stop_trace()
@@ -210,11 +235,11 @@ def device_ms(fn, calls, reps: int = 3) -> list:
             glob.glob(where + "/**/*.xplane.pb", recursive=True)[0])
     plane = next(p for p in data.planes if p.name.startswith("/device:"))
     line = next(ln for ln in plane.lines if ln.name == "XLA Modules")
-    runs = [e.duration_ns / 1e6
-            for e in sorted(line.events, key=lambda e: e.start_ns)]
-    assert len(runs) == reps * len(calls), len(runs)
-    return [statistics.median(runs[reps * i:reps * (i + 1)])
-            for i in range(len(calls))]
+    found = [e.duration_ns / 1e6
+             for e in sorted(line.events, key=lambda e: e.start_ns)]
+    assert len(found) == reps * len(runs), len(found)
+    return [statistics.median(found[reps * i:reps * (i + 1)])
+            for i in range(len(runs))]
 
 
 def probe_shipped(n: int = 8192) -> list[dict]:
@@ -273,12 +298,12 @@ def probe_chunks(multiple: float, n: int = 8192) -> list[dict]:
         {"params": p}, x, mutable=["intermediates"])[1]["intermediates"])
     k, held = CELL["k"], CELL["held"]
     gates = jnp.full((n, k), 1.0 / k, jnp.float32)
-    chunk = int(multiple * n * k * held / 64)
+    chunk = int(multiple * n * k * held / CELL.get("e", 64))
 
     def loss(p, x, topi):
         w = [jnp.asarray(p[f"experts_{k}_kernel"], jnp.bfloat16)
              for k in ("gate", "in", "out")]
-        out, rows, bound, _ = _chunked_moe(
+        out, rows, bound, *_ = _chunked_moe(
             x[0], topi, gates, *w, first_expert=0, chunk=chunk)
         return (out ** 2).mean(), (rows.sum(), bound)
 
@@ -309,7 +334,7 @@ def probe_combine(multiple: float, n: int = 8192) -> list[dict]:
         {"params": p}, x, mutable=["intermediates"])[1]["intermediates"])(
             params, x)["topk"][0]
     k, held, d = CELL["k"], CELL["held"], CELL["d"]
-    chunk = int(multiple * n * k * held / 64)
+    chunk = int(multiple * n * k * held / CELL.get("e", 64))
     flat = jnp.where(topi < held, topi, held).reshape(-1).astype(jnp.int32)
     order = jnp.argsort(flat, stable=True)
     place = jnp.argsort(order).reshape(n, k)
@@ -406,6 +431,114 @@ def probe_combine(multiple: float, n: int = 8192) -> list[dict]:
     return found
 
 
+def _sweep_tiles(m: int, k: int, n: int, form: str) -> list:
+    """The tiles the per-form sweep times: the rule's, the rule's with
+    the row tile at 256 and 512, and the rule's with the widest output
+    extent (``tn``) cut to the largest dividing multiple of 128 at most
+    half of it."""
+    import jax.numpy as jnp
+
+    from dct_tpu.ops import grouped_matmul as gm
+
+    rule = gm.grouped_tiles(m, k, n, jnp.bfloat16, form)
+    tm, tk, tn = rule
+    found = [rule] + [(t, tk, tn) for t in (256, 512) if m % t == 0]
+    half = [c for c in range(128, tn // 2 + 1, 128) if n % c == 0]
+    if half:
+        found.append((tm, tk, half[-1]))
+    return list(dict.fromkeys(found))
+
+
+def probe_forms(n: int = 8192) -> list[dict]:
+    """Each of the layer's grouped products alone at the cell's shapes
+    (chunk rows for the forward and the products against the stacks, the
+    rows of every chunk that can come for the stacks' gradients) and at
+    the layer's own routing: XLA's ``ragged_dot`` (the products against
+    the transposed stacks on a copy transposed beforehand, as the parent
+    layer runs them) against the Pallas kernels on the swept tiles, device
+    time, share of the bf16 peak on the routed rows' operations, and the
+    largest difference from XLA's result over its largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from dct_tpu.models.moe import _chunk_sizes
+    from dct_tpu.ops import grouped_matmul as gm
+
+    layer, rng, x, params = cell_layer(n)
+    _, sown = jax.jit(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=["counters"]))(params, x)
+    rows = jnp.asarray(sown["counters"]["moe_rows"][0], jnp.int32)
+    d, f, k, held = CELL["d"], CELL["f"], CELL["k"], CELL["held"]
+    chunk = -(-5 * n * k * held // (4 * CELL.get("e", 64)))
+    top = -(-n * min(k, held) // chunk) * chunk
+    in_chunk = _chunk_sizes(rows, chunk=chunk, index=0)
+    bf16 = jnp.bfloat16
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), bf16)
+
+    products = []  # (name, form, m, k, n, sizes, operands, XLA's fn)
+    for name, kk, nn in (("d_to_f", d, f), ("f_to_d", f, d)):
+        w = normal(held, kk, nn)
+        lhs = normal(chunk, kk)
+        products.append((
+            name, "gmm", chunk, kk, nn, in_chunk, (lhs, w),
+            lambda a, w, s: lax.ragged_dot(a, w, s), (lhs, w)))
+        w_t = normal(held, nn, kk)  # the stack as the layer holds it
+        products.append((
+            name, "gmm_t", chunk, kk, nn, in_chunk, (lhs, w_t),
+            lambda a, w, s: lax.ragged_dot(a, w, s),
+            (lhs, jnp.swapaxes(w_t, 1, 2))))
+        a, b = normal(top, kk), normal(top, nn)
+        products.append((
+            name, "tgmm", top, kk, nn, rows, (a, b),
+            lambda a, b, s, g=held, kk=kk, nn=nn: jax.linear_transpose(
+                lambda w: lax.ragged_dot(a, w, s),
+                jax.ShapeDtypeStruct((g, kk, nn), bf16))(b)[0], (a, b)))
+    found, runs = [], []
+    for name, form, m, kk, nn, sizes, ops, xla, xla_ops in products:
+        routed = int(sizes.sum())
+        want = np.asarray(jax.jit(xla)(*xla_ops, sizes), np.float32)
+        if form != "tgmm":
+            want = want[:routed]
+        scale = float(np.abs(want).max())
+        entries = [("xla", jax.jit(xla).lower(*xla_ops, sizes).compile(),
+                    xla_ops)]
+        for tiles in _sweep_tiles(m, kk, nn, form):
+            if form == "tgmm":
+                fn = functools.partial(gm.tgmm, tiles=tiles)
+            else:
+                fn = functools.partial(
+                    gm.gmm, tiles=tiles, transpose_rhs=form == "gmm_t")
+            try:
+                entries.append((tiles, jax.jit(fn).lower(
+                    *ops, sizes).compile(), ops))
+            except Exception as e:  # noqa: BLE001 - a refused tile is a row
+                found.append({"product": name, "form": form,
+                              "tiles": tiles, "refused": str(e)[:200]})
+        for tiles, compiled, args in entries:
+            got = np.asarray(compiled(*args, sizes), np.float32)
+            if form != "tgmm":
+                got = got[:routed]
+            found.append({
+                "product": name, "form": form, "m": m, "k": kk, "n": nn,
+                "routed": routed, "tiles": tiles,
+                "tiled_rows": (int(gm.tiled_rows(sizes, tiles[0]))
+                               if tiles != "xla" and form != "tgmm"
+                               else None),
+                "max_diff_over_max": float(np.abs(got - want).max())
+                / scale})
+            runs.append((compiled, (*args, sizes)))
+    for one, ms in zip([r for r in found if "refused" not in r],
+                       device_ms_each(runs, reps=5)):
+        one["device_ms"] = ms
+        one["peak_share"] = (2 * one["routed"] * one["k"] * one["n"]
+                             / (ms * 1e-3) / 197e12)
+    return found
+
+
 def probe_text() -> dict:
     import jax
     import jax.numpy as jnp
@@ -430,11 +563,13 @@ def main() -> int:
     ap.add_argument("--bound", type=float, nargs="*", default=[2, 4, 8])
     ap.add_argument("--chunk", type=float, nargs="*", default=[1.25, 2])
     ap.add_argument("--skip-kernel", action="store_true")
-    ap.add_argument("--cell", choices=list(CELLS), default="lfm2")
+    ap.add_argument("--cell", choices=list(CELLS), nargs="+",
+                    default=["lfm2"])
     ap.add_argument("--root", help="a tree to probe as shipped, alone")
+    ap.add_argument("--forms", action="store_true",
+                    help="the per-form sweep, then the layer as shipped")
     args = ap.parse_args()
     global CELL
-    CELL = CELLS[args.cell]
     import jax
 
     if jax.devices()[0].platform != "tpu":
@@ -442,28 +577,36 @@ def main() -> int:
         return 2
     if args.root:
         sys.path.insert(0, os.path.abspath(args.root))
-        for found in probe_shipped():
-            print(json.dumps({"root": args.root, **found}), flush=True)
-        return 0
-    if not args.skip_kernel:
-        import chip_smoke
-
-        chip_smoke.phase_kernels([
-            case for case in chip_smoke.FULL.kernels
-            if case[0] in CELL["kernels"]])
-    print(json.dumps({"hlo_text": probe_text()}), flush=True)
     finite = True
-    # The layer first: the process's peak memory only ever rises.
-    for found in probe_shipped():
-        finite &= found.get("grads_finite", True)
-        print(json.dumps(found), flush=True)
-    for c in args.chunk:
-        for found in probe_combine(c) + probe_chunks(c):
+    for cell in args.cell:
+        CELL = CELLS[cell]
+        if args.forms:
+            for found in probe_forms():
+                print(json.dumps({"cell": cell, **found}), flush=True)
+        if args.forms or args.root:
+            for found in probe_shipped():
+                finite &= found.get("grads_finite", True)
+                print(json.dumps({"cell": cell, "root": args.root or ".",
+                                  **found}), flush=True)
+            continue
+        if not args.skip_kernel:
+            import chip_smoke
+
+            chip_smoke.phase_kernels([
+                case for case in chip_smoke.FULL.kernels
+                if case[0] in CELL["kernels"]])
+        print(json.dumps({"hlo_text": probe_text()}), flush=True)
+        # The layer first: the process's peak memory only ever rises.
+        for found in probe_shipped():
+            finite &= found.get("grads_finite", True)
             print(json.dumps(found), flush=True)
-    for c in args.bound:
-        found = probe_layer(c)
-        finite &= found["grads_finite"]
-        print(json.dumps(found), flush=True)
+        for c in args.chunk:
+            for found in probe_combine(c) + probe_chunks(c):
+                print(json.dumps(found), flush=True)
+        for c in args.bound:
+            found = probe_layer(c)
+            finite &= found["grads_finite"]
+            print(json.dumps(found), flush=True)
     return 0 if finite else 1
 
 

@@ -14,6 +14,7 @@ process may load the TPU's library, and every xdist worker imports every
 test file. Keep these tests in this one file for the same reason.
 """
 
+import functools
 import os
 import re
 
@@ -253,3 +254,106 @@ def test_the_epoch_program_tells_the_flash_kernels_apart(
     assert sorted("step.eval" in o for o in fwd.values()) == [False, True]
     assert all("jvp(step.loss)" in o or "step.eval" in o
                for o in fwd.values())
+
+
+#: The routed cells' grouped products (``ops/grouped_matmul.py``): chunk
+#: rows, the rows of every chunk that can come, and the layer's d x f
+#: (BENCHMARK.json's lfm2, moonlight, mellum2 and laguna cells).
+GROUPED = {
+    "lfm2": (5120, 35840, 2048, 1536, 8),
+    "moonlight": (7680, 53760, 2048, 1408, 8),
+    "mellum2": (20480, 81920, 2304, 896, 16),
+    "laguna": (5120, 66560, 2048, 512, 16),
+}
+
+
+@pytest.mark.parametrize("form", ["gmm", "gmm_t", "tgmm"])
+@pytest.mark.parametrize("cell", list(GROUPED))
+def test_grouped_kernels_compile_for_v5e_on_the_rules_tiles(
+        one_chip, no_compile_cache, cell, form):
+    """Each form of the grouped matmul, both ways round (d -> f and
+    f -> d), at the cell's shapes on the tiles ``grouped_tiles`` picks:
+    what Mosaic refuses (a misaligned block, more scoped VMEM than the
+    call asks for) fails here."""
+    from dct_tpu.ops import grouped_matmul as gm
+
+    chunk, top, d, f, held = GROUPED[cell]
+    bf16 = jnp.bfloat16
+
+    def arg(dims, dtype=bf16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sizes = arg((held,), jnp.int32)
+    for k, n in ((d, f), (f, d)):
+        if form == "tgmm":
+            fn = gm.tgmm
+            args = (arg((top, k)), arg((top, n)), sizes)
+        else:
+            fn = functools.partial(gm.gmm, transpose_rhs=form == "gmm_t")
+            stack = (held, n, k) if form == "gmm_t" else (held, k, n)
+            args = (arg((chunk, k)), arg(stack), sizes)
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_routed_epoch_program_runs_the_grouped_kernels(
+        topo, no_compile_cache, monkeypatch):
+    """A one-layer routed model's fused epoch program, compiled as the
+    trainer builds it with the kernels on: no XLA ragged-dot call is left,
+    and every Mosaic call that is not attention's lies under
+    ``moe.experts``, the scope ``moe.ffn_share`` and
+    ``moe.expert_roofline`` read: the forward's three products in the
+    chunk loop, the backward's six in its loop, three stack gradients."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from benchmark.reduce.scopes import in_scope
+    from dct_tpu.config import RunConfig
+    from dct_tpu.models.registry import get_model
+    from dct_tpu.ops import attention
+    from dct_tpu.ops import grouped_matmul
+    from dct_tpu.parallel.mesh import AXES, stacked_batch_sharding
+    from dct_tpu.parallel.sharding_rules import state_shardings
+    from dct_tpu.train.state import create_train_state
+    from dct_tpu.train.steps import make_epoch_train_eval_step
+
+    monkeypatch.setattr(attention, "flash_interpret_mode", lambda: False)
+    monkeypatch.setattr(
+        grouped_matmul, "grouped_interpret_mode", lambda: False)
+    for k, v in dict(
+            DCT_MODEL="weather_hybrid_moe_causal", DCT_D_MODEL=256,
+            DCT_N_HEADS=2, DCT_N_KV_HEADS=1, DCT_D_FF=512, DCT_N_LAYERS=1,
+            DCT_SEQ_LEN=256, DCT_POS_EMBED="rope", DCT_NORM="rmsnorm",
+            DCT_MLP="swiglu", DCT_USE_BIAS=0,
+            DCT_LAYER_TYPES="full_attention", DCT_NUM_DENSE_LAYERS=0,
+            DCT_N_EXPERTS=16, DCT_ROUTER_TOP_K=4, DCT_MOE_D_FF=256,
+            DCT_EXPERTS_HELD=4, DCT_FIRST_EXPERT=0).items():
+        monkeypatch.setenv(k, str(v))
+    cfg = RunConfig.from_env()
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1, 1), AXES)
+    model = get_model(cfg.model, input_dim=5, compute_dtype=jnp.bfloat16,
+                      mesh=mesh)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, input_dim=5, lr=1e-4, seed=0, example_shape=(1, 256, 5),
+        grad_clip_norm=1.0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        state, state_shardings(state, mesh, family=cfg.model.name))
+    stack = stacked_batch_sharding(mesh)
+
+    def stacks(n):
+        return (
+            jax.ShapeDtypeStruct((n, 2, 256, 5), jnp.float32, sharding=stack),
+            jax.ShapeDtypeStruct((n, 2, 256), jnp.int32, sharding=stack),
+            jax.ShapeDtypeStruct((n, 2), jnp.float32, sharding=stack))
+
+    fused = make_epoch_train_eval_step(
+        donate=False, donate_stacks=True, with_grad_norms=True)
+    text = fused.lower(state, *stacks(2), *stacks(1)).compile().as_text()
+    assert "ragged-dot" not in text
+    calls = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text)
+    grouped = [(n, o) for n, o in calls if not in_scope(o, "attn")]
+    assert len(grouped) == 12 + 3  # training's 12, validation's forward 3
+    assert all(in_scope(o, "moe.experts") for _, o in grouped), grouped

@@ -395,7 +395,7 @@ def test_the_chunks_equal_the_engine_under_one_bound(
         *(held[f"experts_{name}_kernel"] for name in ("gate", "in", "out")))
 
     def chunked(*operands):
-        out, rows, ran, overflow = _chunked_moe(
+        out, rows, ran, overflow, _ = _chunked_moe(
             operands[0], topi, *operands[1:], first_expert=4, chunk=chunk)
         return (out * cot).sum(), (rows.sum(), ran, overflow)
 

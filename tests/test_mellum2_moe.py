@@ -366,9 +366,10 @@ def test_the_accepted_cells_tiles_are_what_they_were():
 #: shapes and the StableHLO text of the objective's value and gradient, as
 #: digests. The trees and ``sc2_3b_block``'s text are what 9b9390d built
 #: (PR 35's parent; ``mellum2_12b_cut``'s tree what a54b777 built); the
-#: three routed configurations' texts were recorded anew in PR 36, whose
-#: chunk of 1.25 x the even share is a shape in them (at a54b777:
-#: ed07330872198a5f, dddf8cb6c2fd7f4d, fec8e302eba026ce).
+#: three routed configurations' texts are recorded anew whenever the
+#: grouped layer's program changes: last when its products went through
+#: ``ops/grouped_matmul.py`` (the products against the transposed stacks
+#: read the stacks in place) and it began to count ``moe_rows_tiled``.
 PRESETS = {
     "sc2_3b_block": (
         dict(DCT_D_MODEL="96", DCT_D_FF="384", DCT_N_HEADS="24",
@@ -376,18 +377,18 @@ PRESETS = {
         "ee8cc5ee3bbd5641", "9e2c314abf323051"),
     "lfm2_24b_a2b_ep8": (
         dict(DCT_D_MODEL="64", DCT_D_FF="368", DCT_MOE_D_FF="48"),
-        "29bdf51521177ce8", "326831293ee7ba1a"),
+        "29bdf51521177ce8", "7a07948292150674"),
     "moonlight_16b_a3b_ep8": (
         dict(DCT_D_MODEL="64", DCT_D_FF="96", DCT_N_HEADS="4",
              DCT_N_KV_HEADS="4", DCT_MOE_D_FF="24", DCT_MOE_SHARED_D_FF="48",
              DCT_KV_LORA_RANK="16", DCT_QK_NOPE_HEAD_DIM="8",
              DCT_QK_ROPE_HEAD_DIM="4", DCT_V_HEAD_DIM="8"),
-        "5eae93a7bbcc20cc", "275ec9154ced88b4"),
+        "5eae93a7bbcc20cc", "1a97cadd4e1eb404"),
     "mellum2_12b_cut": (
         dict(DCT_D_MODEL="64", DCT_D_FF="96", DCT_N_HEADS="8",
              DCT_N_KV_HEADS="2", DCT_HEAD_DIM="16", DCT_MOE_D_FF="24",
              DCT_ATTN_WINDOW="16", DCT_ROPE_ORIGINAL_LEN="32"),
-        "92ba8f2ae08a3289", "445cc51cfb480be0"),
+        "92ba8f2ae08a3289", "49574ee973ba8d34"),
 }
 
 
